@@ -21,6 +21,7 @@ from benchmarks.common import (build_methods, build_seconds, dataset, emit,
                                emit_bench_json, gt_for, recall_at_k,
                                timed_search, workloads)
 from repro.core.rfann import RNSGIndex
+from repro.runtime.compile_cache import enable_compile_cache
 
 
 def bench_qps_recall(n, d, nq, quick):
@@ -225,34 +226,49 @@ def bench_search_substrate(n, d, nq, quick):
     return rows
 
 
+def _needs_cpu_child(flag: str) -> bool:
+    """A multi-device bench on a one-device CPU host re-execs itself under
+    forced host devices.  Only on CPU: a child process could not reach a
+    chip this process already holds, so on TPU the bench runs in-process
+    over the real devices."""
+    import jax
+    return (jax.default_backend() == "cpu" and jax.device_count() == 1
+            and not os.environ.get(flag))
+
+
+def _rows_from_cpu_child(name: str, flag: str, n: int, quick: bool):
+    """Run ``--only name`` in a CPU child with 8 forced host devices (the
+    flag must be set before jax initializes) and return the rows it wrote
+    to results/bench/<name>.csv."""
+    root = Path(__file__).resolve().parent.parent
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=8",
+               PYTHONPATH=os.pathsep.join(
+                   [str(root / "src"),
+                    os.environ.get("PYTHONPATH", "")]).rstrip(os.pathsep))
+    env[flag] = "1"
+    r = subprocess.run(
+        [sys.executable, "-m", "benchmarks.run", "--only", name,
+         "--n", str(n)] + ([] if quick else ["--full"]),
+        env=env, cwd=str(root), capture_output=True, text=True, timeout=3600)
+    if r.returncode != 0:
+        raise RuntimeError(f"{name} subprocess failed:\n{r.stdout}\n"
+                           f"{r.stderr}")
+    with open(root / "results" / "bench" / f"{name}.csv") as f:
+        return list(csv.DictReader(f))
+
+
 def bench_mesh_auto(n, d, nq, quick):
     """Mesh-path strategy routing: ``DistributedRFANN(plan="auto")`` vs the
     graph-only mesh path on a shard_map mesh across selectivity regimes.
 
-    Needs a multi-device mesh; with a single local device the bench re-execs
-    itself under ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` (the
-    flag must be set before jax initializes its backends) and returns the
-    rows the child wrote to results/bench/mesh_auto.csv."""
+    Needs a multi-device mesh: on a TPU host it runs in-process over the
+    real chips; on a CPU host with one device it re-execs itself under 8
+    forced host devices (``_rows_from_cpu_child``)."""
     import jax
 
-    root = Path(__file__).resolve().parent.parent
-    if jax.device_count() == 1 and not os.environ.get("RNSG_MESH_BENCH"):
-        env = dict(os.environ,
-                   XLA_FLAGS="--xla_force_host_platform_device_count=8",
-                   RNSG_MESH_BENCH="1",
-                   PYTHONPATH=os.pathsep.join(
-                       [str(root / "src"),
-                        os.environ.get("PYTHONPATH", "")]).rstrip(os.pathsep))
-        r = subprocess.run(
-            [sys.executable, "-m", "benchmarks.run", "--only", "mesh_auto",
-             "--n", str(n)] + ([] if quick else ["--full"]),
-            env=env, cwd=str(root), capture_output=True, text=True,
-            timeout=3600)
-        if r.returncode != 0:
-            raise RuntimeError(f"mesh_auto subprocess failed:\n{r.stdout}\n"
-                               f"{r.stderr}")
-        with open(root / "results" / "bench" / "mesh_auto.csv") as f:
-            return list(csv.DictReader(f))
+    if _needs_cpu_child("RNSG_MESH_BENCH"):
+        return _rows_from_cpu_child("mesh_auto", "RNSG_MESH_BENCH", n, quick)
 
     from repro.data.ann import selectivity_ranges
     from repro.search import rank_interval
@@ -664,29 +680,13 @@ def bench_build(n, d, quick):
     bit-identity to the single-host build asserted per point), and the
     directory-format save/restore wall vs an O(n²) rebuild.
 
-    Needs a multi-device mesh; with one local device the bench re-execs
-    itself under ``XLA_FLAGS=--xla_force_host_platform_device_count=8``
-    (same pattern as mesh_auto) and returns the child's rows."""
+    Needs a multi-device mesh: on a TPU host it runs in-process over the
+    real chips; on a CPU host with one device it re-execs itself under 8
+    forced host devices (same pattern as mesh_auto)."""
     import jax
 
-    root = Path(__file__).resolve().parent.parent
-    if jax.device_count() == 1 and not os.environ.get("RNSG_BUILD_BENCH"):
-        env = dict(os.environ,
-                   XLA_FLAGS="--xla_force_host_platform_device_count=8",
-                   RNSG_BUILD_BENCH="1",
-                   PYTHONPATH=os.pathsep.join(
-                       [str(root / "src"),
-                        os.environ.get("PYTHONPATH", "")]).rstrip(os.pathsep))
-        r = subprocess.run(
-            [sys.executable, "-m", "benchmarks.run", "--only", "build",
-             "--n", str(n)] + ([] if quick else ["--full"]),
-            env=env, cwd=str(root), capture_output=True, text=True,
-            timeout=3600)
-        if r.returncode != 0:
-            raise RuntimeError(f"build subprocess failed:\n{r.stdout}\n"
-                               f"{r.stderr}")
-        with open(root / "results" / "bench" / "build.csv") as f:
-            return list(csv.DictReader(f))
+    if _needs_cpu_child("RNSG_BUILD_BENCH"):
+        return _rows_from_cpu_child("build", "RNSG_BUILD_BENCH", n, quick)
 
     import tempfile
 
@@ -848,6 +848,7 @@ def main() -> None:
     ap.add_argument("--only", default="")
     ap.add_argument("--n", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
     quick = not args.full
     n = args.n or (4096 if quick else 16384)
     d = 32 if quick else 64

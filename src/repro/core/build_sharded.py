@@ -42,43 +42,12 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.core.construction import RNSGGraph
+from repro.core.construction import RNSGGraph, gap_sorted_side
 from repro.core.entry import build_rmq, centroid_dists
 from repro.core.pruning import pack_kept, prune_side
-from repro.index.knn import sq_dists
-from repro.parallel.sharding import shard_map_compat
+from repro.index.knn import topk_smallest, sq_dists
 
 _PAD_VAL = 1e9          # must match index.knn.exact_knn's pad rows
-
-
-def _gap_sorted_side_jnp(ids, n: int, knn_ids, ef_attribute: int, side: str):
-    """jnp port of ``construction._gap_sorted_side`` over one slab.
-
-    ``ids``: (B, 1) global attribute ranks of the slab rows.  Same
-    candidate set, same stable sorts — stable argsort permutations are
-    unique given the keys, so the output matches the numpy reference
-    bit for bit (gap values fit int32: |cand - id| < n < 2³¹).
-    """
-    big = np.iinfo(np.int32).max // 2
-    win_off = jnp.arange(1, ef_attribute + 1, dtype=jnp.int32)[None, :]
-    win = ids - win_off if side == "l" else ids + win_off
-    win_ok = (win >= 0) & (win < n)
-    kn = knn_ids
-    kn_ok = ((kn >= 0) & (kn < n)
-             & ((kn < ids) if side == "l" else (kn > ids)))
-    cand = jnp.concatenate([jnp.where(win_ok, win, -1),
-                            jnp.where(kn_ok, kn, -1)], axis=1)
-    gap = jnp.where(cand >= 0, jnp.abs(cand - ids), big)
-    order = jnp.argsort(gap, axis=1, stable=True)
-    cand = jnp.take_along_axis(cand, order, axis=1)
-    gap = jnp.take_along_axis(gap, order, axis=1)
-    dup = jnp.concatenate(
-        [jnp.zeros((cand.shape[0], 1), bool),
-         (cand[:, 1:] == cand[:, :-1]) & (cand[:, 1:] >= 0)], axis=1)
-    cand = jnp.where(dup, -1, cand)
-    gap = jnp.where(dup, big, gap)
-    order = jnp.argsort(gap, axis=1, stable=True)
-    return jnp.take_along_axis(cand, order, axis=1).astype(jnp.int32)
 
 
 def _slab_nbrs_body(n: int, n_pad: int, rows_per_shard: int, block: int,
@@ -99,8 +68,7 @@ def _slab_nbrs_body(n: int, n_pad: int, rows_per_shard: int, block: int,
             # results discarded) in bounds without touching real rows
             d = d.at[jnp.arange(block),
                      jnp.minimum(rows, n_pad - 1)].set(jnp.inf)
-            _, ni = jax.lax.top_k(-d, k)
-            return ni
+            return topk_smallest(d, k)[1]
 
         knn = jax.lax.map(knn_block,
                           jnp.arange(rows_per_shard // block))
@@ -108,8 +76,8 @@ def _slab_nbrs_body(n: int, n_pad: int, rows_per_shard: int, block: int,
         # pad-row ids (>= n) never survive: the gap-sort side mask drops
         # them exactly like the host pipeline's kn < n bound
         ids = (row0 + jnp.arange(rows_per_shard, dtype=jnp.int32))[:, None]
-        cand_l = _gap_sorted_side_jnp(ids, n, knn, ef_attribute, "l")
-        cand_r = _gap_sorted_side_jnp(ids, n, knn, ef_attribute, "r")
+        cand_l = gap_sorted_side(ids, n, knn, ef_attribute, "l")
+        cand_r = gap_sorted_side(ids, n, knn, ef_attribute, "r")
 
         def prune_block(i):
             xv = jax.lax.dynamic_slice_in_dim(q_slab, i * block, block)
@@ -181,9 +149,8 @@ def build_rnsg_sharded(vectors: np.ndarray, attrs: np.ndarray, *,
 
     body = _slab_nbrs_body(n, n_pad, rows_per_shard, block, k_eff,
                            ef_attribute, m, axis)
-    fn = jax.jit(shard_map_compat(body, mesh,
-                                  in_specs=(P(axis), P()),
-                                  out_specs=P(axis)))
+    fn = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P(axis), P()),
+                               out_specs=P(axis), check_vma=False))
     nbrs = np.asarray(fn(jnp.asarray(queries), jnp.asarray(corpus)))[:n]
 
     if reverse_edges:

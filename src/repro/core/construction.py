@@ -12,8 +12,11 @@ import json
 import os
 import time
 from dataclasses import dataclass
-from typing import Optional, Tuple
+from functools import partial
+from typing import Optional
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.core.entry import build_rmq, centroid_dists
@@ -100,35 +103,52 @@ class RNSGGraph:
             return cls(**arrays, build_seconds=bs, meta={})
 
 
-def _gap_sorted_side(n: int, knn_ids: np.ndarray, ef_attribute: int,
-                     side: str) -> np.ndarray:
+def gap_sorted_side(ids, n: int, knn_ids, ef_attribute: int, side: str):
     """Per-node candidate ids of one side, ascending rank-gap, -1 padded.
-    Side candidates = attribute window ∪ same-side KNN neighbors."""
-    k = knn_ids.shape[1]
-    ch = ef_attribute + k
-    ids = np.arange(n)[:, None]
-    win_off = np.arange(1, ef_attribute + 1)[None, :]
-    win = ids - win_off if side == "l" else ids + win_off          # (n, ef)
+    Side candidates = attribute window ∪ same-side KNN neighbors.
+
+    Traceable (jnp) over any block of rows — ``ids``: (B, 1) global
+    attribute ranks of the rows, ``knn_ids``: (B, k) their KNN ids — so
+    the single-device build and every slab of the sharded build
+    (``core.build_sharded``) run this same code.  Both sorts are stable,
+    and a stable sort's permutation is unique given its keys (gaps fit
+    int32: |cand - id| < n < 2³¹)."""
+    big = np.iinfo(np.int32).max // 2
+    win_off = jnp.arange(1, ef_attribute + 1, dtype=jnp.int32)[None, :]
+    win = ids - win_off if side == "l" else ids + win_off
     win_ok = (win >= 0) & (win < n)
-    kn = knn_ids.copy()
+    kn = knn_ids
     # kn < n guards against out-of-range candidates (e.g. pad-row ids from a
     # k >= n exact_knn, or a caller-supplied approximate KNN graph): an id
     # >= n would flow into prune_all_jax's vector gathers and the final
     # adjacency, corrupting the index
     kn_ok = ((kn >= 0) & (kn < n)
              & ((kn < ids) if side == "l" else (kn > ids)))
-    cand = np.concatenate([np.where(win_ok, win, -1),
-                           np.where(kn_ok, kn, -1)], axis=1)        # (n, ch)
-    gap = np.where(cand >= 0, np.abs(cand - ids), np.iinfo(np.int64).max // 2)
-    order = np.argsort(gap, axis=1, kind="stable")
-    cand = np.take_along_axis(cand, order, axis=1)
-    gap = np.take_along_axis(gap, order, axis=1)
-    dup = np.zeros_like(cand, bool)
-    dup[:, 1:] = (cand[:, 1:] == cand[:, :-1]) & (cand[:, 1:] >= 0)
-    cand = np.where(dup, -1, cand)
-    gap = np.where(dup, np.iinfo(np.int64).max // 2, gap)
-    order = np.argsort(gap, axis=1, kind="stable")
-    return np.take_along_axis(cand, order, axis=1).astype(np.int32)
+    cand = jnp.concatenate([jnp.where(win_ok, win, -1),
+                            jnp.where(kn_ok, kn, -1)], axis=1)
+    gap = jnp.where(cand >= 0, jnp.abs(cand - ids), big)
+    order = jnp.argsort(gap, axis=1, stable=True)
+    cand = jnp.take_along_axis(cand, order, axis=1)
+    gap = jnp.take_along_axis(gap, order, axis=1)
+    dup = jnp.concatenate(
+        [jnp.zeros((cand.shape[0], 1), bool),
+         (cand[:, 1:] == cand[:, :-1]) & (cand[:, 1:] >= 0)], axis=1)
+    cand = jnp.where(dup, -1, cand)
+    gap = jnp.where(dup, big, gap)
+    order = jnp.argsort(gap, axis=1, stable=True)
+    return jnp.take_along_axis(cand, order, axis=1).astype(jnp.int32)
+
+
+@partial(jax.jit, static_argnames=("n", "ef_attribute", "side"))
+def _gap_sorted_side_jit(knn_ids, n: int, ef_attribute: int, side: str):
+    ids = jnp.arange(n, dtype=jnp.int32)[:, None]
+    return gap_sorted_side(ids, n, knn_ids, ef_attribute, side)
+
+
+def _gap_sorted_side(n: int, knn_ids, ef_attribute: int, side: str):
+    """``gap_sorted_side`` over all n rows, on the device: (n, ef+k) i32."""
+    return _gap_sorted_side_jit(jnp.asarray(knn_ids, jnp.int32), n,
+                                ef_attribute, side)
 
 
 def build_rnsg(vectors: np.ndarray, attrs: np.ndarray, *, m: int = 32,
@@ -151,6 +171,7 @@ def build_rnsg(vectors: np.ndarray, attrs: np.ndarray, *, m: int = 32,
     order = np.argsort(attrs, kind="stable")
     vs, as_ = vectors[order], attrs[order]
 
+    marks = [time.perf_counter()]   # stage boundaries: knn|sides|prune|rmq
     if knn_ids is None:
         # a corpus has at most n-1 true neighbors per node; asking for more
         # only returns pad/duplicate rows (tiny-corpus regression)
@@ -161,18 +182,24 @@ def build_rnsg(vectors: np.ndarray, attrs: np.ndarray, *, m: int = 32,
             _, knn_ids = exact_knn(vs, k_eff)
         else:
             _, knn_ids = nndescent(vs, k_eff, iters=knn_iters, seed=seed)
-    cand_l = _gap_sorted_side(n, knn_ids, ef_attribute, "l")
-    cand_r = _gap_sorted_side(n, knn_ids, ef_attribute, "r")
+    marks.append(time.perf_counter())
+    cand_l, cand_r = jax.block_until_ready(
+        [_gap_sorted_side(n, knn_ids, ef_attribute, s) for s in "lr"])
+    marks.append(time.perf_counter())
     nbrs = prune_all_jax(vs, cand_l, cand_r, m)
     if reverse_edges:
         from repro.index.baselines import add_reverse_edges
         nbrs = add_reverse_edges(nbrs, reverse_cap or int(m * 1.25))
-
+    marks.append(time.perf_counter())
     c, dist_c = centroid_dists(vs)
     rmq = build_rmq(dist_c)
+    marks.append(time.perf_counter())
+    stages = {name: b - a for name, a, b in
+              zip(("knn", "sides", "prune", "rmq"), marks, marks[1:])}
     dt = time.perf_counter() - t0
     return RNSGGraph(vecs=vs, attrs=as_, nbrs=nbrs, order=order.astype(np.int32),
                      centroid=c.astype(np.float32), dist_c=dist_c, rmq=rmq,
                      build_seconds=dt,
                      meta=dict(m=m, ef_spatial=ef_spatial,
-                               ef_attribute=ef_attribute, knn=knn_method))
+                               ef_attribute=ef_attribute, knn=knn_method,
+                               stage_seconds=stages))

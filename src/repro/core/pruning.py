@@ -60,7 +60,11 @@ def prune_side(x_vecs, cand_ids, cand_vecs, m_half: int):
     d_xc = jnp.sum(jnp.square(cand_vecs - x_vecs[:, None, :]), axis=-1)   # (B,C)
     # candidate-candidate distance tiles
     cn = jnp.sum(cand_vecs * cand_vecs, axis=-1)
-    d_cc = (cn[:, :, None] - 2.0 * jnp.einsum("bcd,bed->bce", cand_vecs, cand_vecs)
+    # full f32 product (a TPU's default is one bf16 pass): the keep/prune
+    # comparisons below must match the exact distances
+    d_cc = (cn[:, :, None]
+            - 2.0 * jnp.einsum("bcd,bed->bce", cand_vecs, cand_vecs,
+                               precision=jax.lax.Precision.HIGHEST)
             + cn[:, None, :])
     d_cc = jnp.maximum(d_cc, 0.0)
     valid = cand_ids >= 0
@@ -114,7 +118,8 @@ def prune_all_jax(vecs: np.ndarray, cand_l: np.ndarray, cand_r: np.ndarray,
     """Run Algorithm 1 for every node. cand_l/cand_r: (n, Ch) rank-gap-sorted
     candidate ids per side (-1 padded). Returns (n, m) neighbor ids (-1 pad).
     The keep/prune recurrence and the kept→adjacency pack both run on
-    device (``_prune_pack_block``); the host loop only blocks rows."""
+    device (``_prune_pack_block``); the host loop only enqueues row blocks
+    and reads the adjacency back once."""
     n = vecs.shape[0]
     half = max(m // 2, 1)
     v = jnp.asarray(vecs, jnp.float32)
@@ -124,9 +129,9 @@ def prune_all_jax(vecs: np.ndarray, cand_l: np.ndarray, cand_r: np.ndarray,
         xv = v[lo:hi]
         ci_l = jnp.asarray(cand_l[lo:hi], jnp.int32)
         ci_r = jnp.asarray(cand_r[lo:hi], jnp.int32)
-        out.append(np.asarray(_prune_pack_block(
+        out.append(_prune_pack_block(
             xv, ci_l, v[jnp.maximum(ci_l, 0)],
-            ci_r, v[jnp.maximum(ci_r, 0)], m, half)))
+            ci_r, v[jnp.maximum(ci_r, 0)], m, half))
     if not out:
         return np.full((0, m), -1, np.int32)
-    return np.concatenate(out)
+    return np.asarray(jnp.concatenate(out))

@@ -14,11 +14,35 @@ import numpy as np
 
 
 def sq_dists(a: jax.Array, b: jax.Array) -> jax.Array:
-    """(Na,d) × (Nb,d) -> (Na,Nb) squared L2 via ‖a‖² - 2a·b + ‖b‖²."""
+    """(Na,d) × (Nb,d) -> (Na,Nb) squared L2 via ‖a‖² - 2a·b + ‖b‖².
+    The product runs at full f32 precision: exact KNN promises exact ids,
+    and a TPU's default f32 matmul is a single bf16 pass."""
     an = jnp.sum(a * a, axis=-1, keepdims=True)
     bn = jnp.sum(b * b, axis=-1)
-    d = an - 2.0 * (a @ b.T) + bn[None, :]
+    ab = jnp.matmul(a, b.T, precision=jax.lax.Precision.HIGHEST)
+    d = an - 2.0 * ab + bn[None, :]
     return jnp.maximum(d, 0.0)
+
+
+def topk_smallest(d: jax.Array, k: int) -> Tuple[jax.Array, jax.Array]:
+    """Exact row-wise k smallest of d (B, N) — same values, ids and
+    tie order (lower index first) as ``lax.top_k(-d, k)`` — without a
+    top-k over all N columns, which dominates the build on a TPU.
+
+    Columns are cut into groups of G; the k groups whose minima are
+    smallest (ties toward the lower group) hold the k smallest entries:
+    an entry of a group outside them has k distinct entries, the other
+    groups' minima, ahead of it.  So only k·G candidates per row are
+    ranked, gathered in ascending column order to keep the tie order."""
+    b, n = d.shape
+    g = 1 << max(min(128, n // k).bit_length() - 1, 0)   # pow2; n % g == 0
+    grp = d.reshape(b, n // g, g)
+    _, gsel = jax.lax.top_k(-jnp.min(grp, axis=2), k)
+    gsel = jnp.sort(gsel, axis=1)
+    cand = jnp.take_along_axis(grp, gsel[:, :, None], axis=1).reshape(b, -1)
+    col = (gsel[:, :, None] * g + jnp.arange(g)).reshape(b, -1)
+    nd, pos = jax.lax.top_k(-cand, k)
+    return -nd, jnp.take_along_axis(col, pos, axis=1)
 
 
 @partial(jax.jit, static_argnames=("k", "block"))
@@ -31,8 +55,7 @@ def _exact_knn_jit(vecs: jax.Array, k: int, block: int):
         d = sq_dists(q, vecs)
         rows = i * block + jnp.arange(block)
         d = d.at[jnp.arange(block), rows].set(jnp.inf)      # exclude self
-        nd, ni = jax.lax.top_k(-d, k)
-        return -nd, ni
+        return topk_smallest(d, k)
 
     dists, ids = jax.lax.map(one_block, jnp.arange(nb))
     return dists.reshape(n, k), ids.reshape(n, k)

@@ -6,10 +6,14 @@ the BlockSpec index_map can steer each grid step's DMA to the right row of X
 — the TPU-native replacement for the CPU pointer-chase.
 
 Both kernels process the id vector in **row tiles** of T ids: the grid is
-``(num_tiles, T)``, the innermost dimension walks the tile (one steered
-(1, d) row DMA per step, which Mosaic pipelines across steps), and each
-row's Σ(x−q)² lands in a lane of a (1, T) VMEM accumulator.  Work leaves
-VMEM once per *tile*, not once per row:
+``(num_tiles, T)``, the innermost dimension walks the tile, and each row's
+Σ(x−q)² lands in a lane of a (1, T) VMEM accumulator.  A TPU block's last
+two dims must divide the (sublane, lane) tile or equal the array's, so a
+steered step cannot DMA one (1, d) row: it DMAs the **aligned row group**
+``(R, d)`` holding the row (R = one sublane tile: 8 f32 / 16 bf16 / 32 int8
+rows, so every dtype moves the same bytes) and selects the row in VMEM with
+a one-hot sublane mask.  Mosaic pipelines the group DMAs across steps.
+Work leaves VMEM once per *tile*, not once per row:
 
 * ``gather_dist_pallas`` — writes the accumulated (1, T) distance block to
   the output on the tile's last step (full (M,) distances, the legacy
@@ -39,10 +43,29 @@ def _tile(m: int, cap: int = 128) -> int:
     return int(min(cap, 1 << max(int(m) - 1, 0).bit_length() if m > 1 else 1))
 
 
-def _row_d2(x_ref, q_ref, scale_ref):
-    """Σ(x−q)² of one gathered row, dequantized in VMEM when the corpus is
+def _row_group(x: jax.Array) -> int:
+    """Rows per steered DMA: one sublane tile of x's dtype (32-bit words
+    pack 4/itemsize rows per sublane), or all of x when it is shorter."""
+    return int(min(8 * max(4 // x.dtype.itemsize, 1), x.shape[0]))
+
+
+def _row_spec(x: jax.Array, flat_pos):
+    """BlockSpec steering the aligned row group that holds the id at flat
+    position ``flat_pos(*grid_idx)`` of the scalar-prefetched id table."""
+    r = _row_group(x)
+    return pl.BlockSpec(
+        (r, x.shape[1]),
+        lambda *a: (a[-1][flat_pos(*a[:-1])] // r, 0))
+
+
+def _row_d2(x_ref, q_ref, scale_ref, row):
+    """Σ(x−q)² of corpus row ``row``: picked out of the DMA'd aligned row
+    group by a one-hot sublane mask, dequantized in VMEM when the corpus is
     int8 (``scale_ref`` holds the (1, d) per-dimension factors)."""
-    xf = x_ref[...].astype(jnp.float32)
+    grp = x_ref[...].astype(jnp.float32)                 # (R, d)
+    r = grp.shape[0]
+    sel = jax.lax.broadcasted_iota(jnp.int32, (r, 1), 0) == row % r
+    xf = jnp.sum(jnp.where(sel, grp, 0.0), axis=0, keepdims=True)   # (1, d)
     if scale_ref is not None:
         xf = xf * scale_ref[...]
     diff = xf - q_ref[...].astype(jnp.float32)
@@ -51,13 +74,14 @@ def _row_d2(x_ref, q_ref, scale_ref):
 
 def _dist_body(ids_ref, x_ref, q_ref, scale_ref, o_ref, acc_ref, *,
                tile: int):
+    i = pl.program_id(0)
     t = pl.program_id(1)
 
     @pl.when(t == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    d2 = _row_d2(x_ref, q_ref, scale_ref)
+    d2 = _row_d2(x_ref, q_ref, scale_ref, ids_ref[i * tile + t])
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, tile), 1) == t
     acc_ref[...] = jnp.where(lane, d2, acc_ref[...])
 
@@ -88,7 +112,7 @@ def gather_dist_pallas(x: jax.Array, ids: jax.Array, q: jax.Array, *,
     nt = -(-m // tile)
     ids_c = jnp.clip(ids, 0, n - 1).astype(jnp.int32)
     ids_c = jnp.pad(ids_c, (0, nt * tile - m))      # tail rows: row 0, sliced off
-    x_spec = pl.BlockSpec((1, d), lambda i, t, ids_ref: (ids_ref[i * tile + t], 0))
+    x_spec = _row_spec(x, lambda i, t: i * tile + t)
     q_spec = pl.BlockSpec((1, d), lambda i, t, ids_ref: (0, 0))
     if scale is None:
         kernel, in_specs, ops = _dist_kernel, [x_spec, q_spec], (x, q[None, :])
@@ -100,13 +124,14 @@ def gather_dist_pallas(x: jax.Array, ids: jax.Array, q: jax.Array, *,
         num_scalar_prefetch=1,
         grid=(nt, tile),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, tile), lambda i, t, ids_ref: (i, 0)),
+        out_specs=pl.BlockSpec((None, 1, tile),
+                               lambda i, t, ids_ref: (i, 0, 0)),
         scratch_shapes=[pltpu.VMEM((1, tile), jnp.float32)],
     )
     out = pl.pallas_call(
         functools.partial(kernel, tile=tile),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((nt, tile), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((nt, 1, tile), jnp.float32),
         interpret=interpret,
     )(ids_c, *ops)
     return out.reshape(nt * tile)[:m]
@@ -154,7 +179,7 @@ def _topk_body(ids_ref, x_ref, q_ref, scale_ref, idm_ref, od_ref, oi_ref,
     def _init_acc():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    d2 = _row_d2(x_ref, q_ref, scale_ref)
+    d2 = _row_d2(x_ref, q_ref, scale_ref, ids_ref[i * tile + t])
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, tile), 1) == t
     acc_ref[...] = jnp.where(lane, d2, acc_ref[...])
 
@@ -198,7 +223,7 @@ def gather_topk_pallas(x: jax.Array, ids: jax.Array, q: jax.Array, *,
     pad = nt * tile - m
     ids_m = jnp.pad(ids.astype(jnp.int32), (0, pad), constant_values=-1)
     ids_c = jnp.clip(ids_m, 0, n - 1)
-    x_spec = pl.BlockSpec((1, d), lambda i, t, ids_ref: (ids_ref[i * tile + t], 0))
+    x_spec = _row_spec(x, lambda i, t: i * tile + t)
     q_spec = pl.BlockSpec((1, d), lambda i, t, ids_ref: (0, 0))
     idm_spec = pl.BlockSpec((1, tile), lambda i, t, ids_ref: (0, i))
     if scale is None:
@@ -234,7 +259,8 @@ def gather_topk_pallas(x: jax.Array, ids: jax.Array, q: jax.Array, *,
 # Batched rerank: per-query gather + f32 top-k over survivor id lists
 # ======================================================================
 def _rerank_kernel(ids_ref, x_ref, q_ref, idm_ref, od_ref, oi_ref, acc_ref,
-                   *, tile: int, k: int):
+                   *, tile: int, k: int, mp: int):
+    i = pl.program_id(0)          # query
     j = pl.program_id(1)          # id tile within this query's list
     t = pl.program_id(2)          # position within the tile
 
@@ -247,7 +273,7 @@ def _rerank_kernel(ids_ref, x_ref, q_ref, idm_ref, od_ref, oi_ref, acc_ref,
     def _init_acc():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    d2 = _row_d2(x_ref, q_ref, None)
+    d2 = _row_d2(x_ref, q_ref, None, ids_ref[i * mp + j * tile + t])
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, tile), 1) == t
     acc_ref[...] = jnp.where(lane, d2, acc_ref[...])
 
@@ -281,26 +307,30 @@ def gather_rerank_pallas(x: jax.Array, ids: jax.Array, q: jax.Array, *,
     ids_m = jnp.pad(ids.astype(jnp.int32), ((0, 0), (0, mp - m)),
                     constant_values=-1)
     ids_c = jnp.clip(ids_m, 0, n - 1).reshape(Q * mp)
+    # q, the id mask and the outputs are viewed 3-D, (Q, 1, ·), with the
+    # query dim squeezed (a (1, ·) block of a (Q, ·) array is not tileable)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(Q, nt, tile),
         in_specs=[
-            pl.BlockSpec((1, d), lambda i, j, t, ids_ref:
-                         (ids_ref[i * (nt * tile) + j * tile + t], 0)),
-            pl.BlockSpec((1, d), lambda i, j, t, ids_ref: (i, 0)),
-            pl.BlockSpec((1, tile), lambda i, j, t, ids_ref: (i, j)),
+            _row_spec(x, lambda i, j, t: i * mp + j * tile + t),
+            pl.BlockSpec((None, 1, d), lambda i, j, t, ids_ref: (i, 0, 0)),
+            pl.BlockSpec((None, 1, tile),
+                         lambda i, j, t, ids_ref: (i, 0, j)),
         ],
         out_specs=[
-            pl.BlockSpec((1, tile), lambda i, j, t, ids_ref: (i, 0)),
-            pl.BlockSpec((1, tile), lambda i, j, t, ids_ref: (i, 0)),
+            pl.BlockSpec((None, 1, tile),
+                         lambda i, j, t, ids_ref: (i, 0, 0)),
+            pl.BlockSpec((None, 1, tile),
+                         lambda i, j, t, ids_ref: (i, 0, 0)),
         ],
         scratch_shapes=[pltpu.VMEM((1, tile), jnp.float32)],
     )
     od, oi = pl.pallas_call(
-        functools.partial(_rerank_kernel, tile=tile, k=k),
+        functools.partial(_rerank_kernel, tile=tile, k=k, mp=mp),
         grid_spec=grid_spec,
-        out_shape=(jax.ShapeDtypeStruct((Q, tile), jnp.float32),
-                   jax.ShapeDtypeStruct((Q, tile), jnp.int32)),
+        out_shape=(jax.ShapeDtypeStruct((Q, 1, tile), jnp.float32),
+                   jax.ShapeDtypeStruct((Q, 1, tile), jnp.int32)),
         interpret=interpret,
-    )(ids_c, x, q, ids_m)
-    return oi[:, :k], od[:, :k]
+    )(ids_c, x, q[:, None, :], ids_m[:, None, :])
+    return oi[:, 0, :k], od[:, 0, :k]

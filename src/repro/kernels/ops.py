@@ -1,8 +1,9 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On CPU (this container) the kernels execute in ``interpret=True`` mode — the
-kernel body runs verbatim for correctness; on TPU the same call sites compile
-to Mosaic.  Backend selection is automatic.
+On TPU the kernels compile to Mosaic.  On CPU (tests, small local runs)
+they execute in ``interpret=True`` mode — the kernel body runs verbatim for
+correctness.  Any other backend raises: a served kernel never silently
+degrades to the interpreter on an accelerator.
 """
 from __future__ import annotations
 
@@ -16,7 +17,11 @@ from repro.kernels.range_scan import range_scan_pallas
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(f"Pallas kernels run on TPU (Mosaic) or CPU "
+                           f"(interpret mode), not on {backend!r}")
+    return backend == "cpu"
 
 
 def l2dist(q: jax.Array, x: jax.Array, **kw) -> jax.Array:

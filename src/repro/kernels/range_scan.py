@@ -59,7 +59,10 @@ def _body(starts_ref, lens_ref, x_ref, scale_ref, live_ref, q_ref, od_ref,
     if scale_ref is not None:                     # int8: dequant in VMEM
         x = x * scale_ref[...]                    # (1, td) broadcast
     q = q_ref[...].astype(jnp.float32)            # (1, td)
+    # full-f32 MXU passes: the scan promises exact ids, and a default
+    # single-pass bf16 product would reorder near-ties on the chip
     dot = jax.lax.dot_general(q, x, (((1,), (1,)), ((), ())),
+                              precision=jax.lax.Precision.HIGHEST,
                               preferred_element_type=jnp.float32)
     acc_ref[...] += -2.0 * dot
     acc_ref[...] += jnp.sum(q * q, axis=1, keepdims=True)
@@ -145,12 +148,9 @@ def range_scan_pallas(x: jax.Array, starts: jax.Array, lens: jax.Array,
     Q = q.shape[0]
     n_valid = int(n_valid) or n_pad
     if k > tb:
-        # running top-k lives in one tb-lane register row; beyond that fall
-        # back to the materializing oracle (rare: k > 128)
-        from repro.kernels.ref import range_scan_ref
-        return range_scan_ref(x, starts, lens, q, bucket=bucket, k=k, tb=tb,
-                              n_valid=n_valid, scale=scale,
-                              live=None if live is None else live[0])
+        raise ValueError(f"range_scan: k={k} exceeds the {tb}-lane running "
+                         f"top-k row (the planner routes such queries to "
+                         f"beam)")
     td = d_pad if d_pad <= td else 128
     nd = d_pad // td
     w = window_rows(bucket, tb)
@@ -162,7 +162,11 @@ def range_scan_pallas(x: jax.Array, starts: jax.Array, lens: jax.Array,
     x_spec = pl.BlockSpec((tb, td),
                           lambda i, j, kd, s_ref, l_ref:
                           (jnp.minimum(s_ref[i] // tb + j, max_blk), kd))
-    q_spec = pl.BlockSpec((1, td), lambda i, j, kd, s_ref, l_ref: (i, kd))
+    # q and the outputs are viewed 3-D, (Q, 1, ·), with the query dim
+    # squeezed: a (1, ·) block of a (Q, ·) array breaks the TPU rule that
+    # a block's last two dims divide (8, 128) or equal the array's
+    q_spec = pl.BlockSpec((None, 1, td),
+                          lambda i, j, kd, s_ref, l_ref: (i, 0, kd))
     kernel = _KERNELS[(scale is not None, live is not None)]
     in_specs, ops = [x_spec], [x]
     if scale is not None:
@@ -176,23 +180,25 @@ def range_scan_pallas(x: jax.Array, starts: jax.Array, lens: jax.Array,
             (0, jnp.minimum(s_ref[i] // tb + j, max_blk))))
         ops.append(live.astype(jnp.int32))
     in_specs.append(q_spec)
-    ops.append(q)
+    ops.append(q[:, None, :])
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(Q, nb, nd),
         in_specs=in_specs,
         out_specs=[
-            pl.BlockSpec((1, tb), lambda i, j, kd, s_ref, l_ref: (i, 0)),
-            pl.BlockSpec((1, tb), lambda i, j, kd, s_ref, l_ref: (i, 0)),
+            pl.BlockSpec((None, 1, tb),
+                         lambda i, j, kd, s_ref, l_ref: (i, 0, 0)),
+            pl.BlockSpec((None, 1, tb),
+                         lambda i, j, kd, s_ref, l_ref: (i, 0, 0)),
         ],
         scratch_shapes=[pltpu.VMEM((1, tb), jnp.float32)],
     )
     dists, ids = pl.pallas_call(
         functools.partial(kernel, nd=nd, tb=tb, k=k, n_valid=n_valid),
         grid_spec=grid_spec,
-        out_shape=(jax.ShapeDtypeStruct((Q, tb), jnp.float32),
-                   jax.ShapeDtypeStruct((Q, tb), jnp.int32)),
+        out_shape=(jax.ShapeDtypeStruct((Q, 1, tb), jnp.float32),
+                   jax.ShapeDtypeStruct((Q, 1, tb), jnp.int32)),
         interpret=interpret,
     )(starts, lens, *ops)
 
-    return ids[:, :k], dists[:, :k]
+    return ids[:, 0, :k], dists[:, 0, :k]

@@ -31,7 +31,7 @@ from repro.launch.specs import input_specs
 from repro.models.lm import Model
 from repro.models.params import ShardPlan, logical_axes
 from repro.parallel.sharding import (batch_logical, cache_logical,
-                                     make_act_sharder, set_mesh_compat,
+                                     make_act_sharder,
                                      spec_for_logical, tree_shardings)
 from repro.training.train_step import build_train_step, train_state_shapes
 
@@ -208,10 +208,9 @@ def run_rnsg_cell(multi_pod: bool, variant: str = "base", save: bool = True):
 
     shard = P(("pod", "data") if multi_pod else "data")
     q_spec = P("model") if qshard else P()
-    from repro.parallel.sharding import shard_map_compat
-    fn = shard_map_compat(body, mesh,
-                          in_specs=(shard,) * 6 + (q_spec, q_spec),
-                          out_specs=(q_spec, q_spec))
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(shard,) * 6 + (q_spec, q_spec),
+                       out_specs=(q_spec, q_spec), check_vma=False)
     S = data_sz
     args = (jax.ShapeDtypeStruct((S, ns, d), jnp.float32),
             jax.ShapeDtypeStruct((S, ns, m), jnp.int32),
@@ -222,7 +221,7 @@ def run_rnsg_cell(multi_pod: bool, variant: str = "base", save: bool = True):
             jax.ShapeDtypeStruct((nq, d), jnp.float32),
             jax.ShapeDtypeStruct((nq, 2), jnp.float32))
     t0 = time.perf_counter()
-    with set_mesh_compat(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(fn).lower(*args)
         compiled = lowered.compile()
     t_compile = time.perf_counter() - t0
@@ -281,7 +280,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, variant: str = "base",
         fn, args, meta, cfg, shape = build_cell(arch, shape_name, mesh, variant,
                                                 analysis=analysis,
                                                 depth_groups=depth_groups)
-        with set_mesh_compat(mesh):
+        with jax.set_mesh(mesh):
             lowered = fn.lower(*args)
             t_lower = time.perf_counter() - t0
             compiled = lowered.compile()

@@ -44,6 +44,7 @@ from repro.data.ann import (ground_truth, make_attrs, make_vectors,
 from repro.launch.specs import concrete_batch
 from repro.models.lm import Model
 from repro.models.params import ShardPlan
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.runtime.fault_tolerance import PreemptionHandler
 from repro.serving.engine import RFANNEngine
 from repro.streaming import ReadOnlyIndexError
@@ -318,6 +319,7 @@ def main(argv=None):
                          "only")
     ap.add_argument("--new-tokens", type=int, default=16)
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.mode == "rfann":
         serve_rfann(args)
     else:

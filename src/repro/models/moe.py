@@ -28,7 +28,6 @@ from jax.sharding import PartitionSpec as P
 from repro.configs.base import ArchConfig
 from repro.models.layers import norm
 from repro.models.params import ModelDims
-from repro.parallel.sharding import shard_map_compat
 
 
 def _route(xt: jax.Array, router: jax.Array, k: int):
@@ -133,8 +132,8 @@ def moe_ffn(x: jax.Array, p: Dict, cfg: ArchConfig, dm: ModelDims,
     body = partial(_moe_local, k=cfg.moe_top_k, cf=cfg.capacity_factor,
                    axis_names=names, tp_axis="model" if ep else None,
                    e_total=dm.e)
-    y, aux = shard_map_compat(
-        body, mesh,
+    y, aux = jax.shard_map(
+        body, mesh=mesh, check_vma=False,
         in_specs=(tok_spec, P(None, None), w_spec, w_spec,
                   P("model", None, None) if ep else P(None, None, None)),
         out_specs=(tok_spec, P()),

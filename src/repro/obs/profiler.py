@@ -6,45 +6,26 @@
 span names — the kernel dispatch sites in ``repro.search.substrate`` use
 ``rnsg.scan_dispatch`` / ``rnsg.beam_dispatch`` / ``rnsg.gather`` style
 names.  When no profiler session is active a ``TraceAnnotation`` is a few
-nanoseconds of overhead, so the annotations stay on unconditionally; if
-the running jax build lacks the profiler entirely, everything degrades to
-no-ops instead of failing.
+nanoseconds of overhead, so the annotations stay on unconditionally.
 """
 from __future__ import annotations
 
-from contextlib import contextmanager, nullcontext
+from contextlib import contextmanager
 
-try:                                    # profiler present in jax >= 0.3
-    from jax.profiler import TraceAnnotation as _TraceAnnotation
-except Exception:                       # pragma: no cover - stub builds
-    _TraceAnnotation = None
+import jax.profiler
 
 
 def annotate(name: str):
     """Context manager marking a host region in the profiler timeline."""
-    if _TraceAnnotation is None:        # pragma: no cover - stub builds
-        return nullcontext()
-    return _TraceAnnotation(name)
+    return jax.profiler.TraceAnnotation(name)
 
 
 @contextmanager
 def device_trace(log_dir: str):
-    """Capture a ``jax.profiler`` trace (TensorBoard format) around a block.
-
-    No-op (with a warning) when the profiler is unavailable, so callers —
-    ``make profile`` / ``tools/profile_capture.py`` — never hard-fail in a
-    stripped container."""
-    try:
-        import jax.profiler as _prof
-        _prof.start_trace(log_dir)
-        started = True
-    except Exception as e:              # pragma: no cover - stub builds
-        import warnings
-        warnings.warn(f"jax profiler unavailable ({e}); capturing nothing")
-        started = False
+    """Capture a ``jax.profiler`` trace (TensorBoard format) around a
+    block (``make profile`` / ``tools/profile_capture.py``)."""
+    jax.profiler.start_trace(log_dir)
     try:
         yield
     finally:
-        if started:
-            import jax.profiler as _prof
-            _prof.stop_trace()
+        jax.profiler.stop_trace()

@@ -20,8 +20,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.parallel.sharding import shard_map_compat
-
 
 def gpipe(stage_fn: Callable, mesh, axis: str, n_stages: int, n_micro: int):
     """Returns pipelined(params_stacked, x_micro) -> y_micro.
@@ -63,7 +61,8 @@ def gpipe(stage_fn: Callable, mesh, axis: str, n_stages: int, n_micro: int):
 
     def pipelined(params_stacked, x_micro):
         in_specs = (jax.tree.map(lambda _: P(axis), params_stacked), P())
-        return shard_map_compat(body, mesh, in_specs=in_specs,
-                                out_specs=P())(params_stacked, x_micro)
+        return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                             out_specs=P(),
+                             check_vma=False)(params_stacked, x_micro)
 
     return pipelined

@@ -72,67 +72,6 @@ REPLICA_RULES: Dict[Optional[str], Tuple] = {
 STRATEGIES = {"tp": DEFAULT_RULES, "fsdp": FSDP_RULES, "replica": REPLICA_RULES}
 
 
-def make_mesh_compat(shape: Sequence[int], axis_names: Sequence[str]) -> Mesh:
-    """Version-portable device-mesh builder: jax ≥ 0.5 accepts
-    ``axis_types=(AxisType.Auto, ...)`` (and some versions require it for the
-    implicit-mesh machinery), jax 0.4.x has neither ``AxisType`` nor the
-    kwarg.  Auto axis types match 0.4.x semantics exactly, so behavior is
-    identical on both sides."""
-    try:
-        from jax.sharding import AxisType
-        return jax.make_mesh(tuple(shape), tuple(axis_names),
-                             axis_types=(AxisType.Auto,) * len(axis_names))
-    except (ImportError, TypeError):
-        return jax.make_mesh(tuple(shape), tuple(axis_names))
-
-
-def set_mesh_compat(mesh: Mesh):
-    """Version-portable ``with jax.set_mesh(mesh): ...`` context: jax ≥ 0.6
-    exposes ``jax.set_mesh`` (usable as a context manager), jax 0.4.x spells
-    the same thing as entering the ``Mesh`` itself (the resource-env context
-    ``with mesh:``).  Callers must use this as a context manager only."""
-    sm = getattr(jax, "set_mesh", None)
-    if sm is not None:
-        return sm(mesh)
-    return mesh                     # Mesh is a context manager on jax 0.4.x
-
-
-def abstract_mesh(axis_sizes: Sequence[int], axis_names: Sequence[str]):
-    """Version-portable AbstractMesh: jax ≤ 0.4.x takes one tuple of
-    (name, size) pairs, jax ≥ 0.5 takes (axis_sizes, axis_names)."""
-    from jax.sharding import AbstractMesh
-    try:
-        return AbstractMesh(tuple(zip(axis_names, axis_sizes)))
-    except TypeError:
-        return AbstractMesh(tuple(axis_sizes), tuple(axis_names))
-
-
-def shard_map_compat(f, mesh: Mesh, *, in_specs, out_specs):
-    """Version-portable ``shard_map``: jax ≥ 0.5 exposes ``jax.shard_map``
-    (replication checking via ``check_vma``), jax 0.4.x ships it as
-    ``jax.experimental.shard_map.shard_map`` (``check_rep``).  Replication
-    checking is disabled on both — callers (the RFANN mesh substrate, the
-    pipeline) end their bodies in explicitly replicated ``all_gather``
-    merges, which the static checker cannot always prove."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is None:
-        from jax.experimental.shard_map import shard_map as sm_old
-        return sm_old(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False)
-    err = None
-    for kw in ({"check_vma": False}, {"check_rep": False}):
-        try:
-            return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kw)
-        except TypeError as e:                  # other flag spelling
-            err = e
-    # no flagless fallback: it would silently re-enable the replication
-    # check this wrapper exists to disable — fail loudly instead
-    raise TypeError("jax.shard_map accepts neither check_vma nor "
-                    "check_rep; extend shard_map_compat for this jax "
-                    "version") from err
-
-
 def _alternatives(entry) -> Tuple[Tuple[str, ...], ...]:
     if not entry:
         return ()

@@ -24,12 +24,17 @@ from typing import List, Tuple
 
 import numpy as np
 
-from repro.planner.bucketing import (buckets_np, bucket_for_len, ef_bucket,
-                                     ef_bucket_np, next_pow2, pad_pow2,
-                                     window_rows, window_rows_np)
+from repro.planner.bucketing import (ROW_TILE, buckets_np, bucket_for_len,
+                                     ef_bucket, ef_bucket_np, next_pow2,
+                                     pad_pow2, window_rows, window_rows_np)
 from repro.planner.cost import CostModel
 
 SCAN, BEAM = 0, 1
+
+#: the scan kernel keeps its running top-k in one row-tile of lanes, so it
+#: serves k ≤ 128; larger k always routes to beam (empty ranges included —
+#: the beam returns an all-pad row for them)
+SCAN_MAX_K = ROW_TILE
 
 
 @dataclass
@@ -84,6 +89,8 @@ class QueryPlanner:
         ``beam_width`` selects which batched-expansion regime prices the
         beam side."""
         ln = int(length)
+        if k > SCAN_MAX_K:
+            return BEAM
         if ln <= 0 or ln <= k:
             return SCAN
         if ln > self.max_scan_len:
@@ -130,6 +137,7 @@ class QueryPlanner:
         eligible = lens <= self.max_scan_len
         use_scan = (eligible & (scan_cost <= beam_cost)) | (lens <= 0) \
             | (lens <= k)                  # tiny slices: scan is exact & free
+        use_scan &= k <= SCAN_MAX_K
         return np.where(use_scan, SCAN, BEAM).astype(np.int8)
 
     # ------------------------------------------------------------------
@@ -147,7 +155,8 @@ class QueryPlanner:
         if mode == "scan":
             use_scan = np.ones(q, bool)
         elif mode == "beam":
-            use_scan = lens <= 0           # beam cannot express empty ranges
+            # empty ranges go to the (free) scan while it can serve k
+            use_scan = (lens <= 0) & (k <= SCAN_MAX_K)
         else:
             use_scan = self.choose_strategy_batch(
                 lens, k=k, ef=ef, beam_width=beam_width,

@@ -64,7 +64,6 @@ from repro.kernels.quantize import (QuantizedCorpus, quantize_corpus,
 from repro.obs.metrics import MetricsRegistry
 from repro.obs.profiler import annotate
 from repro.obs.trace import maybe_span
-from repro.parallel.sharding import shard_map_compat
 from repro.planner.bucketing import (ROW_TILE, bucket_for_len, next_pow2,
                                      pad_pow2, window_rows)
 from repro.planner.planner import BEAM, QueryPlanner, SCAN
@@ -1233,10 +1232,10 @@ class MeshSubstrate:
                            beam_width=beam_width, precision=precision,
                            use_live=use_live)
             shard, rep = P(self.axis), P()
-            fn = jax.jit(shard_map_compat(
-                body, self.mesh,
+            fn = jax.jit(jax.shard_map(
+                body, mesh=self.mesh,
                 in_specs=(shard,) * 7 + (rep,) + (shard,) + (rep,) * 3,
-                out_specs=(rep, rep, rep)))
+                out_specs=(rep, rep, rep), check_vma=False))
             self._fns[key] = fn
         return fn
 
@@ -1252,9 +1251,9 @@ class MeshSubstrate:
                            beam_width=beam_width, precision=precision,
                            use_live=use_live)
             shard, rep = P(self.axis), P()
-            fn = jax.jit(shard_map_compat(
-                body, self.mesh,
+            fn = jax.jit(jax.shard_map(
+                body, mesh=self.mesh,
                 in_specs=(shard,) * 8 + (rep,) + (shard,) + (rep,) * 8,
-                out_specs=(rep, rep, rep)))
+                out_specs=(rep, rep, rep), check_vma=False))
             self._fns[key] = fn
         return fn

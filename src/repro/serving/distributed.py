@@ -35,11 +35,14 @@ routes through the unified search substrate, and ``plan="auto"`` works on
 """
 from __future__ import annotations
 
+from concurrent.futures import ThreadPoolExecutor
+from contextlib import nullcontext
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.construction import build_rnsg
 from repro.search import (MeshSubstrate, SearchCache, SearchRequest,
@@ -64,21 +67,34 @@ class DistributedRFANN:
         self.n_shards = n_shards
         self.per = per
         self.attrs_sorted = as_       # global resolve happens over this
-        graphs = []
-        for s in range(n_shards):      # independently buildable (heredity)
+        # each shard is independently buildable (heredity); with a mesh,
+        # shard s is built on its own device, all shards at once
+        devs = None if mesh is None else list(mesh.devices.flat)
+
+        def build(s):
             sl = slice(s * per, (s + 1) * per)
-            g = build_rnsg(vs[sl], as_[sl], **build_kw)
-            graphs.append((g, order[sl]))
+            with (nullcontext() if devs is None
+                  else jax.default_device(devs[s])):
+                return build_rnsg(vs[sl], as_[sl], **build_kw), order[sl]
+        if devs is None:
+            graphs = [build(s) for s in range(n_shards)]
+        else:
+            with ThreadPoolExecutor(n_shards) as ex:
+                graphs = list(ex.map(build, range(n_shards)))
         self.shard_span = np.asarray(
             [[g.attrs[0], g.attrs[-1]] for g, _ in graphs], np.float32)
-        stack = lambda f: jnp.asarray(np.stack([f(g, o) for g, o in graphs]))  # noqa: E731
+        # with a mesh, every stacked array is placed shard-per-device along
+        # the data axis (never stacked onto one device first)
+        place = (jnp.asarray if mesh is None else
+                 lambda a: jax.device_put(a, NamedSharding(mesh, P(axis))))
+        stack = lambda f: place(np.stack([f(g, o) for g, o in graphs]))  # noqa: E731
         self.vecs = stack(lambda g, o: g.vecs)
         self.nbrs = stack(lambda g, o: g.nbrs)
         self.attrs = stack(lambda g, o: g.attrs)
         self.rmq = stack(lambda g, o: g.rmq)
         self.dist_c = stack(lambda g, o: g.dist_c)
         self.order = stack(lambda g, o: o[g.order].astype(np.int32))
-        self.rank0 = jnp.asarray(
+        self.rank0 = place(
             np.arange(n_shards, dtype=np.int32)[:, None] * per)   # (S, 1)
         self.build_seconds = sum(g.build_seconds for g, _ in graphs)
         self.async_dispatch = async_dispatch
@@ -96,14 +112,27 @@ class DistributedRFANN:
     def substrates(self):
         """One unified search substrate per shard (local execution path)."""
         if self._subs is None:
+            sh = self._shard
             self._subs = [
-                SearchSubstrate(self.vecs[s], self.nbrs[s], self.rmq[s],
-                                self.dist_c[s], np.asarray(self.order[s]),
-                                np.asarray(self.attrs[s]),
+                SearchSubstrate(sh(self.vecs, s), sh(self.nbrs, s),
+                                sh(self.rmq, s), sh(self.dist_c, s),
+                                np.asarray(sh(self.order, s)),
+                                np.asarray(sh(self.attrs, s)),
                                 cache=self._cache, cache_ns=s,
                                 metrics=self._metrics)
                 for s in range(self.n_shards)]
         return self._subs
+
+    @staticmethod
+    def _shard(a: jax.Array, s: int) -> jax.Array:
+        """Shard ``s`` of a stacked array — on its own device when the
+        stack is placed across a mesh (so each shard's substrate runs
+        there), else a slice of the one-device stack."""
+        if isinstance(a.sharding, NamedSharding):
+            for piece in a.addressable_shards:
+                if (piece.index[0].start or 0) == s:
+                    return piece.data[0]
+        return a[s]
 
     @property
     def mesh_substrate(self) -> MeshSubstrate:

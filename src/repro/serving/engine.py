@@ -301,8 +301,12 @@ class RFANNEngine:
                      and self._batch_seq % self.trace_sample_every == 0
                      else None)
             t_res = time.perf_counter()
-            lo, hi = (index.rank_range(rg)
-                      if hasattr(index, "rank_range") else (None, None))
+            try:
+                lo, hi = (index.rank_range(rg)
+                          if hasattr(index, "rank_range") else (None, None))
+            except Exception as e:          # noqa: BLE001 — a bad batch
+                self._fail_batch(batch, e)  # fails its own futures, the
+                continue                    # engine keeps serving
             resolve_ms = (time.perf_counter() - t_res) * 1e3
             self._m_resolve.observe(resolve_ms)
             if trace is not None:
@@ -329,52 +333,60 @@ class RFANNEngine:
             except queue.Empty:
                 continue
             self._m_hdepth.set(self._dq.qsize())
-            with self._index_lock:
-                index = self.index
-            # beam_width=1 is omitted so indexes predating the batched-
-            # expansion API (baselines, external wrappers) keep working
-            kw = dict(k=self.k, ef=self.ef, plan=self.plan)
-            if self.beam_width != 1:
-                kw["beam_width"] = self.beam_width
-            if self.precision != "f32":     # same omission back-compat rule
-                kw["precision"] = self.precision
-            if trace is not None:
-                kw["trace"] = trace
             try:
-                res = self._run_search(index, qv, rg, lo, hi, r_index, kw)
-            except TypeError:
-                if "trace" not in kw:       # genuine signature error
-                    raise
-                kw.pop("trace")             # index predates the trace API
-                res = self._run_search(index, qv, rg, lo, hi, r_index, kw)
-            if not hasattr(res, "row"):     # tuple-returning index
-                from repro.search import SearchResult
-                res = SearchResult(np.asarray(res[0]), np.asarray(res[1]), {})
-            if "strategy" in res.stats:
-                from repro.planner import SCAN
-                self.stats.scan_routed += int(
-                    (np.asarray(res.stats["strategy"]) == SCAN).sum())
-            self.stats.cache_hits += int(res.stats.get("cache_hits", 0))
-            self.stats.dedup_hits += int(res.stats.get("batch_dedup", 0))
-            now = time.perf_counter()
-            lats = [(now - t0) * 1e3 for (_, _, t0, _) in batch]
-            # account BEFORE resolving futures: a client that holds its
-            # result must see the stats/metrics that include its request
-            for ms in lats:
-                self.stats.record_latency(ms)
-            self.stats.served += len(batch)
-            self.stats.batches += 1
-            self._m_e2e.observe_many(lats)
-            self._m_batch_size.observe(len(batch))
-            self._m_requests.inc(len(batch))
-            self._m_batches.inc()
-            if trace is not None:
-                self.last_trace = trace
-            for i, (_, _, _, fut) in enumerate(batch):
-                fut.set_result(res.row(i))
-            if self.log_interval and now - self._last_log >= self.log_interval:
-                self._last_log = now
-                print(format_stats_line(self.metrics()), flush=True)
+                self._serve_batch(batch, qv, rg, lo, hi, r_index, trace)
+            except Exception as e:          # noqa: BLE001 — a failed
+                # dispatch fails its futures with the error instead of
+                # killing this thread and leaving every caller blocked
+                self._fail_batch(batch, e)
+
+    def _serve_batch(self, batch, qv, rg, lo, hi, r_index, trace):
+        with self._index_lock:
+            index = self.index
+        # beam_width=1 is omitted so indexes predating the batched-
+        # expansion API (baselines, external wrappers) keep working
+        kw = dict(k=self.k, ef=self.ef, plan=self.plan)
+        if self.beam_width != 1:
+            kw["beam_width"] = self.beam_width
+        if self.precision != "f32":     # same omission back-compat rule
+            kw["precision"] = self.precision
+        if trace is not None:
+            kw["trace"] = trace
+        try:
+            res = self._run_search(index, qv, rg, lo, hi, r_index, kw)
+        except TypeError:
+            if "trace" not in kw:       # genuine signature error
+                raise
+            kw.pop("trace")             # index predates the trace API
+            res = self._run_search(index, qv, rg, lo, hi, r_index, kw)
+        if not hasattr(res, "row"):     # tuple-returning index
+            from repro.search import SearchResult
+            res = SearchResult(np.asarray(res[0]), np.asarray(res[1]), {})
+        if "strategy" in res.stats:
+            from repro.planner import SCAN
+            self.stats.scan_routed += int(
+                (np.asarray(res.stats["strategy"]) == SCAN).sum())
+        self.stats.cache_hits += int(res.stats.get("cache_hits", 0))
+        self.stats.dedup_hits += int(res.stats.get("batch_dedup", 0))
+        now = time.perf_counter()
+        lats = [(now - t0) * 1e3 for (_, _, t0, _) in batch]
+        # account BEFORE resolving futures: a client that holds its
+        # result must see the stats/metrics that include its request
+        for ms in lats:
+            self.stats.record_latency(ms)
+        self.stats.served += len(batch)
+        self.stats.batches += 1
+        self._m_e2e.observe_many(lats)
+        self._m_batch_size.observe(len(batch))
+        self._m_requests.inc(len(batch))
+        self._m_batches.inc()
+        if trace is not None:
+            self.last_trace = trace
+        for i, (_, _, _, fut) in enumerate(batch):
+            fut.set_result(res.row(i))
+        if self.log_interval and now - self._last_log >= self.log_interval:
+            self._last_log = now
+            print(format_stats_line(self.metrics()), flush=True)
 
     def _run_search(self, index, qv, rg, lo, hi, r_index, kw):
         if index is not r_index or lo is None:
@@ -384,11 +396,11 @@ class RFANNEngine:
         return index.search_ranks(qv, lo, hi, **kw)
 
     @staticmethod
-    def _fail_batch(batch) -> None:
+    def _fail_batch(batch, exc: Optional[BaseException] = None) -> None:
         for _, _, _, fut in batch:
             if not fut.done():
-                fut.set_exception(RuntimeError("engine closed before "
-                                               "this request was served"))
+                fut.set_exception(exc if exc is not None else RuntimeError(
+                    "engine closed before this request was served"))
 
     def close(self):
         self._stop.set()

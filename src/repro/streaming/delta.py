@@ -17,8 +17,10 @@ retrace).
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -30,6 +32,29 @@ _ROW_TILE = 128         # must match repro.kernels.range_scan.ROW_TILE
 
 def _next_pow2(x: int) -> int:
     return 1 << max(int(x) - 1, 1).bit_length()
+
+
+@functools.partial(jax.jit, static_argnames=("k",))
+def _window_topk(x, starts, lens, q, *, k: int, live):
+    """Exact masked top-k over the whole delta for k > 128, which the
+    scan kernel's 128-lane running top-k cannot hold (the delta has no
+    beam path to fall back on).  Same contract as ``range_scan`` over the
+    whole buffer: (Q, k) ranks (-1 pad) and distances; distance ties
+    break toward lower rank."""
+    rank = jnp.arange(x.shape[0], dtype=jnp.int32)[None, :]
+    dot = jax.lax.dot_general(q, x, (((1,), (1,)), ((), ())),
+                              precision=jax.lax.Precision.HIGHEST)
+    d = (jnp.sum(q * q, axis=1, keepdims=True) - 2.0 * dot
+         + jnp.sum(x * x, axis=1)[None, :])
+    valid = ((rank >= starts[:, None]) & (rank < (starts + lens)[:, None])
+             & (live != 0))
+    d = jnp.where(valid, jnp.maximum(d, 0.0), jnp.inf)
+    kk = min(k, x.shape[0])
+    neg, idx = jax.lax.top_k(-d, kk)
+    idx = jnp.where(jnp.isfinite(neg), idx, -1).astype(jnp.int32)
+    pad = k - kk
+    return (jnp.pad(idx, ((0, 0), (0, pad)), constant_values=-1),
+            jnp.pad(-neg, ((0, 0), (0, pad)), constant_values=jnp.inf))
 
 
 class DeltaView:
@@ -117,9 +142,11 @@ class DeltaView:
         lens[:nq] = np.clip(hi.astype(np.int64) - lo + 1, 0, cap)
         qp = np.zeros((pad_q, d_pad), np.float32)
         qp[:nq, :qv.shape[1]] = qv
-        ids_r, dists = range_scan(x_pad, jnp.asarray(starts),
-                                  jnp.asarray(lens), jnp.asarray(qp),
-                                  bucket=cap, k=k, live=live_row)
+        ops = (x_pad, jnp.asarray(starts), jnp.asarray(lens), jnp.asarray(qp))
+        if k <= _ROW_TILE:
+            ids_r, dists = range_scan(*ops, bucket=cap, k=k, live=live_row)
+        else:
+            ids_r, dists = _window_topk(*ops, k=k, live=live_row)
         ids_r = np.asarray(ids_r)[:nq]
         dists = np.asarray(dists)[:nq]
         ext = np.where(ids_r >= 0, self.ids[np.maximum(ids_r, 0)], -1)

@@ -140,6 +140,7 @@ class StreamingRFANN:
         self._ops_since_compact = 0
         self._compacting = threading.Event()
         self._worker: Optional[threading.Thread] = None
+        self._compact_error: Optional[Exception] = None
         self.compactions = 0
         self.build_seconds = 0.0
         self._wal: Optional[WriteAheadLog] = None
@@ -603,7 +604,9 @@ class StreamingRFANN:
     def compact(self, wait: bool = True) -> bool:
         """Rebuild the base from the live set on a worker thread and
         hot-swap it.  Returns False when a compaction is already running
-        or there is nothing to fold in."""
+        or there is nothing to fold in.  With ``wait`` a failed rebuild
+        raises here (``RuntimeError`` chained to the worker's error);
+        a failed background rebuild raises at :meth:`close`."""
         with self._lock:
             if self._compacting.is_set():
                 if wait and self._worker is not None:
@@ -624,7 +627,13 @@ class StreamingRFANN:
                 w.start()
         if wait:
             w.join()
+            self._raise_compact_error()
         return True
+
+    def _raise_compact_error(self) -> None:
+        err, self._compact_error = self._compact_error, None
+        if err is not None:
+            raise RuntimeError(f"compaction failed: {err!r}") from err
 
     def _compact_run(self, v0: SegmentView) -> None:
         try:
@@ -678,6 +687,8 @@ class StreamingRFANN:
                 except Exception as e:      # noqa: BLE001 — degrade, log
                     warnings.warn(f"post-compaction checkpoint to "
                                   f"{self._ckpt_path} failed: {e}")
+        except Exception as e:      # noqa: BLE001 — surfaced, not printed:
+            self._compact_error = e     # compact(wait)/close() raise it
         finally:
             self._compacting.clear()
 
@@ -698,6 +709,7 @@ class StreamingRFANN:
                     pass        # a dead disk at shutdown changes nothing
                 self._wal.close()
                 self._wal = None
+        self._raise_compact_error()
 
     # ------------------------------------------------------------- export
     def live_items(self) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:

@@ -28,7 +28,7 @@ def test_sharded_train_step_moe_ep():
         from repro.configs.registry import get_smoke_config
         from repro.models.lm import Model
         from repro.models.params import ShardPlan, logical_axes
-        from repro.parallel.sharding import (make_act_sharder, set_mesh_compat,
+        from repro.parallel.sharding import (make_act_sharder,
                                              tree_shardings,
                                              batch_logical, spec_for_logical)
         from repro.launch.specs import concrete_batch
@@ -51,11 +51,11 @@ def test_sharded_train_step_moe_ep():
         bsh = {k: NamedSharding(mesh, spec_for_logical(blog[k], v.shape, mesh))
                for k, v in batch.items()}
         batch = jax.device_put(batch, bsh)
-        with set_mesh_compat(mesh):
+        with jax.set_mesh(mesh):
             state2, m = jax.jit(build_train_step(model))(state, batch)
         assert np.isfinite(float(m["loss"])), m
         # MoE EP path must actually emit an all-to-all
-        with set_mesh_compat(mesh):
+        with jax.set_mesh(mesh):
             txt = jax.jit(build_train_step(model)).lower(state, batch).compile().as_text()
         assert "all-to-all" in txt, "expected EP all-to-all in HLO"
         print("OK", float(m["loss"]))
@@ -71,7 +71,6 @@ def test_moe_ep_sharded_matches_local():
         from repro.models.lm import Model
         from repro.models.params import ShardPlan, resolve_dims
         from repro.models.moe import moe_ffn
-        from repro.parallel.sharding import set_mesh_compat
         cfg = dataclasses.replace(get_smoke_config("mixtral-8x7b"), dtype="float32")
         mesh = jax.make_mesh((2, 2), ("data", "model"))
         dm = resolve_dims(cfg, ShardPlan(tp=2, fsdp=2))
@@ -85,7 +84,7 @@ def test_moe_ep_sharded_matches_local():
              "w_out": jnp.asarray(rng.standard_normal((e, f, d)) * .1, jnp.float32),
              "norm": jnp.ones((d,), jnp.float32)}
         y_local, _ = moe_ffn(x, p, cfg, dm, mesh=None)
-        with set_mesh_compat(mesh):
+        with jax.set_mesh(mesh):
             y_shard, _ = jax.jit(lambda x, p: moe_ffn(x, p, cfg, dm, mesh=mesh))(x, p)
         err = float(jnp.max(jnp.abs(y_local - y_shard)))
         assert err < 1e-4, err
@@ -229,7 +228,6 @@ def test_gpipe_pipeline_fwd_and_grad_parity():
     out = _run("""
         import numpy as np, jax, jax.numpy as jnp
         from repro.parallel.pipeline import gpipe
-        from repro.parallel.sharding import set_mesh_compat
         mesh = jax.make_mesh((4,), ("pp",))
         S, M, B, D = 4, 8, 2, 16
         rng = np.random.default_rng(0)
@@ -238,7 +236,7 @@ def test_gpipe_pipeline_fwd_and_grad_parity():
         x = jnp.asarray(rng.standard_normal((M, B, D)), jnp.float32)
         stage_fn = lambda p, h: jnp.tanh(h @ p["w"] + p["b"])
         pipe = gpipe(stage_fn, mesh, "pp", S, M)
-        with set_mesh_compat(mesh):
+        with jax.set_mesh(mesh):
             y = jax.jit(pipe)(params, x)
         ref = x
         for s in range(S):
@@ -250,7 +248,7 @@ def test_gpipe_pipeline_fwd_and_grad_parity():
             for s in range(S):
                 h = jnp.tanh(h @ p["w"][s] + p["b"][s])
             return jnp.sum(h ** 2)
-        with set_mesh_compat(mesh):
+        with jax.set_mesh(mesh):
             g1 = jax.jit(jax.grad(loss_pipe))(params)
         g2 = jax.grad(loss_ref)(params)
         err = max(float(jnp.max(jnp.abs(a - b)))

@@ -251,3 +251,66 @@ def test_engine_serves_with_planner():
     eng.close()
     assert len(res) == 24 and all(r[0].shape == (5,) for r in res)
     assert eng.stats.scan_routed > 0
+
+
+# -------------------------------------------------------- k beyond the scan
+def test_range_scan_rejects_k_beyond_lane_row():
+    """k > 128 cannot live in the scan's running top-k row: the kernel
+    raises instead of silently materializing the jnp oracle."""
+    _, xp, d_pad = _padded(300, 8)
+    with pytest.raises(ValueError, match="running top-k"):
+        range_scan(jnp.asarray(xp), jnp.zeros(2, jnp.int32),
+                   jnp.full(2, 200, jnp.int32),
+                   jnp.zeros((2, d_pad), jnp.float32), bucket=256, k=200)
+
+
+def test_planner_routes_k_beyond_scan_to_beam():
+    """Every query with k > 128 — narrow, empty or forced-beam — routes to
+    beam, and the served auto path answers it end to end."""
+    from repro.planner import BEAM, SCAN
+    from repro.planner.planner import SCAN_MAX_K
+    p = QueryPlanner(4096, 16.0)
+    lo = np.asarray([0, 10, 7])
+    hi = np.asarray([3, 400, 5])                   # tiny, narrow, empty
+    for mode in ("auto", "beam"):
+        plan = p.plan_batch(lo, hi, k=SCAN_MAX_K + 1, ef=256, mode=mode)
+        assert (plan.strategy == BEAM).all(), mode
+        assert all(part.kind == "beam" for part in plan.partitions)
+    assert p.choose_strategy(3, k=SCAN_MAX_K + 1, ef=256) == BEAM
+    assert p.choose_strategy(3, k=SCAN_MAX_K, ef=256) == SCAN
+    vecs, attrs, idx = _small_index(n=512)
+    qv = make_vectors(4, 16, seed=11)
+    ranges = selectivity_ranges(attrs, 4, 0.5, seed=12)
+    ids, dists, st = idx.search(qv, ranges, k=150, ef=150, plan="auto")
+    assert ids.shape == (4, 150) and (st["strategy"] == BEAM).all()
+    assert (ids >= 0).sum(axis=1).min() > 128      # a genuinely large k
+
+
+# ------------------------------------------------------- engine failures
+class _FailingIndex:
+    """An index whose dispatch raises (a device or kernel error)."""
+
+    def search(self, queries, attr_ranges, **kw):
+        raise RuntimeError("injected dispatch failure")
+
+
+def test_engine_dispatch_error_fails_futures():
+    """A dispatch that raises must fail that batch's futures with the
+    error — never kill the dispatcher and leave callers blocked — and the
+    engine keeps serving later batches."""
+    from repro.serving.engine import RFANNEngine
+    _, attrs, idx = _small_index(n=512)
+    eng = RFANNEngine(_FailingIndex(), k=5, ef=32, max_batch=4,
+                      max_wait_ms=1)
+    try:
+        futs = [eng.submit(np.zeros(16, np.float32), (0.0, 1.0))
+                for _ in range(6)]
+        for f in futs:
+            with pytest.raises(RuntimeError, match="injected dispatch"):
+                f.result(timeout=60)
+        eng.swap_index(idx)                        # dispatcher still alive
+        r = eng.submit(make_vectors(1, 16, seed=3)[0],
+                       (float(attrs.min()), float(attrs.max())))
+        assert r.result(timeout=120).ids.shape == (5,)
+    finally:
+        eng.close()

@@ -204,3 +204,24 @@ def test_nndescent_build_matches_exact_quality():
     re_ = recall_at_k(ix_e.search(qv, ranges, k=10, ef=64)[0], gt)
     rn = recall_at_k(ix_n.search(qv, ranges, k=10, ef=64)[0], gt)
     assert rn > re_ - 0.05, (re_, rn)
+
+
+# ------------------------------------------------------------ exact KNN top-k
+@pytest.mark.parametrize("b,n,k", [(7, 512, 32), (5, 4096, 32), (3, 512, 500),
+                                   (4, 1024, 1), (6, 2048, 100)])
+@pytest.mark.parametrize("ties", [False, True])
+def test_topk_smallest_matches_full_row_top_k(b, n, k, ties):
+    """The group-minimum top-k of the KNN build returns exactly what
+    ``lax.top_k`` over the whole row returns — ids, values and the
+    lower-index-first tie order — including massive ties and +inf."""
+    import jax
+    from repro.index.knn import topk_smallest
+    rng = np.random.default_rng(b * n + k)
+    d = rng.standard_normal((b, n)).astype(np.float32)
+    if ties:
+        d = np.round(d * 2) / 2
+        d[:, ::7] = np.inf
+    want_v, want_i = jax.lax.top_k(-jnp.asarray(d), k)
+    got_v, got_i = topk_smallest(jnp.asarray(d), k)
+    assert np.array_equal(np.asarray(got_i), np.asarray(want_i))
+    assert np.array_equal(np.asarray(got_v), -np.asarray(want_v))

@@ -363,3 +363,91 @@ def test_serve_sigterm_drains_and_restarts(tmp_path):
                           check=True).stdout.decode(errors="replace")
     assert "restored index" in out2
     assert "replayed" in out2
+
+
+# ------------------------------------------------------- compaction failure
+def test_failed_compaction_raises_not_prints(monkeypatch):
+    """A rebuild that fails on the worker thread surfaces as an error to
+    ``compact(wait=True)`` — and a failed background rebuild to
+    ``close()`` — instead of only printing a thread traceback; the served
+    view stays the pre-compaction one."""
+    import repro.streaming.streaming as smod
+    rng = np.random.default_rng(1)
+    s = StreamingRFANN(rng.standard_normal((96, 8)).astype(np.float32),
+                       rng.random(96).astype(np.float32), m=8,
+                       max_delta=1 << 20)
+    for j in range(4):
+        s.insert(rng.standard_normal(8).astype(np.float32), float(j) / 4)
+
+    def boom(*a, **kw):
+        raise MemoryError("injected rebuild failure")
+    monkeypatch.setattr(smod, "build_rnsg", boom)
+    with pytest.raises(RuntimeError, match="compaction failed") as ei:
+        s.compact(wait=True)
+    assert isinstance(ei.value.__cause__, MemoryError)
+    assert s.stats()["n_delta"] == 4 and s.compactions == 0
+    assert s.compact(wait=False)                   # background: raised at
+    s._worker.join()                               # close, not swallowed
+    with pytest.raises(RuntimeError, match="compaction failed"):
+        s.close()
+
+
+# ------------------------------------------------------------- k > 128
+@pytest.mark.parametrize("m", [20, 300])
+def test_delta_search_exact_above_scan_k(m):
+    """k > 128 exceeds the scan kernel's running top-k row; the delta
+    serves it through its own exact window top-k (also when k exceeds the
+    delta's padded capacity) with the scan's contract: ids and distances
+    of the k nearest rows in the range, -1/+inf padded."""
+    from repro.streaming.delta import DeltaView
+    rng = np.random.default_rng(m)
+    d, k = 8, 150
+    view = DeltaView.empty(d)
+    for j in range(m):
+        view = view.with_inserted(rng.standard_normal(d), float(rng.random()),
+                                  1000 + j)
+    qv = rng.standard_normal((3, d)).astype(np.float32)
+    ar = np.asarray([[0.0, 1.0], [0.2, 0.7], [2.0, 3.0]], np.float32)
+    ids, dists = view.search(qv, ar, k)
+    assert ids.shape == dists.shape == (3, k)
+    for i in range(3):
+        inr = (view.attrs >= ar[i, 0]) & (view.attrs <= ar[i, 1])
+        dd = ((view.vecs[inr].astype(np.float64) - qv[i]) ** 2).sum(1)
+        o = np.argsort(dd, kind="stable")[:k]
+        want = view.ids[inr][o]
+        got = ids[i][ids[i] >= 0]
+        assert set(got.tolist()) == set(want.tolist())
+        np.testing.assert_allclose(np.sort(dists[i][:len(got)]), dd[o],
+                                   rtol=1e-4, atol=1e-4)
+        assert np.isinf(dists[i][len(got):]).all()
+
+
+def test_streaming_search_above_scan_k_with_delta():
+    """A streaming query with k > 128 and a non-empty delta is served
+    (base through beam, delta exactly) and returns no tombstoned id."""
+    rng = np.random.default_rng(8)
+    n0, d, k = 192, 8, 150
+    s = StreamingRFANN(rng.standard_normal((n0, d)).astype(np.float32),
+                       rng.random(n0).astype(np.float32), m=8,
+                       max_delta=10**9)
+    eng = RFANNEngine(s, k=k, ef=256, plan="auto", max_wait_ms=0.5)
+    try:
+        for j in range(40):
+            eng.insert(rng.standard_normal(d).astype(np.float32),
+                       float(rng.random()))
+        dead = {3, 17, n0 + 5}
+        for e in dead:
+            eng.delete(e)
+        q = rng.standard_normal(d).astype(np.float32)
+        res = eng.submit(q, (0.0, 1.0)).result(timeout=60)
+        assert len(res.ids) == k
+        got = {int(i) for i in res.ids if i >= 0}
+        assert not got & dead
+        lv, _, li = s.live_items()
+        dd = ((lv.astype(np.float64) - q) ** 2).sum(1)
+        want = set(li[np.argsort(dd, kind="stable")[:k]].tolist())
+        assert len(got & want) >= 0.95 * k
+        assert got & set(range(n0, n0 + 40))      # delta rows were merged
+    finally:
+        eng.close()
+        s.close()

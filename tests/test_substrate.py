@@ -135,8 +135,8 @@ def test_prefetcher_preserves_order():
 
 # ---------------------------------------------------------------- resolver
 def test_resolver_divisibility_and_conflicts():
-    from repro.parallel.sharding import abstract_mesh
-    mesh = abstract_mesh((4, 2), ("data", "model"))
+    from jax.sharding import AbstractMesh
+    mesh = AbstractMesh((4, 2), ("data", "model"))
     # divisible both dims
     assert spec_for_logical(("fsdp", "tp"), (8, 6), mesh) == \
         jax.sharding.PartitionSpec("data", "model")
@@ -148,7 +148,7 @@ def test_resolver_divisibility_and_conflicts():
             (part if isinstance(part, tuple) else (part,))]
     assert len(flat) == len(set(flat))
     # batch over (pod, data) prefix logic
-    mesh3 = abstract_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh3 = AbstractMesh((2, 2, 2), ("pod", "data", "model"))
     assert spec_for_logical(("batch",), (4,), mesh3) == \
         jax.sharding.PartitionSpec(("pod", "data"))
     # FSDP strategy: batch spreads over (data, model) when pod doesn't divide
