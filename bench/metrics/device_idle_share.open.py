@@ -1,0 +1,8 @@
+"""Device: share of the traced window in which no operation ran on the
+device (1 - union of op intervals / window), in percent; open loop."""
+
+
+def read(ctx):
+    if ctx.trace is None or ctx.cell.mix["loop"] != "open":
+        return None
+    return 100.0 * ctx.trace.idle_share
