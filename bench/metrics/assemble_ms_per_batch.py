@@ -1,0 +1,10 @@
+"""Substrate stitch layer: mean host wall per batch of assembling its
+result — request-order scatter, id remap, cache, dispatch histograms and
+cost-model feedback (``stage_assemble_ms`` sum over count)."""
+
+
+def read(ctx):
+    h = ctx.hist("stage_assemble_ms")
+    if h is None or h[1] <= 0:
+        return None
+    return h[0] / h[1]

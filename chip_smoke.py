@@ -210,7 +210,7 @@ def serve(index, qv, ranges, clock: CompileClock, tag: str, *,
     futs = [eng.submit(q, r) for q, r in zip(qv, ranges)]
     rows = [f.result(timeout=900) for f in futs]
     wall = time.perf_counter() - t0
-    s = eng.stats.summary()
+    s = eng.summary()
     if engine is None:
         eng.close()
     log(f"{tag}: {len(rows)} requests in {wall:.3f}s "
@@ -228,8 +228,11 @@ def layer_line(snap: dict) -> str:
     routing counters."""
     h, c = snap["histograms"], snap["counters"]
     parts = [f"{name}={h[name]['sum']:.1f}ms/{h[name]['count']}"
-             for name in ("engine_resolve_ms", "scan_dispatch_ms",
-                          "beam_dispatch_ms", "stitch_ms", "engine_e2e_ms")
+             for name in ("engine_queue_wait_ms", "engine_resolve_ms",
+                          "engine_handoff_wait_ms", "scan_dispatch_ms",
+                          "beam_dispatch_ms", "stage_scan_block_ms",
+                          "stage_beam_block_ms", "stage_assemble_ms",
+                          "engine_e2e_ms")
              if name in h]
     parts += [f"{name}={c[name]}" for name in
               ("scan_routed_total", "beam_routed_total", "pad_rows_total")

@@ -21,6 +21,13 @@ Two hot paths share one ``while_loop``-per-query / ``vmap``-over-batch shape:
   collisions only ever cause false *negatives*: a forgotten node is
   re-scored, and the merge provably drops it (the pool's worst distance is
   monotonically non-increasing once full), so results stay exact.
+
+Each hop's phases run under ``jax.named_scope`` — ``beam.expand`` (pick the
+node(s) to expand, gather and score their neighbors), ``beam.visited``
+(the visited-set test and update), ``beam.merge`` (fold the fresh
+candidates into the pool) and ``beam.finish`` (tombstone filter, top-k or
+rerank) — so the compiled program's op metadata, and a device profile,
+name the phase each op belongs to.
 """
 from __future__ import annotations
 
@@ -247,31 +254,38 @@ def beam_search_batch(vecs: jax.Array, nbrs: jax.Array, qv: jax.Array,
 
         def body(st):
             cand_d, expanded, cand_ids, visited, steps, ndist = st
-            unexp = jnp.where(~expanded, cand_d, INF)
-            bi = jnp.argmin(unexp)
-            expanded = expanded.at[bi].set(True)
-            node = jnp.maximum(cand_ids[bi], 0)
-            nb = nbrs[node]                                   # (m,)
-            valid = (nb >= 0) & (nb >= L) & (nb <= R)
-            valid = valid & ~visited[jnp.maximum(nb, 0)]
-            visited = visited.at[jnp.where(valid, nb, n)].set(True)
-            d_nb = neighbor_dists(q, nb, valid)
-            ids_all = jnp.concatenate([cand_ids, nb.astype(jnp.int32)])
-            d_all = jnp.concatenate([cand_d, d_nb])
-            exp_all = jnp.concatenate([expanded, ~valid])     # invalid: never expand
-            order = jnp.argsort(d_all)[:ef]
-            return (d_all[order], exp_all[order], ids_all[order], visited,
-                    steps + 1, ndist + jnp.sum(valid))
+            with jax.named_scope("beam.expand"):
+                unexp = jnp.where(~expanded, cand_d, INF)
+                bi = jnp.argmin(unexp)
+                expanded = expanded.at[bi].set(True)
+                node = jnp.maximum(cand_ids[bi], 0)
+                nb = nbrs[node]                               # (m,)
+                valid = (nb >= 0) & (nb >= L) & (nb <= R)
+            with jax.named_scope("beam.visited"):
+                valid = valid & ~visited[jnp.maximum(nb, 0)]
+                visited = visited.at[jnp.where(valid, nb, n)].set(True)
+            with jax.named_scope("beam.expand"):
+                d_nb = neighbor_dists(q, nb, valid)
+            with jax.named_scope("beam.merge"):
+                ids_all = jnp.concatenate([cand_ids, nb.astype(jnp.int32)])
+                d_all = jnp.concatenate([cand_d, d_nb])
+                # invalid neighbors: never expand
+                exp_all = jnp.concatenate([expanded, ~valid])
+                order = jnp.argsort(d_all)[:ef]
+                return (d_all[order], exp_all[order], ids_all[order],
+                        visited, steps + 1, ndist + jnp.sum(valid))
 
         st = (cand_d, expanded, cand_ids, visited,
               jnp.zeros((), jnp.int32), jnp.zeros((), jnp.int32))
         cand_d, _, cand_ids, _, steps, ndist = jax.lax.while_loop(cond, body, st)
-        out_ids, out_d = _pool_finish(cand_d, cand_ids, live, k, quant)
+        with jax.named_scope("beam.finish"):
+            out_ids, out_d = _pool_finish(cand_d, cand_ids, live, k, quant)
         return out_ids, out_d, steps, ndist
 
     ids, dists, steps, ndist = jax.vmap(one_query)(qv, lo, hi, entry)
     if quant is not None:
-        ids, dists = rerank_pool(vecs, ids, qv, k, use_kernel)
+        with jax.named_scope("beam.finish"):
+            ids, dists = rerank_pool(vecs, ids, qv, k, use_kernel)
     return ids, dists, {"hops": steps, "ndist": ndist}
 
 
@@ -355,48 +369,58 @@ def _beam_batched(vecs, nbrs, qv, lo, hi, entry, *, k: int, ef: int,
 
         def body(st):
             cand_d, expanded, cand_ids, table, steps, ndist = st
-            # best B unexpanded: the pool is sorted, so they are the first
-            # B selectable lanes
-            lane = jnp.where(~expanded & jnp.isfinite(cand_d),
-                             jnp.arange(ef), ef)
-            lanes = jnp.sort(lane)[:B]                       # (B,)
-            take = lanes < ef
-            node = jnp.where(take, cand_ids[jnp.minimum(lanes, ef - 1)], -1)
-            expanded = expanded | jnp.any(
-                (jnp.arange(ef)[None, :] == lanes[:, None]) & take[:, None],
-                axis=0)
-            nb = nbrs[jnp.maximum(node, 0)]                  # (B, m)
-            ids_f = nb.reshape(F).astype(jnp.int32)
-            valid = ((ids_f >= 0) & (ids_f >= L) & (ids_f <= R)
-                     & jnp.repeat(node >= 0, m))
-            # intra-hop dedup: two expanded nodes may share a neighbor —
-            # keep the first occurrence (the legacy path never sees this:
-            # its single hop has unique neighbors)
-            eq = ids_f[:, None] == ids_f[None, :]
-            before = jnp.arange(F)[None, :] < jnp.arange(F)[:, None]
-            valid &= ~jnp.any(eq & before & valid[None, :], axis=1)
-            # pool-membership dedup: anything currently held in the pool is
-            # by definition already scored (covers hash evictions of live
-            # candidates — the exactness keystone, see module docstring)
-            valid &= ~jnp.any(ids_f[:, None] == cand_ids[None, :], axis=1)
-            # lossy visited set: false negatives fall through to a re-score
-            valid &= ~_table_lookup(table, ids_f, H)
-            table = _table_insert(table, jnp.where(valid, ids_f, -1), H)
-            fd, fi = fresh_sorted(q, ids_f, valid)
-            fe = fi < 0                                      # pads: never expand
-            cand_d, cand_ids, expanded = _merge_sorted(
-                cand_d, cand_ids, expanded, fd, fi, fe, ef)
-            return (cand_d, expanded, cand_ids, table,
-                    steps + 1, ndist + jnp.sum(valid))
+            with jax.named_scope("beam.expand"):
+                # best B unexpanded: the pool is sorted, so they are the
+                # first B selectable lanes
+                lane = jnp.where(~expanded & jnp.isfinite(cand_d),
+                                 jnp.arange(ef), ef)
+                lanes = jnp.sort(lane)[:B]                   # (B,)
+                take = lanes < ef
+                node = jnp.where(take,
+                                 cand_ids[jnp.minimum(lanes, ef - 1)], -1)
+                expanded = expanded | jnp.any(
+                    (jnp.arange(ef)[None, :] == lanes[:, None])
+                    & take[:, None], axis=0)
+                nb = nbrs[jnp.maximum(node, 0)]              # (B, m)
+                ids_f = nb.reshape(F).astype(jnp.int32)
+                valid = ((ids_f >= 0) & (ids_f >= L) & (ids_f <= R)
+                         & jnp.repeat(node >= 0, m))
+            with jax.named_scope("beam.visited"):
+                # intra-hop dedup: two expanded nodes may share a neighbor
+                # — keep the first occurrence (the legacy path never sees
+                # this: its single hop has unique neighbors)
+                eq = ids_f[:, None] == ids_f[None, :]
+                before = jnp.arange(F)[None, :] < jnp.arange(F)[:, None]
+                valid &= ~jnp.any(eq & before & valid[None, :], axis=1)
+                # pool-membership dedup: anything currently held in the
+                # pool is by definition already scored (covers hash
+                # evictions of live candidates — the exactness keystone,
+                # see module docstring)
+                valid &= ~jnp.any(ids_f[:, None] == cand_ids[None, :],
+                                  axis=1)
+                # lossy visited set: false negatives fall through to a
+                # re-score
+                valid &= ~_table_lookup(table, ids_f, H)
+                table = _table_insert(table, jnp.where(valid, ids_f, -1), H)
+            with jax.named_scope("beam.expand"):
+                fd, fi = fresh_sorted(q, ids_f, valid)
+            with jax.named_scope("beam.merge"):
+                fe = fi < 0                                  # pads: never expand
+                cand_d, cand_ids, expanded = _merge_sorted(
+                    cand_d, cand_ids, expanded, fd, fi, fe, ef)
+                return (cand_d, expanded, cand_ids, table,
+                        steps + 1, ndist + jnp.sum(valid))
 
         st = (cand_d, expanded, cand_ids, table,
               jnp.zeros((), jnp.int32), jnp.zeros((), jnp.int32))
         cand_d, _, cand_ids, _, steps, ndist = jax.lax.while_loop(
             cond, body, st)
-        out_ids, out_d = _pool_finish(cand_d, cand_ids, live, k, quant)
+        with jax.named_scope("beam.finish"):
+            out_ids, out_d = _pool_finish(cand_d, cand_ids, live, k, quant)
         return out_ids, out_d, steps, ndist
 
     ids, dists, steps, ndist = jax.vmap(one_query)(qv, lo, hi, entry)
     if quant is not None:
-        ids, dists = rerank_pool(vecs, ids, qv, k, use_kernel)
+        with jax.named_scope("beam.finish"):
+            ids, dists = rerank_pool(vecs, ids, qv, k, use_kernel)
     return ids, dists, {"hops": steps, "ndist": ndist}

@@ -107,11 +107,12 @@ class RNSGIndex:
         legacy single-node hop; B>1 fuses B node expansions per hop).
         precision: "f32" | "int8" | "bf16" — quantized scoring with a fused
         exact f32 rerank (same top-k id set as f32).
-        trace: optional ``repro.obs.QueryTrace`` — collects resolve / plan /
-        dispatch / stitch spans and rides back on the result.
+        trace: optional ``repro.obs.QueryTrace`` — collects the stages'
+        spans (resolve, plan, *_dispatch, assemble, ...) and rides back on
+        the result.
         Returns a ``SearchResult`` (tuple-compatible: ids, dists, stats)."""
-        from repro.obs import maybe_span
-        with maybe_span(trace, "resolve") as sp:
+        from repro.obs import stage
+        with stage("resolve", None, trace) as sp:
             lo, hi = self.rank_range(attr_ranges)
             sp.attrs.update(
                 q=len(np.atleast_2d(queries)), n=self.g.n,

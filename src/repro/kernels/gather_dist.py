@@ -131,6 +131,7 @@ def gather_dist_pallas(x: jax.Array, ids: jax.Array, q: jax.Array, *,
     out = pl.pallas_call(
         functools.partial(kernel, tile=tile),
         grid_spec=grid_spec,
+        name="gather_dist_pallas",     # stable op name in device profiles
         out_shape=jax.ShapeDtypeStruct((nt, 1, tile), jnp.float32),
         interpret=interpret,
     )(ids_c, *ops)
@@ -248,6 +249,7 @@ def gather_topk_pallas(x: jax.Array, ids: jax.Array, q: jax.Array, *,
     od, oi = pl.pallas_call(
         functools.partial(kernel, tile=tile, k=k),
         grid_spec=grid_spec,
+        name="gather_topk_pallas",     # stable op name in device profiles
         out_shape=(jax.ShapeDtypeStruct((1, tile), jnp.float32),
                    jax.ShapeDtypeStruct((1, tile), jnp.int32)),
         interpret=interpret,
@@ -329,6 +331,7 @@ def gather_rerank_pallas(x: jax.Array, ids: jax.Array, q: jax.Array, *,
     od, oi = pl.pallas_call(
         functools.partial(_rerank_kernel, tile=tile, k=k, mp=mp),
         grid_spec=grid_spec,
+        name="gather_rerank_pallas",   # stable op name in device profiles
         out_shape=(jax.ShapeDtypeStruct((Q, 1, tile), jnp.float32),
                    jax.ShapeDtypeStruct((Q, 1, tile), jnp.int32)),
         interpret=interpret,

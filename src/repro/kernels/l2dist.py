@@ -52,6 +52,7 @@ def l2dist_pallas(q: jax.Array, x: jax.Array, *, tq: int = 128, tn: int = 128,
     out = pl.pallas_call(
         functools.partial(_kernel, nd=nd),
         grid=grid,
+        name="l2dist_pallas",          # stable op name in device profiles
         in_specs=[
             pl.BlockSpec((tq, td), lambda i, j, k: (i, k)),
             pl.BlockSpec((tn, td), lambda i, j, k: (j, k)),

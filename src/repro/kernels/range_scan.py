@@ -196,6 +196,7 @@ def range_scan_pallas(x: jax.Array, starts: jax.Array, lens: jax.Array,
     dists, ids = pl.pallas_call(
         functools.partial(kernel, nd=nd, tb=tb, k=k, n_valid=n_valid),
         grid_spec=grid_spec,
+        name="range_scan_pallas",      # stable op name in device profiles
         out_shape=(jax.ShapeDtypeStruct((Q, 1, tb), jnp.float32),
                    jax.ShapeDtypeStruct((Q, 1, tb), jnp.int32)),
         interpret=interpret,

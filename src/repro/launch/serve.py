@@ -227,7 +227,7 @@ def serve_rfann(args):
         rec = recall_at_k(results, gt)
     print(f"[serve] served {served} reqs in {dt:.2f}s "
           f"({served/dt:.0f} QPS) recall@{args.k}={rec:.4f}")
-    print(f"[serve] {engine.stats.summary()}")
+    print(f"[serve] {engine.summary()}")
     return rec
 
 

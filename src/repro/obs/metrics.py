@@ -8,7 +8,8 @@ from substrate dispatch without contention:
   hot path — the registry lock is taken only on first get-or-create);
 * critical sections are a handful of arithmetic ops;
 * histograms accept **batched** observations (``observe_many``) so one
-  engine batch costs one lock acquisition, not one per request.
+  engine batch costs one lock acquisition, not one per request, and a
+  scalar ``observe`` takes a numpy-free path (``bisect`` over the edges).
 
 Histograms use **fixed log-scale buckets**: geometric bucket edges between
 ``lo`` and ``hi`` (values outside clamp into the first / overflow bucket).
@@ -26,6 +27,7 @@ from __future__ import annotations
 
 import math
 import threading
+from bisect import bisect_left
 from typing import Callable, Dict, Iterable, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -80,8 +82,8 @@ class Histogram:
     buckets) forever — a long-running server never grows it.  ``sum`` /
     ``min`` / ``max`` are tracked exactly, so the mean is exact and only
     the percentiles carry the bucket-resolution error."""
-    __slots__ = ("name", "help", "edges", "_counts", "_sum", "_min", "_max",
-                 "_count", "_lock")
+    __slots__ = ("name", "help", "edges", "_edge_list", "_counts", "_sum",
+                 "_min", "_max", "_count", "_lock")
 
     def __init__(self, name: str, help: str = "", *, lo: float = 1e-3,
                  hi: float = 6e4, growth: float = 1.25):
@@ -90,7 +92,9 @@ class Histogram:
         self.name, self.help = name, help
         n = int(math.ceil(math.log(hi / lo) / math.log(growth)))
         self.edges = lo * np.power(growth, np.arange(n + 1))  # upper edges
-        self._counts = np.zeros(n + 2, np.int64)              # +under/overflow
+        self._edge_list = self.edges.tolist()   # same float64 values
+        # +under/overflow; a list, so a scalar observe touches no numpy
+        self._counts = [0] * (n + 2)
         self._sum = 0.0
         self._min = math.inf
         self._max = -math.inf
@@ -98,7 +102,18 @@ class Histogram:
         self._lock = threading.Lock()
 
     def observe(self, v: float) -> None:
-        self.observe_many((v,))
+        """One value, without numpy: ``bisect_left`` over the edges is the
+        first edge >= v, the bucket ``observe_many``'s digitize picks."""
+        v = float(v)
+        i = bisect_left(self._edge_list, v)
+        with self._lock:
+            self._counts[i] += 1
+            self._sum += v
+            if v < self._min:
+                self._min = v
+            if v > self._max:
+                self._max = v
+            self._count += 1
 
     def observe_many(self, values: Iterable[float]) -> None:
         vals = np.asarray(list(values) if not isinstance(values, np.ndarray)
@@ -107,8 +122,12 @@ class Histogram:
             return
         # digitize(right=True) == first edge >= v: bucket index by upper edge
         idx = np.digitize(vals, self.edges, right=True)
+        add = np.bincount(idx, minlength=len(self._counts))
+        hit = np.flatnonzero(add).tolist()
         with self._lock:
-            np.add.at(self._counts, idx, 1)
+            counts = self._counts
+            for i in hit:
+                counts[i] += int(add[i])
             self._sum += float(vals.sum())
             self._min = min(self._min, float(vals.min()))
             self._max = max(self._max, float(vals.max()))
@@ -131,7 +150,7 @@ class Histogram:
             total = self._count
             if total == 0:
                 return 0.0
-            counts = self._counts.copy()
+            counts = np.asarray(self._counts, np.int64)
             vmin, vmax = self._min, self._max
         rank = max(1, int(math.ceil(p / 100.0 * total)))
         cum = np.cumsum(counts)
@@ -156,7 +175,7 @@ class Histogram:
         index 0 is already the first ``le`` bucket), the trailing +inf
         bucket the overflow, so the last cumulative count is the total."""
         with self._lock:
-            counts = self._counts.copy()
+            counts = np.asarray(self._counts, np.int64)
         cum = np.cumsum(counts)
         edges = np.concatenate([self.edges, [np.inf]])
         return edges, cum
@@ -211,6 +230,9 @@ class MetricsRegistry:
         return self._get(name, Gauge, help=help)
 
     def histogram(self, name: str, help: str = "", **kw) -> Histogram:
+        m = self._m.get(name)
+        if type(m) is Histogram:        # every stage exit looks one up
+            return m
         return self._get(name, Histogram, help=help, **kw)
 
     def register_producer(self, section: str, fn: Callable[[], dict]) -> None:

@@ -1,23 +1,14 @@
-"""``jax.profiler`` integration: host-span annotations + trace capture.
+"""``jax.profiler`` trace capture.
 
-``annotate(name)`` wraps a host-side region in a
-``jax.profiler.TraceAnnotation`` so device profiles (captured with
-``device_trace`` / ``make profile``) line up with the serve path's own
-span names — the kernel dispatch sites in ``repro.search.substrate`` use
-``rnsg.scan_dispatch`` / ``rnsg.beam_dispatch`` / ``rnsg.gather`` style
-names.  When no profiler session is active a ``TraceAnnotation`` is a few
-nanoseconds of overhead, so the annotations stay on unconditionally.
+Host spans on the profiler's clock come from ``repro.obs.stage`` (every
+stage opens an ``rnsg.<name>`` annotation); ``device_trace`` captures a
+session around a block so those spans line up with the device's ops.
 """
 from __future__ import annotations
 
 from contextlib import contextmanager
 
 import jax.profiler
-
-
-def annotate(name: str):
-    """Context manager marking a host region in the profiler timeline."""
-    return jax.profiler.TraceAnnotation(name)
 
 
 @contextmanager

@@ -48,9 +48,9 @@ class SearchRequest:
               installed (``install_quantized``).
     trace   : optional ``repro.obs.QueryTrace``.  When attached, every
               stage that touches the request appends a wall-timed span
-              (resolve / plan / dispatch / stitch) and the trace comes back
-              on the ``SearchResult``.  ``None`` (the default) keeps the
-              hot path to a single ``is None`` check.
+              (resolve / plan / *_dispatch / assemble, ...) and the trace
+              comes back on the ``SearchResult``.  ``None`` (the default)
+              keeps the hot path to a single ``is None`` check.
     live    : optional (n,) bool per-**rank** liveness mask (the streaming
               layer's tombstones; ``False`` = deleted).  Dead rows never
               appear in results but stay traversable routing nodes on the

@@ -1,25 +1,33 @@
-"""Dispatch + stitch stages: one strategy-routed execution layer.
+"""Plan, dispatch and assembly: one strategy-routed execution layer.
 
 ``SearchSubstrate`` owns the entire query path for one attribute-sorted
-corpus slice (a whole index, or one shard of a distributed one):
+corpus slice (a whole index, or one shard of a distributed one), as a run
+of leaf stages (``repro.obs.stage``; each lands in ``stage_<name>_ms`` and,
+under a profiler session, on the device trace as ``rnsg.<name>``):
 
-* ``resolve``  — attribute ranges -> rank intervals (``repro.search.resolve``);
-* cache        — when a ``SearchCache`` is installed, each request is split
+* ``resolve``  — attribute ranges -> rank intervals (``repro.search.resolve``,
+                 run by the caller);
+* ``plan``     — when a ``SearchCache`` is installed, each request is split
                  into hit rows (served from memory, no device work), unique
                  miss rows (executed), and intra-batch duplicates of a miss
-                 (executed once, fanned back out), stitched in request
-                 order;
-* dispatch     — ``graph`` runs the paper's beam search over the full batch;
+                 (executed once, fanned back out); ``graph`` runs the
+                 paper's beam search over the full batch, while
                  ``auto``/``scan``/``beam`` go through the adaptive planner,
                  which partitions the batch into fixed-shape jit dispatches
                  (fused Pallas ``range_scan`` | bucketed beam search);
-* stitch       — partition results land back in request order, rank ids are
-                 remapped to original corpus ids, and per-query stats
-                 (hops / ndist / strategy) are assembled.
+* per partition, ``scan_prep``/``beam_prep`` (host arrays, padding, device
+  copies, entry selection), ``scan_dispatch``/``beam_dispatch``/
+  ``graph_beam_dispatch`` (the enqueue) and ``scan_block``/``beam_block``
+  (the wait for the device and the copy back);
+* ``assemble`` — partition results land back in request order, rank ids
+                 are remapped to original corpus ids, per-query stats
+                 (hops / ndist / strategy) are assembled, the cache stores
+                 and assembles, and the dispatch histograms and cost model
+                 are fed.
 
 Dispatch is **asynchronous at the substrate boundary**: ``dispatch(req)``
 enqueues all device work (jax async dispatch) and returns a
-``PendingSearch`` whose ``result()`` blocks and stitches.  ``run`` is the
+``PendingSearch`` whose ``result()`` blocks and assembles.  ``run`` is the
 synchronous spelling (``dispatch(..., defer=False).result()``); the
 distributed local path dispatches every shard before blocking any of them,
 overlapping the per-shard device queues.  Deferred dispatches skip
@@ -62,8 +70,7 @@ from repro.kernels.ops import range_scan
 from repro.kernels.quantize import (QuantizedCorpus, quantize_corpus,
                                     rerank_depth)
 from repro.obs.metrics import MetricsRegistry
-from repro.obs.profiler import annotate
-from repro.obs.trace import maybe_span
+from repro.obs.stages import stage
 from repro.planner.bucketing import (ROW_TILE, bucket_for_len, next_pow2,
                                      pad_pow2, window_rows)
 from repro.planner.planner import BEAM, QueryPlanner, SCAN
@@ -90,7 +97,7 @@ class PendingSearch:
     """Handle for an in-flight substrate dispatch.
 
     The device work is already enqueued when this object exists (jax async
-    dispatch); ``result()`` blocks on the outputs, stitches, feeds the cost
+    dispatch); ``result()`` blocks on the outputs, assembles, feeds the cost
     model, and returns the ``SearchResult``.  Idempotent — repeated calls
     return the same object."""
     __slots__ = ("_finalize", "_result")
@@ -148,7 +155,7 @@ class SearchSubstrate:
 
     # ---------------------------------------------------------------- run
     def run(self, req: SearchRequest) -> SearchResult:
-        """Dispatch one request synchronously and stitch the result."""
+        """Dispatch one request synchronously and assemble the result."""
         return self.dispatch(req, defer=False).result()
 
     def dispatch(self, req: SearchRequest, *, defer: bool = True,
@@ -156,252 +163,235 @@ class SearchSubstrate:
         """Enqueue one request's device work and return a ``PendingSearch``.
 
         ``defer=True`` (the async path) enqueues every partition before any
-        block and skips wall-time calibration; ``defer=False`` reproduces
-        the synchronous per-partition dispatch+block loop, whose wall times
-        are clean enough to calibrate on.  Cache hits are resolved here —
-        a fully-hit request performs no device work at all.  ``q_digests``
+        block and skips wall-time calibration; ``defer=False`` blocks each
+        partition before dispatching the next, and its wall times are
+        clean enough to calibrate on.  Cache hits are resolved here — a
+        fully-hit request performs no device work at all.  ``q_digests``
         are optional precomputed ``hash_query`` values (the distributed
         local path hashes each query once, not once per shard).
 
-        A ``req.trace`` collects plan / dispatch / stitch spans (the stitch
-        span on a deferred dispatch includes the device block); the
-        installed ``MetricsRegistry`` (when any) counts routed queries,
-        cache outcomes and pad waste, and observes dispatch wall
-        histograms."""
-        qv = np.asarray(req.queries, np.float32)
-        lo = np.asarray(req.lo, np.int64)
-        hi = np.asarray(req.hi, np.int64)
-        k, ef, bw = int(req.k), int(req.ef), int(req.beam_width)
-        prec = req.precision
+        Every step runs in a leaf stage (``repro.obs.stage``): ``plan``
+        (counters, cache split, planner), then per partition ``*_prep``
+        (host arrays, padding, copies), ``*_dispatch`` (the enqueue) and
+        ``*_block`` (device wait and copy back), then ``assemble``
+        (request-order scatter, id remap, cache store and assembly, the
+        dispatch histograms and cost-model feedback).  A ``req.trace``
+        collects the same spans; the installed ``MetricsRegistry`` (when
+        any) receives the stage histograms, routed counts, cache outcomes
+        and pad waste."""
         tr = req.trace
         met = self.metrics
-        nq = len(qv)
-        if met is not None and nq:
-            met.counter("queries_total").inc(nq)
-            met.counter(f"queries_{prec}_total").inc(nq)
-        live = req.live
-        cache = self.cache
-        cache_info = dict(cache_enabled=cache is not None,
-                          cache_hits=0, cache_misses=nq, batch_dedup=0)
-        if cache is None or nq == 0:
-            fin = self._dispatch_all(qv, lo, hi, k, ef, req.strategy,
-                                     req.use_kernel, defer, bw, prec,
-                                     trace=tr, cache_info=cache_info,
-                                     live=live)
-            return PendingSearch(self._stitched(fin, tr))
-        # (global, segment) epoch pair: fences stores vs both invalidate()
-        # and invalidate_segment(self.cache_ns) — the streaming layer bumps
-        # the segment epoch on every tombstone change / compaction
-        epoch = cache.epoch_for(self.cache_ns)
-        cal_epoch = (self.planner.calibration_epoch
-                     if req.strategy == "auto" else None)
-        keys, hit_rows, miss, dups = cache.split(
-            qv, lo, hi, k, ef, req.strategy, req.use_kernel,
-            ns=self.cache_ns, digests=q_digests, beam_width=bw,
-            precision=prec, cal_epoch=cal_epoch)
-        cache_info.update(cache_hits=len(hit_rows), cache_misses=len(miss),
-                          batch_dedup=len(dups))
-        if met is not None:
-            met.counter("cache_hit_rows_total").inc(len(hit_rows))
-            met.counter("cache_miss_rows_total").inc(len(miss))
-            if dups:
-                met.counter("cache_dedup_rows_total").inc(len(dups))
-        if len(miss) == 0:
-            if tr is not None:          # fully hit: no device work at all
-                tr.add_span("dispatch", dispatched=0, ns=self.cache_ns,
-                            **cache_info)
-            return PendingSearch(self._stitched(
-                lambda: cache.assemble(nq, k, hit_rows, None, miss), tr))
-        fin = self._dispatch_all(qv[miss], lo[miss], hi[miss], k, ef,
-                                 req.strategy, req.use_kernel, defer, bw,
-                                 prec, trace=tr, cache_info=cache_info,
-                                 live=live)
-        miss_keys = [keys[i] for i in miss]
-
-        def finalize() -> SearchResult:
-            miss_res = fin()
-            cache.store_batch(miss_keys, miss_res, epoch=epoch,
-                              cal_epoch=cal_epoch)
-            if not hit_rows and not dups:
-                miss_res.stats["cache_hits"] = 0
-                return miss_res
-            return cache.assemble(nq, k, hit_rows, miss_res, miss,
-                                  dups)
-        return PendingSearch(self._stitched(finalize, tr))
-
-    def _stitched(self, fin: Callable[[], SearchResult],
-                  tr) -> Callable[[], SearchResult]:
-        """Wrap a finalize closure with the stitch span (block + assembly +
-        id remap; on deferred dispatches the block time includes sibling
-        device work) and attach the trace to the result.  Identity when
-        neither tracing nor metrics are on — the hot path is unchanged."""
-        met = self.metrics
-        if tr is None and met is None:
-            return fin
-
-        def finalize() -> SearchResult:
-            t0 = time.perf_counter()
-            with maybe_span(tr, "stitch", ns=self.cache_ns):
-                res = fin()
-            if met is not None:
-                met.histogram("stitch_ms").observe(
-                    (time.perf_counter() - t0) * 1e3)
-            if tr is not None:
-                res.trace = tr
-            return res
-        return finalize
-
-    # ----------------------------------------------------------- dispatch
-    def _dispatch_all(self, qv, lo, hi, k, ef, strategy, use_kernel,
-                      defer: bool, beam_width: int = 1,
-                      precision: str = "f32", trace=None,
-                      cache_info=None, live=None) -> Callable[[], SearchResult]:
-        """Enqueue the uncached work for one (sub-)batch; the returned
-        closure blocks, stitches, and remaps rank ids to original ids.
-        The dispatch span covers the enqueue (plus, on the ``defer=False``
-        path, the per-partition blocks); the plan span is recorded inside
-        it, so spans land in resolve -> plan -> dispatch -> stitch order."""
-        met = self.metrics
-        with maybe_span(trace, "dispatch") as sp:
-            sp.attrs.update(cache_info or {})
-            sp.attrs.update(strategy_mode=strategy, use_kernel=use_kernel,
-                            beam_width=beam_width, ns=self.cache_ns,
-                            precision=precision,
-                            dispatched=len(qv), deferred=defer)
-            if strategy == "graph":
-                if trace is not None:
-                    trace.add_span("plan", strategy_mode="graph",
-                                   chosen="graph", beam_width=beam_width)
+        split = None
+        plan = None
+        with stage("plan", met, tr) as sp:
+            qv = np.asarray(req.queries, np.float32)
+            lo = np.asarray(req.lo, np.int64)
+            hi = np.asarray(req.hi, np.int64)
+            k, ef, bw = int(req.k), int(req.ef), int(req.beam_width)
+            prec, mode = req.precision, req.strategy
+            nq = len(qv)
+            cache = self.cache
+            if met is not None and nq:
+                met.counter("queries_total").inc(nq)
+                met.counter(f"queries_{prec}_total").inc(nq)
+            cache_info = dict(cache_enabled=cache is not None,
+                              cache_hits=0, cache_misses=nq, batch_dedup=0)
+            if cache is not None and nq:
+                # (global, segment) epoch pair: fences stores vs both
+                # invalidate() and invalidate_segment(self.cache_ns) — the
+                # streaming layer bumps the segment epoch on every
+                # tombstone change / compaction
+                epoch = cache.epoch_for(self.cache_ns)
+                cal_epoch = (self.planner.calibration_epoch
+                             if mode == "auto" else None)
+                keys, hit_rows, miss, dups = cache.split(
+                    qv, lo, hi, k, ef, mode, req.use_kernel,
+                    ns=self.cache_ns, digests=q_digests, beam_width=bw,
+                    precision=prec, cal_epoch=cal_epoch)
+                cache_info.update(cache_hits=len(hit_rows),
+                                  cache_misses=len(miss),
+                                  batch_dedup=len(dups))
+                if met is not None:
+                    met.counter("cache_hit_rows_total").inc(len(hit_rows))
+                    met.counter("cache_miss_rows_total").inc(len(miss))
+                    if dups:
+                        met.counter("cache_dedup_rows_total").inc(len(dups))
+                split = (epoch, cal_epoch, keys, hit_rows, miss, dups)
+                qv, lo, hi = qv[miss], lo[miss], hi[miss]
+            work = split is None or len(qv) > 0
+            sp.attrs.update(cache_info, strategy_mode=mode,
+                            use_kernel=req.use_kernel, beam_width=bw,
+                            ns=self.cache_ns, precision=prec,
+                            dispatched=len(qv) if work else 0,
+                            deferred=defer)
+            if work and mode == "graph":
+                sp.attrs["chosen"] = "graph"
                 if met is not None and len(qv):
                     met.counter("graph_queries_total").inc(len(qv))
-                fin = self._dispatch_graph(qv, lo, hi, k, ef, use_kernel,
-                                           beam_width, precision, live=live)
-            else:
-                fin = self._dispatch_planned(qv, lo, hi, k, ef, strategy,
-                                             use_kernel, defer, beam_width,
-                                             precision,
-                                             trace=trace, span=sp, live=live)
+            elif work:
+                plan = self._plan(lo, hi, k, ef, mode, bw, prec, tr, sp)
+        if not work:                    # fully hit: no device work at all
+            blocks = []
+        elif plan is None:
+            blocks = [self._dispatch_graph(qv, lo, hi, k, ef,
+                                           req.use_kernel, bw, prec,
+                                           live=req.live, trace=tr)]
+        else:
+            blocks = self._dispatch_planned(qv, lo, hi, plan, k, ef, mode,
+                                            req.use_kernel, defer, bw, prec,
+                                            trace=tr, live=req.live)
 
         def finalize() -> SearchResult:
-            ids, dists, stats = fin()
-            return SearchResult(resolve.remap_ids(self.order, ids), dists,
-                                stats)
-        return finalize
+            outs = [blk() for blk in blocks]    # blocks not yet taken
+            with stage("assemble", met, tr):
+                res = None
+                if work:
+                    ids, dists, stats = (self._scatter(plan, outs, len(qv), k)
+                                         if plan is not None
+                                         else self._graph_out(outs[0]))
+                    res = SearchResult(resolve.remap_ids(self.order, ids),
+                                       dists, stats)
+                if split is not None:
+                    epoch, cal_epoch, keys, hit_rows, miss, dups = split
+                    if res is not None:
+                        cache.store_batch([keys[i] for i in miss], res,
+                                          epoch=epoch, cal_epoch=cal_epoch)
+                    if hit_rows or dups or res is None:
+                        res = cache.assemble(nq, k, hit_rows, res, miss,
+                                             dups)
+                    else:
+                        res.stats["cache_hits"] = 0
+                res.trace = tr
+            return res
+        return PendingSearch(finalize)
 
-    # ------------------------------------------------------ graph strategy
-    def _dispatch_graph(self, qv, lo, hi, k, ef, use_kernel, beam_width=1,
-                        precision="f32", live=None):
-        """The paper's path: one beam-search dispatch over the full batch.
-        Non-f32 precisions score the traversal against the quantized corpus
-        and rerank the final pool in f32 inside ``beam_search_batch``."""
-        qj = jnp.asarray(qv, jnp.float32)
-        lo_j = jnp.asarray(lo)
-        hi_j = jnp.asarray(hi)
-        entry = resolve.select_entry(self._rmq, self._dist_c, lo_j, hi_j,
-                                     self.n)
-        slot = self._quant_for(precision)
-        quant = None if slot is None else (slot["data"], slot["scale"])
-        live_b, _ = self._live_ops(live)
-        t0 = time.perf_counter()
-        with annotate("rnsg.graph_beam_dispatch"):
-            ids, dists, st = beam_search_batch(
-                self._vecs, self._nbrs, qj, lo_j, hi_j, entry,
-                k=k, ef=max(ef, k), use_kernel=use_kernel,
-                beam_width=beam_width, quant=quant, live=live_b)
-        met = self.metrics
-
-        def finalize():
-            st_h = jax.tree.map(np.asarray, st)
-            st_h["strategy"] = np.ones(len(qv), np.int8)     # all graph/beam
-            st_h["scan_frac"] = 0.0
-            if met is not None:
-                met.histogram("graph_dispatch_ms").observe(
-                    (time.perf_counter() - t0) * 1e3)
-            return np.asarray(ids), np.asarray(dists), st_h
-        return finalize
-
-    # ---------------------------------------------------- planned strategies
-    def _dispatch_planned(self, qv, lo, hi, k, ef, mode, use_kernel,
-                          defer: bool, beam_width: int = 1,
-                          precision: str = "f32", trace=None,
-                          span=None, live=None):
-        """Routing policy: plan the batch, dispatch each fixed-shape
-        partition, stitch back in request order.  ``defer=False`` blocks
-        each partition before dispatching the next (today's calibrated
-        loop); ``defer=True`` enqueues them all and blocks only in the
-        returned closure."""
-        q = len(qv)
-        met = self.metrics
-        if trace is None:
-            plan = self.planner.plan_batch(lo, hi, k=k, ef=ef, mode=mode,
-                                           beam_width=beam_width,
-                                           precision=precision)
-        else:
-            with trace.span("plan") as psp:
-                plan = self.planner.plan_batch(lo, hi, k=k, ef=ef,
-                                               mode=mode,
-                                               beam_width=beam_width,
-                                               precision=precision)
-                lens = np.clip(hi - lo + 1, 0, None)
-                sc, bc = self.planner.predict_costs(lens, k=k, ef=ef,
-                                                    beam_width=beam_width,
-                                                    precision=precision)
-                psp.attrs.update(
-                    strategy_mode=mode, strategy=plan.strategy.copy(),
-                    scan_frac=plan.scan_frac, beam_width=beam_width,
-                    precision=precision,
-                    partitions=[p.signature for p in plan.partitions],
-                    predicted_scan_units=sc, predicted_beam_units=bc)
+    def _plan(self, lo, hi, k: int, ef: int, mode: str, beam_width: int,
+              precision: str, trace, sp):
+        """Planner call of the ``plan`` stage: partitions the batch and
+        counts routed rows and pad waste."""
+        plan = self.planner.plan_batch(lo, hi, k=k, ef=ef, mode=mode,
+                                       beam_width=beam_width,
+                                       precision=precision)
+        if trace is not None:
+            lens = np.clip(hi - lo + 1, 0, None)
+            sc, bc = self.planner.predict_costs(lens, k=k, ef=ef,
+                                                beam_width=beam_width,
+                                                precision=precision)
+            sp.attrs.update(strategy=plan.strategy.copy(),
+                            scan_frac=plan.scan_frac,
+                            partitions=[p.signature for p in plan.partitions],
+                            predicted_scan_units=sc, predicted_beam_units=bc)
+        q = len(lo)
         pad_rows = sum(p.pad_q - len(p.indices) for p in plan.partitions)
+        met = self.metrics
         if met is not None and q:
             n_scan = int((plan.strategy == SCAN).sum())
             met.counter("scan_routed_total").inc(n_scan)
             met.counter("beam_routed_total").inc(q - n_scan)
             if pad_rows:
                 met.counter("pad_rows_total").inc(pad_rows)
-        if span is not None:
-            span.attrs["pad_rows"] = pad_rows
-        fins = []
+        sp.attrs["pad_rows"] = pad_rows
+        return plan
+
+    @staticmethod
+    def _scatter(plan, outs, q: int, k: int):
+        """Partition outputs back into request order (``assemble``), after
+        each partition's histogram and cost-model feedback."""
+        out_ids = np.full((q, k), -1, np.int32)
+        out_d = np.full((q, k), INF, np.float32)
+        hops = np.zeros(q, np.int32)
+        ndist = np.zeros(q, np.int32)
+        for part, (ids_p, d_p, extra, book) in zip(plan.partitions, outs):
+            book()
+            idx = part.indices  # never empty (guarded at plan time)
+            if part.kind == "scan":
+                ndist[idx] = extra
+            else:
+                hops[idx] = extra["hops"]
+                ndist[idx] = extra["ndist"]
+            out_ids[idx] = ids_p
+            out_d[idx] = d_p
+        stats = {"hops": hops, "ndist": ndist,
+                 "strategy": plan.strategy, "scan_frac": plan.scan_frac}
+        return out_ids, out_d, stats
+
+    @staticmethod
+    def _graph_out(out):
+        ids, dists, st, book = out
+        book()
+        st["strategy"] = np.ones(len(ids), np.int8)       # all graph/beam
+        st["scan_frac"] = 0.0
+        return ids, dists, st
+
+    # ------------------------------------------------------ graph strategy
+    def _dispatch_graph(self, qv, lo, hi, k, ef, use_kernel, beam_width=1,
+                        precision="f32", live=None, trace=None):
+        """The paper's path: one beam-search dispatch over the full batch.
+        Non-f32 precisions score the traversal against the quantized corpus
+        and rerank the final pool in f32 inside ``beam_search_batch``.
+        Returns the partition's block closure (see ``_dispatch_scan``)."""
+        met = self.metrics
+        with stage("beam_prep", met, trace):
+            qj = jnp.asarray(qv, jnp.float32)
+            lo_j = jnp.asarray(lo)
+            hi_j = jnp.asarray(hi)
+            entry = resolve.select_entry(self._rmq, self._dist_c, lo_j, hi_j,
+                                         self.n)
+            slot = self._quant_for(precision)
+            quant = None if slot is None else (slot["data"], slot["scale"])
+            live_b, _ = self._live_ops(live)
+            t0 = time.perf_counter()
+        with stage("graph_beam_dispatch", met, trace):
+            out = list(beam_search_batch(
+                self._vecs, self._nbrs, qj, lo_j, hi_j, entry,
+                k=k, ef=max(ef, k), use_kernel=use_kernel,
+                beam_width=beam_width, quant=quant, live=live_b))
+            del qj, lo_j, hi_j, entry           # see _dispatch_scan
+
+        def block():
+            with stage("beam_block", met, trace):
+                ids, dists, st = out
+                out.clear()
+                st_h = jax.tree.map(np.asarray, st)
+                ids_h, d_h = np.asarray(ids), np.asarray(dists)
+                del ids, dists, st
+            dt = time.perf_counter() - t0
+
+            def book():
+                if met is not None:
+                    met.histogram("graph_dispatch_ms").observe(dt * 1e3)
+            return ids_h, d_h, st_h, book
+        return block
+
+    # ---------------------------------------------------- planned strategies
+    def _dispatch_planned(self, qv, lo, hi, plan, k, ef, mode, use_kernel,
+                          defer: bool, beam_width: int = 1,
+                          precision: str = "f32", trace=None, live=None):
+        """Dispatch each fixed-shape partition of a plan.  ``defer=False``
+        blocks each partition before dispatching the next (the calibrated
+        loop); ``defer=True`` enqueues them all.  Returns one block closure
+        per partition, already taken (memoized) when not deferred."""
+        blocks = []
         for part in plan.partitions:
             if part.kind == "scan":
-                fin = self._dispatch_scan(qv, lo, hi, part.indices,
+                blk = self._dispatch_scan(qv, lo, hi, part.indices,
                                           part.param, part.pad_q, k, ef,
                                           calibrate_wall=not defer,
                                           precision=precision, trace=trace,
                                           live=live)
             else:
-                fin = self._dispatch_beam(qv, lo, hi, part.indices,
+                blk = self._dispatch_beam(qv, lo, hi, part.indices,
                                           part.param, part.pad_q, k,
                                           calibrate=(mode == "auto"),
                                           calibrate_wall=not defer,
                                           use_kernel=use_kernel,
                                           beam_width=beam_width,
-                                          precision=precision, live=live)
+                                          precision=precision, live=live,
+                                          trace=trace)
             if not defer:
-                val = fin()
-                fin = (lambda v: lambda: v)(val)
-            fins.append(fin)
-
-        def finalize():
-            out_ids = np.full((q, k), -1, np.int32)
-            out_d = np.full((q, k), INF, np.float32)
-            hops = np.zeros(q, np.int32)
-            ndist = np.zeros(q, np.int32)
-            for part, fin in zip(plan.partitions, fins):
-                idx = part.indices  # never empty (guarded at plan time)
-                if part.kind == "scan":
-                    ids_p, d_p, units = fin()
-                    ndist[idx] = units
-                else:
-                    ids_p, d_p, st = fin()
-                    hops[idx] = st["hops"]
-                    ndist[idx] = st["ndist"]
-                out_ids[idx] = ids_p
-                out_d[idx] = d_p
-            stats = {"hops": hops, "ndist": ndist,
-                     "strategy": plan.strategy, "scan_frac": plan.scan_frac}
-            return out_ids, out_d, stats
-        return finalize
+                blk = (lambda v: lambda: v)(blk())
+            blocks.append(blk)
+        return blocks
 
     # ------------------------------------------------------------------
     def _scan_corpus(self):
@@ -500,122 +490,163 @@ class SearchSubstrate:
     def _dispatch_scan(self, qv, lo, hi, idx, bucket: int, pad_q: int,
                        k: int, ef: int, *, calibrate_wall: bool,
                        precision: str = "f32", trace=None, live=None):
-        nq = len(idx)
-        starts = np.zeros(pad_q, np.int32)
-        lens = np.zeros(pad_q, np.int32)
-        starts[:nq] = lo[idx]
-        lens[:nq] = np.clip(hi[idx] - lo[idx] + 1, 0, bucket)
-        qp = np.zeros((pad_q, self.d_pad), np.float32)
-        qp[:nq, :self.d] = qv[idx]
-        slot = self._quant_for(precision)
-        _, live_row = self._live_ops(live)
-        sig = ("scan", bucket, pad_q, k, precision, live is not None)
-        warm = sig in self._warm
-        self._warm.add(sig)
-        t0 = time.perf_counter()
+        """Enqueue one scan partition; returns its block closure.  The
+        closure waits for the outputs (``scan_block``) and returns
+        ``(ids, dists, units, book)``, where ``book`` feeds the
+        ``scan_dispatch_ms`` histogram (enqueue to host result) and the
+        cost model — run in ``assemble``."""
+        met = self.metrics
+        with stage("scan_prep", met, trace):
+            nq = len(idx)
+            starts = np.zeros(pad_q, np.int32)
+            lens = np.zeros(pad_q, np.int32)
+            starts[:nq] = lo[idx]
+            lens[:nq] = np.clip(hi[idx] - lo[idx] + 1, 0, bucket)
+            qp = np.zeros((pad_q, self.d_pad), np.float32)
+            qp[:nq, :self.d] = qv[idx]
+            slot = self._quant_for(precision)
+            _, live_row = self._live_ops(live)
+            sig = ("scan", bucket, pad_q, k, precision, live is not None)
+            warm = sig in self._warm
+            self._warm.add(sig)
+            t0 = time.perf_counter()
+            x = (self._scan_corpus() if slot is None
+                 else slot["data_pad"])
+            starts_j, lens_j, qp_j = (jnp.asarray(starts), jnp.asarray(lens),
+                                      jnp.asarray(qp))
         rq = 0
-        with annotate("rnsg.scan_dispatch"):
+        with stage("scan_dispatch", met, trace):
             if slot is None:
-                ids, d = range_scan(self._scan_corpus(), jnp.asarray(starts),
-                                    jnp.asarray(lens), jnp.asarray(qp),
-                                    bucket=bucket, k=k, live=live_row)
+                out = list(range_scan(x, starts_j, lens_j, qp_j,
+                                      bucket=bucket, k=k, live=live_row))
             else:
                 # quantized scan keeps rerank_depth survivors (clamped to
                 # the slice via lens ≤ bucket masking; tombstoned rows are
                 # masked here, so the survivor pool is live-only) ...
                 rq = rerank_depth(k, ef, cap=self.tb)
-                ids_q, _ = range_scan(slot["data_pad"], jnp.asarray(starts),
-                                      jnp.asarray(lens), jnp.asarray(qp),
+                ids_q, _ = range_scan(x, starts_j, lens_j, qp_j,
                                       bucket=bucket, k=rq,
                                       scale=slot["scale_pad"],
                                       live=live_row)
-                # ... then a fused f32 rescore of those ids restores the
-                # exact top-k (candidates rank-sorted so ties break exactly
-                # as the oracle's)
-                with maybe_span(trace, "rerank", precision=precision,
-                                rows=pad_q * rq, k=k):
-                    ids, d = rerank_pool(self._vecs, ids_q,
-                                         jnp.asarray(qp[:, :self.d]), k,
-                                         use_kernel=True)
+            # device arrays are released inside the stage that used them:
+            # their teardown is host work too, and must not fall between
+            # stages
+            del starts_j, lens_j, qp_j
+        if rq:
+            # ... then a fused f32 rescore of those ids restores the exact
+            # top-k (candidates rank-sorted so ties break exactly as the
+            # oracle's)
+            with stage("rerank", met, trace, precision=precision,
+                       rows=pad_q * rq, k=k):
+                out = list(rerank_pool(self._vecs, ids_q,
+                                       jnp.asarray(qp[:, :self.d]), k,
+                                       use_kernel=True))
+                del ids_q
         units = window_rows(bucket, self.tb)
-        met = self.metrics
 
-        def finalize():
-            ids_h = np.asarray(ids)[:nq]
-            d_h = np.asarray(d)[:nq]
+        def block():
+            with stage("scan_block", met, trace):
+                ids, d = out
+                out.clear()
+                ids_h = np.asarray(ids)[:nq]
+                d_h = np.asarray(d)[:nq]
+                del ids, d
             dt = time.perf_counter() - t0
-            if met is not None:
-                met.histogram("scan_dispatch_ms").observe(dt * 1e3)
-                if rq:
-                    met.counter("rerank_rows_total").inc(pad_q * rq)
-            if calibrate_wall and warm:
-                # the dispatch did pad_q windows of work, not nq: normalize
-                # by pad_q so calibration measures the kernel, not the
-                # padding ratio
-                self.planner.cost.observe_wall("scan", units, dt, pad_q,
-                                               precision=precision)
-            return ids_h, d_h, units
-        return finalize
+
+            def book():
+                if met is not None:
+                    met.histogram("scan_dispatch_ms").observe(dt * 1e3)
+                    if rq:
+                        met.counter("rerank_rows_total").inc(pad_q * rq)
+                if calibrate_wall and warm:
+                    # the dispatch did pad_q windows of work, not nq:
+                    # normalize by pad_q so calibration measures the
+                    # kernel, not the padding ratio
+                    self.planner.cost.observe_wall("scan", units, dt, pad_q,
+                                                   precision=precision)
+            return ids_h, d_h, units, book
+        return block
 
     def _dispatch_beam(self, qv, lo, hi, idx, ef: int, pad_q: int, k: int, *,
                        calibrate: bool, calibrate_wall: bool = True,
                        use_kernel: bool = False, beam_width: int = 1,
-                       precision: str = "f32", live=None):
+                       precision: str = "f32", live=None, trace=None):
+        """Enqueue one beam partition; returns its block closure
+        (``beam_block``), as ``_dispatch_scan`` does."""
         nq = len(idx)
         if nq == 0:                 # empty partition: nothing to dispatch
             empty = np.zeros(0, np.int32)
             return lambda: (np.zeros((0, k), np.int32),
                             np.zeros((0, k), np.float32),
-                            {"hops": empty, "ndist": empty})
-        pad = np.concatenate([idx, np.repeat(idx[-1:], pad_q - nq)])
-        lo_j = jnp.asarray(np.clip(lo[pad], 0, self.n - 1).astype(np.int32))
-        hi_j = jnp.asarray(np.clip(hi[pad], 0, self.n - 1).astype(np.int32))
-        entry = resolve.select_entry(self._rmq, self._dist_c, lo_j, hi_j,
-                                     self.n)
-        qp = jnp.asarray(qv[pad])
-        slot = self._quant_for(precision)
-        quant = None if slot is None else (slot["data"], slot["scale"])
-        live_b, _ = self._live_ops(live)
-        sig = ("beam", ef, pad_q, k, beam_width, precision, live is not None)
-        warm = sig in self._warm
-        self._warm.add(sig)
-        t0 = time.perf_counter()
-        with annotate("rnsg.beam_dispatch"):
-            ids, d, st = beam_search_batch(
-                self._vecs, self._nbrs, qp,
-                jnp.asarray(lo[pad].astype(np.int32)),
-                jnp.asarray(hi[pad].astype(np.int32)),
-                entry, k=k, ef=max(ef, k), use_kernel=use_kernel,
-                beam_width=beam_width, quant=quant, live=live_b)
+                            {"hops": empty, "ndist": empty}, _no_book)
         met = self.metrics
+        with stage("beam_prep", met, trace):
+            pad = np.concatenate([idx, np.repeat(idx[-1:], pad_q - nq)])
+            lo_j = jnp.asarray(
+                np.clip(lo[pad], 0, self.n - 1).astype(np.int32))
+            hi_j = jnp.asarray(
+                np.clip(hi[pad], 0, self.n - 1).astype(np.int32))
+            entry = resolve.select_entry(self._rmq, self._dist_c, lo_j, hi_j,
+                                         self.n)
+            qp = jnp.asarray(qv[pad])
+            slot = self._quant_for(precision)
+            quant = None if slot is None else (slot["data"], slot["scale"])
+            live_b, _ = self._live_ops(live)
+            sig = ("beam", ef, pad_q, k, beam_width, precision,
+                   live is not None)
+            warm = sig in self._warm
+            self._warm.add(sig)
+            t0 = time.perf_counter()
+            lo_p = jnp.asarray(lo[pad].astype(np.int32))
+            hi_p = jnp.asarray(hi[pad].astype(np.int32))
+        with stage("beam_dispatch", met, trace):
+            out = list(beam_search_batch(
+                self._vecs, self._nbrs, qp, lo_p, hi_p,
+                entry, k=k, ef=max(ef, k), use_kernel=use_kernel,
+                beam_width=beam_width, quant=quant, live=live_b))
+            del qp, lo_p, hi_p, entry, lo_j, hi_j   # see _dispatch_scan
 
-        def finalize():
-            ids_h = np.asarray(ids)[:nq]
-            d_h = np.asarray(d)[:nq]
-            st_h = {kk: np.asarray(vv)[:nq] for kk, vv in st.items()}
+        def block():
+            with stage("beam_block", met, trace):
+                ids, d, st = out
+                out.clear()
+                ids_h = np.asarray(ids)[:nq]
+                d_h = np.asarray(d)[:nq]
+                st_h = {kk: np.asarray(vv)[:nq] for kk, vv in st.items()}
+                del ids, d, st
             dt = time.perf_counter() - t0
-            if met is not None:
-                met.histogram("beam_dispatch_ms").observe(dt * 1e3)
-            if calibrate:
-                self.planner.cost.update_beam(float(st_h["ndist"].mean()), ef,
-                                              beam_width=beam_width)
-                if calibrate_wall and warm:
-                    # pad lanes duplicate the last real query, so pad_q lanes
-                    # of ~ndist work each were executed — normalize by pad_q
-                    self.planner.cost.observe_wall(
-                        "beam", max(float(st_h["ndist"].mean()), 1.0), dt,
-                        pad_q, precision=precision)
-            return ids_h, d_h, st_h
-        return finalize
+
+            def book():
+                if met is not None:
+                    met.histogram("beam_dispatch_ms").observe(dt * 1e3)
+                if calibrate:
+                    self.planner.cost.update_beam(
+                        float(st_h["ndist"].mean()), ef,
+                        beam_width=beam_width)
+                    if calibrate_wall and warm:
+                        # pad lanes duplicate the last real query, so pad_q
+                        # lanes of ~ndist work each were executed —
+                        # normalize by pad_q
+                        self.planner.cost.observe_wall(
+                            "beam", max(float(st_h["ndist"].mean()), 1.0),
+                            dt, pad_q, precision=precision)
+            return ids_h, d_h, st_h, book
+        return block
 
     # ------------------------------------------------- legacy sync wrapper
     def _run_beam(self, qv, lo, hi, idx, ef: int, pad_q: int, k: int, *,
                   calibrate: bool, use_kernel: bool = False):
         """Synchronous beam partition dispatch (kept for the empty-partition
         regression test and any external caller of the pre-async API)."""
-        return self._dispatch_beam(qv, lo, hi, np.asarray(idx, np.int64),
-                                   ef, pad_q, k, calibrate=calibrate,
-                                   use_kernel=use_kernel)()
+        ids, d, st, book = self._dispatch_beam(
+            qv, lo, hi, np.asarray(idx, np.int64), ef, pad_q, k,
+            calibrate=calibrate, use_kernel=use_kernel)()
+        book()
+        return ids, d, st
+
+
+def _no_book():
+    """Bookkeeping of a partition that dispatched nothing."""
 
 
 # ======================================================================
@@ -912,16 +943,18 @@ class MeshSubstrate:
     def run(self, req: SearchRequest) -> SearchResult:
         """Dispatch one request on the mesh; result ids are original corpus
         ids, already merged across shards (replicated).  With a cache
-        installed, hit rows skip the mesh dispatch entirely.  A ``req.trace``
-        collects plan / dispatch / stitch spans (the cross-shard scatter +
-        merge run *inside* the traced body, so the host-side stitch span
-        covers output conversion and cache assembly)."""
+        installed, hit rows skip the mesh dispatch entirely.  Stages (no
+        stage histograms: the mesh path keeps ``mesh_dispatch_ms``):
+        ``plan`` (cache split, routing), ``mesh_graph_dispatch`` /
+        ``mesh_planned_dispatch`` (enqueue and block — the cross-shard
+        scatter and merge run *inside* the traced body), ``assemble``
+        (result and cache assembly)."""
         qv = np.asarray(req.queries, np.float32)
         lo = np.asarray(req.lo, np.int64)
         hi = np.asarray(req.hi, np.int64)
         k, ef = int(req.k), max(int(req.ef), int(req.k))
         bw = int(req.beam_width)
-        prec = req.precision
+        prec, mode = req.precision, req.strategy
         tr = req.trace
         met = self.metrics
         nq = len(qv)
@@ -930,140 +963,122 @@ class MeshSubstrate:
                                 np.zeros((0, k), np.float32),
                                 {"strategy": np.zeros(0, np.int8),
                                  "scan_frac": 0.0}, trace=tr)
-        if met is not None:
-            met.counter("queries_total").inc(nq)
-            met.counter("mesh_queries_total").inc(nq)
-            met.counter(f"queries_{prec}_total").inc(nq)
-        live = req.live
         cache = self.cache
-        cache_info = dict(cache_enabled=cache is not None,
-                          cache_hits=0, cache_misses=nq, batch_dedup=0)
-        if cache is None:
-            res = self._run_uncached(qv, lo, hi, k, ef, req.strategy, bw,
-                                     prec, trace=tr, cache_info=cache_info,
-                                     live=live)
+        split = None
+        route = None
+        with stage("plan", None, tr) as sp:
+            if met is not None:
+                met.counter("queries_total").inc(nq)
+                met.counter("mesh_queries_total").inc(nq)
+                met.counter(f"queries_{prec}_total").inc(nq)
+            cache_info = dict(cache_enabled=cache is not None,
+                              cache_hits=0, cache_misses=nq, batch_dedup=0)
+            if cache is not None:
+                # fences stores vs invalidate() / invalidate_segment("mesh")
+                epoch = cache.epoch_for("mesh")
+                cal_epoch = (self.planner.calibration_epoch
+                             if mode == "auto" else None)
+                keys, hit_rows, miss, dups = cache.split(
+                    qv, lo, hi, k, ef, mode, ns="mesh", beam_width=bw,
+                    precision=prec, cal_epoch=cal_epoch)
+                cache_info.update(cache_hits=len(hit_rows),
+                                  cache_misses=len(miss),
+                                  batch_dedup=len(dups))
+                if met is not None:
+                    met.counter("cache_hit_rows_total").inc(len(hit_rows))
+                    met.counter("cache_miss_rows_total").inc(len(miss))
+                    if dups:
+                        met.counter("cache_dedup_rows_total").inc(len(dups))
+                split = (epoch, cal_epoch, keys, hit_rows, miss, dups)
+                qv, lo, hi = qv[miss], lo[miss], hi[miss]
+            sp.attrs.update(cache_info, strategy_mode=mode, ns="mesh",
+                            dispatched=len(qv), beam_width=bw,
+                            precision=prec)
+            if len(qv):
+                if tr is not None:
+                    sp.attrs["shard_clip_widths"] = \
+                        self._shard_clip_widths(lo, hi)
+                route = self._route(lo, hi, k, ef, mode, bw, prec, tr, sp)
+        if route is not None:
+            ids, dists, stats = self._execute(qv, lo, hi, k, ef, mode, bw,
+                                              prec, route, tr, req.live)
+        with stage("assemble", None, tr):
+            res = None if route is None else SearchResult(ids, dists, stats)
+            if split is not None:
+                epoch, cal_epoch, keys, hit_rows, miss, dups = split
+                if res is not None:
+                    cache.store_batch([keys[i] for i in miss], res,
+                                      epoch=epoch, cal_epoch=cal_epoch)
+                if hit_rows or dups or res is None:
+                    res = cache.assemble(nq, k, hit_rows, res, miss, dups)
+                else:
+                    res.stats["cache_hits"] = 0
             res.trace = tr
-            return res
-        # fences stores vs invalidate() / invalidate_segment("mesh")
-        epoch = cache.epoch_for("mesh")
-        cal_epoch = (self.planner.calibration_epoch
-                     if req.strategy == "auto" else None)
-        keys, hit_rows, miss, dups = cache.split(qv, lo, hi, k, ef,
-                                                 req.strategy, ns="mesh",
-                                                 beam_width=bw,
-                                                 precision=prec,
-                                                 cal_epoch=cal_epoch)
-        cache_info.update(cache_hits=len(hit_rows), cache_misses=len(miss),
-                          batch_dedup=len(dups))
-        if met is not None:
-            met.counter("cache_hit_rows_total").inc(len(hit_rows))
-            met.counter("cache_miss_rows_total").inc(len(miss))
-            if dups:
-                met.counter("cache_dedup_rows_total").inc(len(dups))
-        if len(miss) == 0:
-            if tr is not None:          # fully hit: no mesh dispatch at all
-                tr.add_span("dispatch", dispatched=0, ns="mesh",
-                            **cache_info)
-            with maybe_span(tr, "stitch", ns="mesh"):
-                res = cache.assemble(nq, k, hit_rows, None, miss)
-            res.trace = tr
-            return res
-        miss_res = self._run_uncached(qv[miss], lo[miss], hi[miss], k, ef,
-                                      req.strategy, bw, prec, trace=tr,
-                                      cache_info=cache_info, live=live)
-        cache.store_batch([keys[i] for i in miss], miss_res, epoch=epoch,
-                          cal_epoch=cal_epoch)
-        if not hit_rows and not dups:
-            miss_res.stats["cache_hits"] = 0
-            miss_res.trace = tr
-            return miss_res
-        with maybe_span(tr, "stitch", ns="mesh"):
-            res = cache.assemble(nq, k, hit_rows, miss_res, miss, dups)
-        res.trace = tr
         return res
 
     def _shard_clip_widths(self, lo, hi) -> np.ndarray:
-        """(S, Q) shard-local clipped interval widths — the dispatch-span
-        view of how each query's global interval lands on the mesh."""
+        """(S, Q) shard-local clipped interval widths — the plan-span view
+        of how each query's global interval lands on the mesh."""
         w = []
         for s in range(self.n_shards):
             slo, shi = resolve.clip_interval(lo, hi, s * self.per, self.per)
             w.append(np.clip(shi.astype(np.int64) - slo + 1, 0, None))
         return np.stack(w)
 
-    def _run_uncached(self, qv, lo, hi, k: int, ef: int, mode: str,
-                      beam_width: int = 1, precision: str = "f32",
-                      trace=None, cache_info=None, live=None) -> SearchResult:
+    def _route(self, lo, hi, k: int, ef: int, mode: str, beam_width: int,
+               precision: str, trace, sp):
+        """Routing of the ``plan`` stage: ``None`` for the graph strategy,
+        else the per-query strategy vector and widest shard-local clips."""
+        if mode == "graph":
+            sp.attrs["chosen"] = "graph"
+            if self.metrics is not None:
+                self.metrics.counter("graph_queries_total").inc(len(lo))
+            return "graph"
+        strategy, lens_eff = self.plan_strategies(lo, hi, k=k, ef=ef,
+                                                  mode=mode,
+                                                  beam_width=beam_width,
+                                                  precision=precision)
+        if trace is not None:
+            sc, bc = self.planner.predict_costs(lens_eff, k=k, ef=ef,
+                                                beam_width=beam_width,
+                                                precision=precision)
+            sp.attrs.update(strategy=strategy.copy(),
+                            lens_eff=lens_eff.copy(),
+                            scan_frac=float((strategy == SCAN).mean()),
+                            predicted_scan_units=sc,
+                            predicted_beam_units=bc)
+        if self.metrics is not None:
+            n_scan = int((strategy == SCAN).sum())
+            self.metrics.counter("scan_routed_total").inc(n_scan)
+            self.metrics.counter("beam_routed_total").inc(len(lo) - n_scan)
+        return strategy, lens_eff
+
+    def _execute(self, qv, lo, hi, k: int, ef: int, mode: str,
+                 beam_width: int, precision: str, route, trace, live):
+        """Run one routed batch on the mesh: (ids, dists, stats)."""
         nq = len(qv)
         met = self.metrics
-        if mode == "graph":
-            if trace is not None:
-                trace.add_span("plan", strategy_mode="graph", chosen="graph",
-                               beam_width=beam_width)
-            if met is not None:
-                met.counter("graph_queries_total").inc(nq)
-            with maybe_span(trace, "dispatch") as sp:
-                sp.attrs.update(cache_info or {})
-                sp.attrs.update(strategy_mode=mode, ns="mesh",
-                                dispatched=nq, beam_width=beam_width,
-                                precision=precision,
-                                shard_clip_widths=self._shard_clip_widths(
-                                    lo, hi) if trace is not None else None)
-                ids, dists = self._call_graph(qv, lo, hi, k, ef,
-                                              calibrate=False,
-                                              beam_width=beam_width,
-                                              precision=precision, live=live)
-            with maybe_span(trace, "stitch", ns="mesh"):
-                res = SearchResult(ids, dists,
-                                   {"strategy": np.ones(nq, np.int8),
-                                    "scan_frac": 0.0})
-            return res
-        if trace is None:
-            strategy, lens_eff = self.plan_strategies(lo, hi, k=k, ef=ef,
-                                                      mode=mode,
-                                                      beam_width=beam_width,
-                                                      precision=precision)
-        else:
-            with trace.span("plan") as psp:
-                strategy, lens_eff = self.plan_strategies(
-                    lo, hi, k=k, ef=ef, mode=mode, beam_width=beam_width,
-                    precision=precision)
-                sc, bc = self.planner.predict_costs(lens_eff, k=k, ef=ef,
-                                                    beam_width=beam_width,
-                                                    precision=precision)
-                psp.attrs.update(strategy_mode=mode,
-                                 strategy=strategy.copy(),
-                                 lens_eff=lens_eff.copy(),
-                                 beam_width=beam_width,
-                                 precision=precision,
-                                 scan_frac=float((strategy == SCAN).mean()),
-                                 predicted_scan_units=sc,
-                                 predicted_beam_units=bc)
+        if route == "graph":
+            ids, dists = self._call_graph(qv, lo, hi, k, ef,
+                                          calibrate=False,
+                                          beam_width=beam_width,
+                                          precision=precision, live=live,
+                                          trace=trace)
+            return ids, dists, {"strategy": np.ones(nq, np.int8),
+                                "scan_frac": 0.0}
+        strategy, lens_eff = route
         scan_idx = np.flatnonzero(strategy == SCAN)
         beam_idx = np.flatnonzero(strategy == BEAM)
-        if met is not None:
-            met.counter("scan_routed_total").inc(len(scan_idx))
-            met.counter("beam_routed_total").inc(len(beam_idx))
         if len(scan_idx) == 0:
             # uniform-beam batch: the planned body would degenerate to the
             # graph body plus pow2 padding and a scatter — dispatch the graph
             # fn directly (same ef, same merge, bit-identical results)
-            with maybe_span(trace, "dispatch") as sp:
-                sp.attrs.update(cache_info or {})
-                sp.attrs.update(strategy_mode=mode, ns="mesh",
-                                dispatched=nq, beam_width=beam_width,
-                                precision=precision,
-                                uniform_beam_fast_path=True,
-                                shard_clip_widths=self._shard_clip_widths(
-                                    lo, hi) if trace is not None else None)
-                ids, dists = self._call_graph(qv, lo, hi, k, ef,
-                                              calibrate=self.calibrate,
-                                              beam_width=beam_width,
-                                              precision=precision, live=live)
-            with maybe_span(trace, "stitch", ns="mesh"):
-                res = SearchResult(ids, dists,
-                                   {"strategy": strategy, "scan_frac": 0.0})
-            return res
+            ids, dists = self._call_graph(qv, lo, hi, k, ef,
+                                          calibrate=self.calibrate,
+                                          beam_width=beam_width,
+                                          precision=precision, live=live,
+                                          trace=trace)
+            return ids, dists, {"strategy": strategy, "scan_frac": 0.0}
         # scan_idx is non-empty past the fast path; one shared bucket covers
         # every scan query's widest shard-local clip (never truncates)
         cap = next_pow2(self.per)
@@ -1094,23 +1109,16 @@ class MeshSubstrate:
         if met is not None and pad_rows:
             met.counter("pad_rows_total").inc(pad_rows)
         t0 = time.perf_counter()
-        with maybe_span(trace, "dispatch") as sp:
-            sp.attrs.update(cache_info or {})
-            sp.attrs.update(strategy_mode=mode, ns="mesh", dispatched=nq,
-                            beam_width=beam_width, warm=warm, bucket=bucket,
-                            precision=precision,
-                            pad_scan=pad_s, pad_beam=pad_b,
-                            pad_rows=pad_rows,
-                            shard_clip_widths=self._shard_clip_widths(
-                                lo, hi) if trace is not None else None)
-            with annotate("rnsg.mesh_planned_dispatch"):
-                ids, dists, nd_g = fn(x_scan, self._vecs,
-                                      self._nbrs, self._rmq, self._dist_c,
-                                      self._order, self._rank0, xq, scale,
-                                      self._live_shards(live),
-                                      *scan_ops, *beam_ops)
-                ids = np.asarray(ids)
-                dists = np.asarray(dists)
+        with stage("mesh_planned_dispatch", None, trace) as sp:
+            sp.attrs.update(warm=warm, bucket=bucket, pad_scan=pad_s,
+                            pad_beam=pad_b, pad_rows=pad_rows)
+            ids, dists, nd_g = fn(x_scan, self._vecs,
+                                  self._nbrs, self._rmq, self._dist_c,
+                                  self._order, self._rank0, xq, scale,
+                                  self._live_shards(live),
+                                  *scan_ops, *beam_ops)
+            ids = np.asarray(ids)
+            dists = np.asarray(dists)
         if met is not None:
             met.histogram("mesh_dispatch_ms").observe(
                 (time.perf_counter() - t0) * 1e3)
@@ -1134,15 +1142,12 @@ class MeshSubstrate:
                 nd_mean = float(np.asarray(nd_g).mean()) / len(beam_idx)
                 self.planner.cost.update_beam(nd_mean, ef,
                                               beam_width=beam_width)
-        scan_frac = len(scan_idx) / nq
-        with maybe_span(trace, "stitch", ns="mesh"):
-            res = SearchResult(ids, dists,
-                               {"strategy": strategy,
-                                "scan_frac": scan_frac})
-        return res
+        return ids, dists, {"strategy": strategy,
+                            "scan_frac": len(scan_idx) / nq}
 
     def _call_graph(self, qv, lo, hi, k: int, ef: int, *, calibrate: bool,
-                    beam_width: int = 1, precision: str = "f32", live=None):
+                    beam_width: int = 1, precision: str = "f32", live=None,
+                    trace=None):
         """One graph-body mesh dispatch (+ optional warm-call beam
         calibration for routed uniform-beam batches: wall time and the
         all-gathered per-shard ndist feed the cost model)."""
@@ -1154,7 +1159,8 @@ class MeshSubstrate:
         xq = self._vecs if slot is None else slot["data"]
         scale = self._ones_scale() if slot is None else slot["scale_pad"]
         t0 = time.perf_counter()
-        with annotate("rnsg.mesh_graph_dispatch"):
+        with stage("mesh_graph_dispatch", None, trace) as sp:
+            sp.attrs["warm"] = warm
             ids, dists, nd_g = fn(self._vecs, self._nbrs, self._rmq,
                                   self._dist_c, self._order, self._rank0,
                                   xq, scale, self._live_shards(live),

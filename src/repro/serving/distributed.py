@@ -223,9 +223,8 @@ class DistributedRFANN:
             hits += int(res.stats.get("cache_hits", 0))
             if "scan_frac" in res.stats:
                 scan_fracs.append(float(res.stats["scan_frac"]))
-        from repro.obs import maybe_span
-        with maybe_span(trace, "stitch", ns="merge",
-                        n_shards=self.n_shards) as sp:
+        from repro.obs import stage
+        with stage("merge", None, trace, n_shards=self.n_shards) as sp:
             ids, dists = merge_topk(jnp.asarray(all_i), jnp.asarray(all_d), k)
             ids, dists = np.asarray(ids), np.asarray(dists)
             sp.attrs["q"] = q
@@ -271,8 +270,8 @@ class DistributedRFANN:
                k: int = 10, ef: int = 64, plan: str = "graph",
                beam_width: int = 1, precision: str = "f32",
                trace=None, live=None) -> Tuple[np.ndarray, np.ndarray]:
-        from repro.obs import maybe_span
-        with maybe_span(trace, "resolve") as sp:
+        from repro.obs import stage
+        with stage("resolve", None, trace) as sp:
             lo, hi = self.rank_range(attr_ranges)
             sp.attrs.update(
                 q=len(np.atleast_2d(queries)), n=len(self.attrs_sorted),

@@ -39,67 +39,52 @@ installed on the index (and re-installed on ``swap_index``) so substrate
 counters/histograms land in the same snapshot, and the engine itself
 records end-to-end latency/batch-size histograms, queue-depth gauges, and
 pull-side producers for the cache, the cost model, and its own summary.
-``trace_sample_every=N`` attaches a ``QueryTrace`` to every Nth batch
-(resolver times the resolve span, the substrate fills plan/dispatch/stitch)
-and parks the finished trace on :attr:`last_trace`; ``log_interval_s > 0``
-prints a one-line stats summary from the dispatch thread at that cadence.
+The dispatcher thread is tiled by leaf stages (``repro.obs.stage``): it
+waits in ``await_batch``, the substrate runs ``plan`` / ``*_prep`` /
+``*_dispatch`` / ``*_block`` / ``assemble``, and ``complete`` does the
+accounting and sets the futures; each stage's wall time lands in
+``stage_<name>_ms`` and, under a profiler session, on the device trace's
+clock as ``rnsg.<name>``, tagged ``batch=<seq>``.  Per request the engine
+records the queue wait (submit to its batch closing, ``engine_queue_wait_ms``)
+and per batch the hand-off wait (resolver's put to dispatcher's get,
+``engine_handoff_wait_ms``).
+``trace_sample_every=N`` attaches a ``QueryTrace`` to every Nth batch (the
+stages append their spans) and parks the finished trace on
+:attr:`last_trace`; ``log_interval_s > 0`` prints a one-line stats summary
+from the dispatch thread at that cadence.
 """
 from __future__ import annotations
 
 import os
 import queue
-import random
 import threading
 import time
 from concurrent.futures import Future
-from dataclasses import dataclass, field
-from typing import List, Optional, Tuple
+from dataclasses import dataclass
+from typing import Optional, Tuple
 
 import numpy as np
 
-from repro.obs import MetricsRegistry, QueryTrace, format_stats_line
+from repro.obs import (MetricsRegistry, QueryTrace, format_stats_line,
+                       set_batch, stage)
 
 
 @dataclass
 class EngineStats:
-    """Bounded: latencies are a fixed-size uniform reservoir (Vitter's
-    Algorithm R), so a long-running server keeps O(1) memory while the
-    percentile summary stays an unbiased estimate of the full stream."""
+    """Served counts; latency lives in the ``engine_e2e_ms`` histogram."""
     served: int = 0
     batches: int = 0
     scan_routed: int = 0
     cache_hits: int = 0
     dedup_hits: int = 0     # intra-batch duplicate rows served by one dispatch
-    reservoir_size: int = 4096
-    latencies_ms: List[float] = field(default_factory=list)
-    lat_seen: int = 0
-    _rng: random.Random = field(default_factory=lambda: random.Random(0),
-                                repr=False)
-
-    def record_latency(self, ms: float) -> None:
-        self.lat_seen += 1
-        if len(self.latencies_ms) < self.reservoir_size:
-            self.latencies_ms.append(ms)
-        else:
-            j = self._rng.randrange(self.lat_seen)
-            if j < self.reservoir_size:
-                self.latencies_ms[j] = ms
 
     def summary(self) -> dict:
-        # percentiles from an EMPTY reservoir are reported as 0.0, not a
-        # percentile of a fake zero sample — lat_seen disambiguates
-        lat = np.asarray(self.latencies_ms) if self.latencies_ms else np.zeros(1)
         return dict(served=self.served, batches=self.batches,
                     mean_batch=self.served / max(self.batches, 1),
                     scan_frac=self.scan_routed / max(self.served, 1),
                     cache_hit_frac=self.cache_hits / max(self.served, 1),
                     dedup_hits=self.dedup_hits,
-                    dedup_frac=self.dedup_hits / max(self.served, 1),
-                    lat_seen=self.lat_seen,
-                    p50_ms=float(np.percentile(lat, 50)),
-                    p90_ms=float(np.percentile(lat, 90)),
-                    p95_ms=float(np.percentile(lat, 95)),
-                    p99_ms=float(np.percentile(lat, 99)))
+                    dedup_frac=self.dedup_hits / max(self.served, 1))
 
 
 class RFANNEngine:
@@ -186,6 +171,12 @@ class RFANNEngine:
                                            lo=1.0, hi=8192.0, growth=1.25)
         self._m_resolve = reg.histogram("engine_resolve_ms",
                                         "host-side resolve wall time (ms)")
+        self._m_qwait = reg.histogram(
+            "engine_queue_wait_ms",
+            "submit -> the request's batch closing, per request (ms)")
+        self._m_handoff = reg.histogram(
+            "engine_handoff_wait_ms",
+            "resolver's put -> dispatcher's get, per batch (ms)")
         self._m_qdepth = reg.gauge("engine_queue_depth",
                                    "requests waiting to be batched")
         self._m_hdepth = reg.gauge("engine_handoff_depth",
@@ -209,6 +200,15 @@ class RFANNEngine:
         ``swap_index`` transparently switches whose calibration is exported."""
         planner = getattr(self.index, "planner", None)
         return planner.cost.snapshot() if planner is not None else {}
+
+    def summary(self) -> dict:
+        """Served counts (``EngineStats``) plus end-to-end latency
+        percentiles from the ``engine_e2e_ms`` histogram (bucket
+        resolution, ~25 %) — the line ``launch/serve.py`` prints."""
+        out = self.stats.summary()
+        out.update({f"{p}_ms": v for p, v in
+                    self._m_e2e.percentiles((50, 90, 95, 99)).items()})
+        return out
 
     def metrics(self) -> dict:
         """One JSON-able snapshot: every counter/gauge/histogram (with
@@ -289,6 +289,9 @@ class RFANNEngine:
                     batch.append(self._q.get(timeout=left))
                 except queue.Empty:
                     break
+            t_close = time.perf_counter()
+            self._m_qwait.observe_many(
+                [(t_close - b[2]) * 1e3 for b in batch])
             qv = np.stack([b[0] for b in batch])
             rg = np.stack([b[1] for b in batch])
             self._m_qdepth.set(self._q.qsize())
@@ -296,23 +299,25 @@ class RFANNEngine:
                 index = self.index          # lock — never resolve under it,
             # the dispatcher takes it per batch and would stall behind us
             self._batch_seq += 1
+            seq = self._batch_seq
+            set_batch(seq)
             trace = (QueryTrace()
                      if self.trace_sample_every
-                     and self._batch_seq % self.trace_sample_every == 0
-                     else None)
-            t_res = time.perf_counter()
+                     and seq % self.trace_sample_every == 0 else None)
             try:
-                lo, hi = (index.rank_range(rg)
-                          if hasattr(index, "rank_range") else (None, None))
+                with stage("resolve", None, trace) as st:
+                    st.attrs["q"] = len(batch)
+                    lo, hi = (index.rank_range(rg)
+                              if hasattr(index, "rank_range")
+                              else (None, None))
             except Exception as e:          # noqa: BLE001 — a bad batch
                 self._fail_batch(batch, e)  # fails its own futures, the
                 continue                    # engine keeps serving
-            resolve_ms = (time.perf_counter() - t_res) * 1e3
-            self._m_resolve.observe(resolve_ms)
-            if trace is not None:
-                trace.add_span("resolve", wall_ms=resolve_ms, q=len(batch),
-                               stage="engine_resolver")
-            item = (batch, qv, rg, lo, hi, index, trace)
+            self._m_resolve.observe(st.ms)
+            # the put time rides the item: the hand-off wait includes any
+            # backpressure the bounded queue applies below
+            item = (batch, qv, rg, lo, hi, index, trace, seq,
+                    time.perf_counter())
             enqueued = False
             while not self._stop.is_set():  # bounded queue: backpressure
                 try:
@@ -326,21 +331,30 @@ class RFANNEngine:
 
     # ------------------------------------------------------- stage 2: dispatch
     def _dispatch_loop(self):
+        reg = self.registry
         while not self._stop.is_set() or not self._dq.empty():
+            set_batch(None)
+            with stage("await_batch", reg):     # wait for and take a batch
+                try:
+                    item = self._dq.get(timeout=0.05)
+                except queue.Empty:
+                    continue
+                self._m_handoff.observe(
+                    (time.perf_counter() - item[-1]) * 1e3)
+                self._m_hdepth.set(self._dq.qsize())
+                batch, qv, rg, lo, hi, r_index, trace, seq, _ = item
+                set_batch(seq)
+                index, kw = self._search_args(trace)
             try:
-                batch, qv, rg, lo, hi, r_index, trace = \
-                    self._dq.get(timeout=0.05)
-            except queue.Empty:
-                continue
-            self._m_hdepth.set(self._dq.qsize())
-            try:
-                self._serve_batch(batch, qv, rg, lo, hi, r_index, trace)
+                self._serve_batch(index, kw, batch, qv, rg, lo, hi, r_index,
+                                  trace)
             except Exception as e:          # noqa: BLE001 — a failed
                 # dispatch fails its futures with the error instead of
                 # killing this thread and leaving every caller blocked
                 self._fail_batch(batch, e)
 
-    def _serve_batch(self, batch, qv, rg, lo, hi, r_index, trace):
+    def _search_args(self, trace):
+        """The live index and the search keywords for one batch."""
         with self._index_lock:
             index = self.index
         # beam_width=1 is omitted so indexes predating the batched-
@@ -352,6 +366,10 @@ class RFANNEngine:
             kw["precision"] = self.precision
         if trace is not None:
             kw["trace"] = trace
+        return index, kw
+
+    def _serve_batch(self, index, kw, batch, qv, rg, lo, hi, r_index,
+                     trace):
         try:
             res = self._run_search(index, qv, rg, lo, hi, r_index, kw)
         except TypeError:
@@ -359,6 +377,10 @@ class RFANNEngine:
                 raise
             kw.pop("trace")             # index predates the trace API
             res = self._run_search(index, qv, rg, lo, hi, r_index, kw)
+        with stage("complete", self.registry, trace):
+            self._complete(batch, res, trace)
+
+    def _complete(self, batch, res, trace):
         if not hasattr(res, "row"):     # tuple-returning index
             from repro.search import SearchResult
             res = SearchResult(np.asarray(res[0]), np.asarray(res[1]), {})
@@ -369,14 +391,12 @@ class RFANNEngine:
         self.stats.cache_hits += int(res.stats.get("cache_hits", 0))
         self.stats.dedup_hits += int(res.stats.get("batch_dedup", 0))
         now = time.perf_counter()
-        lats = [(now - t0) * 1e3 for (_, _, t0, _) in batch]
         # account BEFORE resolving futures: a client that holds its
         # result must see the stats/metrics that include its request
-        for ms in lats:
-            self.stats.record_latency(ms)
         self.stats.served += len(batch)
         self.stats.batches += 1
-        self._m_e2e.observe_many(lats)
+        self._m_e2e.observe_many([(now - t0) * 1e3
+                                  for (_, _, t0, _) in batch])
         self._m_batch_size.observe(len(batch))
         self._m_requests.inc(len(batch))
         self._m_batches.inc()

@@ -189,3 +189,22 @@ def test_substrate_beam_width_parity(small_index):
         assert ok, (plan, why)
     # the auto plan's beam partitions calibrated the width-4 EMA
     assert 4 in ix.planner.cost._ndist_per_ef
+
+
+@pytest.mark.parametrize("bw", [1, 4])
+def test_beam_phases_are_named_in_the_hlo(bw):
+    """The beam program's phases carry ``beam.<phase>`` named scopes, which
+    reach the lowered program's op metadata (and so a device profile)."""
+    import re
+    n, d, m, q = 256, 8, 8, 4
+
+    def fn(v, nb, qv, lo, hi, e):
+        return beam_search_batch(v, nb, qv, lo, hi, e, k=5, ef=16,
+                                 beam_width=bw)
+    low = jax.jit(fn).lower(
+        jnp.zeros((n, d)), jnp.zeros((n, m), jnp.int32), jnp.zeros((q, d)),
+        jnp.zeros(q, jnp.int32), jnp.zeros(q, jnp.int32),
+        jnp.zeros(q, jnp.int32))
+    text = low.compile().as_text()
+    found = set(re.findall(r"beam\.(expand|visited|merge|finish)\b", text))
+    assert found == {"expand", "visited", "merge", "finish"}, found

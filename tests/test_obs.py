@@ -1,7 +1,9 @@
 """Observability tests: registry thread-safety, histogram percentile
-correctness against the np.percentile oracle, per-query trace completeness
-on every execution path, and exporter round-trips."""
+correctness against the np.percentile oracle, the stage primitive (nesting,
+histograms, trace spans, cost), per-query trace completeness on every
+execution path, the engine's stage coverage, and exporter round-trips."""
 import threading
+import time
 
 import jax
 import numpy as np
@@ -9,14 +11,18 @@ import pytest
 
 from repro.core.rfann import RNSGIndex
 from repro.data.ann import make_attrs, make_vectors, mixed_workload
-from repro.obs import (Counter, Gauge, Histogram, MetricsRegistry, QueryTrace,
-                       format_stats_line, maybe_span, parse_prometheus,
-                       to_prometheus)
+from repro.obs import (DISPATCHER_STAGES, Counter, Gauge, Histogram,
+                       MetricsRegistry, QueryTrace, format_stats_line,
+                       parse_prometheus, set_batch, stage, to_prometheus)
 from repro.search import SearchCache
 from repro.serving.distributed import DistributedRFANN
 from repro.serving.engine import RFANNEngine
 
-REQUIRED_SPANS = {"resolve", "plan", "dispatch", "stitch"}
+REQUIRED_SPANS = {"resolve", "plan", "assemble"}
+
+
+def _has_dispatch(names) -> bool:
+    return any(n.endswith("_dispatch") for n in names)
 
 
 # ------------------------------------------------------------- metrics core
@@ -38,23 +44,43 @@ def test_counter_thread_safety():
     assert c.value == n_threads * per
 
 
-def test_histogram_concurrent_observe():
+@pytest.mark.parametrize("path", ["observe_many", "observe"])
+def test_histogram_concurrent_observe(path):
+    """More threads than cores and a short switch interval: no update of
+    the batched or the scalar path is lost."""
+    import os
+    import sys
     h = Histogram("lat")
-    n_threads, per = 6, 400
+    n_threads, per = max(6, 2 * (os.cpu_count() or 1)), 400
 
     def work(seed):
         rng = np.random.default_rng(seed)
         for _ in range(per // 8):
-            h.observe_many(rng.uniform(0.1, 100.0, 8))
+            vals = rng.uniform(0.1, 100.0, 8)
+            if path == "observe_many":
+                h.observe_many(vals)
+            else:
+                for v in vals:
+                    h.observe(v)
 
-    ts = [threading.Thread(target=work, args=(i,)) for i in range(n_threads)]
-    for t in ts:
-        t.start()
-    for t in ts:
-        t.join()
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        ts = [threading.Thread(target=work, args=(i,))
+              for i in range(n_threads)]
+        for t in ts:
+            t.start()
+        for t in ts:
+            t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(old)
+    assert not any(t.is_alive() for t in ts)
     assert h.count == n_threads * per
     edges, cum = h.bucket_counts()
     assert int(cum[-1]) == h.count              # cumulative folds everything
+    want = sum(np.random.default_rng(i).uniform(0.1, 100.0, per).sum()
+               for i in range(n_threads))
+    assert h.sum == pytest.approx(want, rel=1e-9)
 
 
 @pytest.mark.parametrize("dist_name", ["lognormal", "uniform", "bimodal"])
@@ -166,18 +192,104 @@ def test_format_stats_line_shape():
     assert "p50=" in line and "p99=" in line
 
 
-# ------------------------------------------------------------------- traces
-def test_maybe_span_null_object():
-    with maybe_span(None, "dispatch") as sp:
-        sp.attrs["k"] = 1                    # dropped, never raises
-        sp.attrs.update(x=2)
+def test_histogram_scalar_observe_matches_observe_many():
+    """The numpy-free scalar path lands every value in the bucket
+    ``observe_many`` picks — bucket edges themselves included — with the
+    same count, sum, min and max."""
+    rng = np.random.default_rng(11)
+    one, many = Histogram("one"), Histogram("many")
+    vals = np.concatenate([np.exp(rng.uniform(np.log(1e-4), np.log(1e5),
+                                              10_000)),
+                           one.edges, np.nextafter(one.edges, 0),
+                           np.nextafter(one.edges, np.inf), [0.0, 1e9]])
+    for v in vals:
+        one.observe(v)
+    many.observe_many(vals)
+    assert one._counts == many._counts
+    assert one.count == many.count == len(vals)
+    assert one.sum == pytest.approx(many.sum, rel=1e-12)
+    assert one.snapshot()["min"] == many.snapshot()["min"]
+    assert one.snapshot()["max"] == many.snapshot()["max"]
+    np.testing.assert_array_equal(one.bucket_counts()[1],
+                                  many.bucket_counts()[1])
+
+
+# ------------------------------------------------------------------- stages
+def test_stage_nesting_and_self_time():
+    """A nested stage's wall time is taken out of the outer one's self
+    time; walls land in ``stage_<name>_ms`` and the trace keeps both."""
+    reg, tr = MetricsRegistry(), QueryTrace()
+    with stage("outer", reg, tr) as out:
+        time.sleep(0.004)
+        with stage("inner", reg, tr) as inn:
+            time.sleep(0.01)
+    assert inn.ms >= 10.0 and inn.self_ms == pytest.approx(inn.ms)
+    assert out.ms >= inn.ms + 4.0
+    assert out.self_ms == pytest.approx(out.ms - inn.ms, abs=1e-6)
+    snap = reg.snapshot()["histograms"]
+    assert snap["stage_outer_ms"]["sum"] == pytest.approx(out.ms)
+    assert snap["stage_inner_ms"]["sum"] == pytest.approx(inn.ms)
+    assert tr.names() == ["inner", "outer"]          # appended on exit
+    assert tr.get("outer").self_ms == pytest.approx(out.self_ms, rel=1e-6)
+    assert tr.get("inner").self_ms == pytest.approx(inn.ms, rel=1e-6)
+
+
+def test_stage_mirrors_every_exit_into_its_histogram():
+    """Each exit adds one observation — exceptions included — and the
+    histogram's sum is the stages' summed wall time."""
+    reg = MetricsRegistry()
+    walls = []
+    for i in range(5):
+        with stage("step", reg) as st:
+            time.sleep(0.001 * i)
+        walls.append(st.ms)
+    with pytest.raises(ValueError):
+        with stage("step", reg):
+            raise ValueError("propagates")
+    h = reg.snapshot()["histograms"]["stage_step_ms"]
+    assert h["count"] == 6
+    assert h["sum"] >= sum(walls) and h["sum"] == pytest.approx(
+        sum(walls), abs=1.0)
+
+
+def test_stage_copies_span_into_query_trace():
+    """With a trace the stage appends one span carrying its meta and the
+    attributes set while it ran; the batch tag reaches the profiler meta,
+    not the span; without a trace attribute writes are dropped."""
     tr = QueryTrace()
-    with maybe_span(tr, "dispatch", a=1) as sp:
-        sp.attrs["b"] = 2
-    assert tr.get("dispatch").attrs == {"a": 1, "b": 2}
-    assert tr.wall_ms("dispatch") >= 0.0
-    d = tr.to_dict()
-    assert d["spans"][0]["name"] == "dispatch"
+    set_batch(42)
+    try:
+        with stage("plan", None, tr, ns=3) as st:
+            st.attrs["pad_rows"] = 7
+            st.attrs.update(strategy_mode="auto")
+    finally:
+        set_batch(None)
+    sp = tr.get("plan")
+    assert sp.attrs == {"ns": 3, "pad_rows": 7, "strategy_mode": "auto"}
+    assert sp.wall_ms == pytest.approx(st.ms, rel=1e-6)
+    assert tr.to_dict()["spans"][0]["name"] == "plan"
+    with stage("plan", None, None) as st2:
+        st2.attrs["k"] = 1                  # dropped, never raises
+        st2.attrs.update(x=2)
+    assert len(tr.spans) == 1 and st2.ms >= 0.0
+
+
+def test_stage_without_registry_records_nothing_and_is_cheap():
+    """The no-registry, no-trace path only opens the profiler annotation
+    and times itself: nothing is created anywhere, and it stays in the
+    microseconds (a generous bound, for loaded test machines)."""
+    reg = MetricsRegistry()
+    n = 20_000
+    t0 = time.perf_counter()
+    for _ in range(n):
+        with stage("noop", None):
+            pass
+    per_us = (time.perf_counter() - t0) / n * 1e6
+    assert reg.snapshot()["histograms"] == {}
+    assert per_us < 25.0, per_us
+
+
+# ------------------------------------------------------------------- traces
 
 
 # small shared corpora for the path-coverage matrix -------------------------
@@ -236,6 +348,7 @@ def test_trace_completeness(path, plan, corpus, local_index, dist_local,
 
     names = set(tr.names())
     assert REQUIRED_SPANS <= names, (path, plan, tr.names())
+    assert _has_dispatch(names), (path, plan, tr.names())
     plan_sp = tr.get("plan")
     assert plan_sp.attrs["strategy_mode"] == plan
     if plan == "graph":
@@ -243,9 +356,8 @@ def test_trace_completeness(path, plan, corpus, local_index, dist_local,
     else:
         assert "strategy" in plan_sp.attrs       # per-query routing vector
         assert "scan_frac" in plan_sp.attrs
-    disp = tr.get("dispatch")
-    assert "cache_enabled" in disp.attrs         # cache outcome always there
-    assert disp.attrs["cache_enabled"] is False
+    assert "cache_enabled" in plan_sp.attrs      # cache outcome always there
+    assert plan_sp.attrs["cache_enabled"] is False
     for sp in tr.spans:
         assert sp.wall_ms >= 0.0
     # every span survives JSON conversion
@@ -256,8 +368,9 @@ def test_trace_completeness(path, plan, corpus, local_index, dist_local,
 @pytest.mark.parametrize("path", ["local", "dist", "mesh"])
 def test_trace_cache_outcome(path, corpus, local_index, dist_local,
                              dist_mesh):
-    """Second identical batch is served from the cache: the dispatch span
-    records dispatched=0 and cache_hits=Q (resolve/stitch still present)."""
+    """Second identical batch is served from the cache: the plan span
+    records dispatched=0 and cache_hits=Q, no dispatch stage runs, and
+    resolve/assemble are still present."""
     _, _, qv, ranges = corpus
     idx = _index(path, local_index, dist_local, dist_mesh)
     cache = SearchCache(max_bytes=4 << 20)
@@ -266,13 +379,14 @@ def test_trace_cache_outcome(path, corpus, local_index, dist_local,
         idx.search(qv, ranges, k=5, ef=32, plan="auto")         # populate
         tr = QueryTrace()
         idx.search(qv, ranges, k=5, ef=32, plan="auto", trace=tr)
-        disps = tr.all("dispatch")
-        assert disps, tr.names()
-        for sp in disps:
+        plans = tr.all("plan")
+        assert plans, tr.names()
+        for sp in plans:
             assert sp.attrs["cache_enabled"] is True
             assert sp.attrs["dispatched"] == 0
             assert sp.attrs["cache_hits"] == Q
-        assert {"resolve", "dispatch", "stitch"} <= set(tr.names())
+        assert {"resolve", "plan", "assemble"} <= set(tr.names())
+        assert not _has_dispatch(tr.names()), tr.names()
     finally:
         idx.install_cache(None)
 
@@ -332,15 +446,21 @@ def test_engine_metrics_percentiles_dedup_and_trace(local_index):
         assert eng.stats.dedup_hits > 0
         summ = eng.stats.summary()
         assert summ["dedup_hits"] == eng.stats.dedup_hits
-        assert summ["lat_seen"] == 16
+        assert summ["served"] == 16
 
         snap = eng.metrics()
         lat = snap["histograms"]["engine_e2e_ms"]
         assert lat["count"] == 16
         assert 0 < lat["p50"] <= lat["p99"]
+        # the operator summary's percentiles come from the same histogram
+        summ = eng.summary()
+        assert summ["p50_ms"] == pytest.approx(lat["p50"])
+        assert summ["p99_ms"] == pytest.approx(lat["p99"])
+        assert summ["served"] == 16
         assert snap["histograms"]["engine_batch_size"]["count"] >= 1
         assert eng.last_trace is not None
-        assert {"resolve", "dispatch", "stitch"} <= set(eng.last_trace.names())
+        names = eng.last_trace.names()
+        assert {"resolve", "plan", "assemble"} <= set(names), names
 
         text = to_prometheus(eng.registry)
         samples = parse_prometheus(text)
@@ -351,6 +471,78 @@ def test_engine_metrics_percentiles_dedup_and_trace(local_index):
         assert samples[("rnsg_engine_requests_total", "")] == 16
     finally:
         eng.close()
+
+
+@pytest.fixture(scope="module")
+def engine_index():
+    """A corpus whose beam batches take milliseconds on the CPU, as a
+    served batch does on the chip, so fixed per-stage costs weigh as they
+    would there."""
+    vecs = make_vectors(1024, D, seed=0)
+    attrs = make_attrs(1024, seed=0)
+    return RNSGIndex.build(vecs, attrs, m=8, ef_spatial=16, ef_attribute=24)
+
+
+def _serve_lockstep(index, n_batches: int, per: int, reg, seed: int = 0):
+    """Serve ``n_batches`` batches of exactly ``per`` requests, one after
+    the other; returns the engine (closed) and the wall time from the
+    dispatcher's start to its join."""
+    eng = RFANNEngine(index, k=5, ef=64, plan="auto", max_batch=per,
+                      max_wait_ms=200.0, metrics=reg)
+    t0 = time.perf_counter()
+    try:
+        rng = np.random.default_rng(seed)
+        for _ in range(n_batches):
+            qs = rng.standard_normal((per, D)).astype(np.float32)
+            futs = [eng.submit(q, (-0.5, 0.5)) for q in qs]
+            for f in futs:
+                f.result(timeout=60)
+    finally:
+        eng.close()
+    return eng, time.perf_counter() - t0
+
+
+def _window_sums(reg, names):
+    h = reg.snapshot()["histograms"]
+    return {n: h.get(f"stage_{n}_ms", {}).get("sum", 0.0) for n in names}
+
+
+def test_engine_stages_tile_the_dispatcher_loop(engine_index):
+    """Every instant of the dispatcher thread lies in one leaf stage: over
+    50 batches the leaf stages' summed wall covers at least 98 % of the
+    loop's wall time (and never more than all of it)."""
+    reg = MetricsRegistry()
+    _serve_lockstep(engine_index, 10, 16, reg)        # warm every shape
+    reg = MetricsRegistry()
+    eng, wall_s = _serve_lockstep(engine_index, 50, 16, reg, seed=1)
+    assert eng.stats.batches == 50
+    covered = sum(_window_sums(reg, DISPATCHER_STAGES).values())
+    share = covered / (wall_s * 1e3)
+    assert 0.98 <= share <= 1.0 + 1e-3, (share, _window_sums(
+        reg, DISPATCHER_STAGES))
+
+
+def test_engine_wait_and_serve_stages_add_up_to_e2e(engine_index):
+    """Per request, queue wait + resolve + hand-off wait + the dispatcher's
+    serve stages come within 5 % of the end-to-end mean (batches of equal
+    size, so per-batch and per-request means weigh alike)."""
+    reg = MetricsRegistry()
+    _serve_lockstep(engine_index, 10, 8, reg)         # warm every shape
+    reg = MetricsRegistry()
+    _serve_lockstep(engine_index, 40, 8, reg, seed=2)
+    h = reg.snapshot()["histograms"]
+    batches = h["engine_batch_size"]["count"]
+    assert h["engine_batch_size"]["sum"] == 8 * batches
+    serve = sum(v for n, v in _window_sums(reg, DISPATCHER_STAGES).items()
+                if n != "await_batch")
+    parts = (h["engine_queue_wait_ms"]["mean"]
+             + h["engine_resolve_ms"]["mean"]
+             + h["engine_handoff_wait_ms"]["mean"]
+             + serve / batches)
+    assert h["engine_queue_wait_ms"]["count"] == 8 * batches
+    assert h["engine_handoff_wait_ms"]["count"] == batches
+    e2e = h["engine_e2e_ms"]["mean"]
+    assert parts == pytest.approx(e2e, rel=0.05), (parts, e2e)
 
 
 def test_engine_trace_survives_untraced_index(corpus):

@@ -225,19 +225,7 @@ def test_cost_model_calibration_moves_estimates():
     assert cm.ndist_per_ef > 0
 
 
-# ------------------------------------------------------------------ engine stats
-def test_engine_stats_reservoir_is_bounded():
-    from repro.serving.engine import EngineStats
-    st = EngineStats(reservoir_size=256)
-    for i in range(10_000):
-        st.record_latency(float(i % 100))
-    assert len(st.latencies_ms) == 256
-    assert st.lat_seen == 10_000
-    s = st.summary()
-    assert 25.0 < s["p50_ms"] < 75.0               # sane percentile estimate
-    assert s["p99_ms"] <= 99.0
-
-
+# ------------------------------------------------------------------ engine
 def test_engine_serves_with_planner():
     vecs, attrs, idx = _small_index(n=512)
     from repro.serving.engine import RFANNEngine

@@ -124,3 +124,42 @@ def test_gather_rerank_compiles_for_v5e(one_chip):
     assert "tpu_custom_call" in _compiled_text(
         fn, _sds(s, (n, d), jnp.float32), _sds(s, (nq, m), jnp.int32),
         _sds(s, (nq, d), jnp.float32))
+
+
+# each kernel's explicit ``name=`` — the op name device profiles print — and
+# a small set of its operands (x, then the kernel's other inputs)
+def _kernel_cases():
+    from repro.kernels.l2dist import l2dist_pallas
+    f32, i32 = jnp.float32, jnp.int32
+    return {
+        "range_scan_pallas": (
+            lambda x, st, ln, q: range_scan_pallas(x, st, ln, q, bucket=1024,
+                                                   k=10),
+            [((1 << 14, 128), f32), ((8,), i32), ((8,), i32),
+             ((8, 128), f32)]),
+        "gather_dist_pallas": (
+            gather_dist_pallas,
+            [((4096, 128), f32), ((256,), i32), ((128,), f32)]),
+        "gather_topk_pallas": (
+            lambda x, ids, q: gather_topk_pallas(x, ids, q, k=10),
+            [((4096, 128), f32), ((256,), i32), ((128,), f32)]),
+        "gather_rerank_pallas": (
+            lambda x, ids, q: gather_rerank_pallas(x, ids, q, k=10),
+            [((4096, 128), f32), ((8, 64), i32), ((8, 128), f32)]),
+        "l2dist_pallas": (
+            l2dist_pallas, [((128, 128), f32), ((1024, 128), f32)]),
+    }
+
+
+@pytest.mark.parametrize("name", sorted(_kernel_cases()))
+def test_kernel_op_name_is_explicit(one_chip, name):
+    """Every Pallas kernel carries an explicit name, which the compiled
+    program gives its Mosaic op — the name a device profile prints, stable
+    under whatever jit encloses the kernel."""
+    import re
+    fn, shapes = _kernel_cases()[name]
+    text = _compiled_text(lambda *a: fn(*a),
+                          *[_sds(one_chip, sh, dt) for sh, dt in shapes])
+    ops = re.findall(r"%([\w.\-]+) = [^\n]*custom-call\([^\n]*tpu_custom_call",
+                     text)
+    assert ops and all(op.rsplit(".", 1)[0] == name for op in ops), ops

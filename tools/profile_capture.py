@@ -5,9 +5,10 @@
 
 Writes a profile directory (viewable with ``tensorboard --logdir`` or
 Perfetto) containing the device timeline for a short beam-width sweep.
-Host-side ``TraceAnnotation`` spans emitted by the substrate
-(``rnsg.scan_dispatch``, ``rnsg.beam_dispatch``, ...) appear in the trace,
-so kernel time lines up with the dispatch stages of docs/observability.md.
+The serve path's stages (``repro.obs.stage``: ``rnsg.plan``,
+``rnsg.scan_dispatch``, ``rnsg.beam_block``, ...) appear in the trace as
+host spans, so kernel time lines up with the stages of
+docs/observability.md.
 """
 import argparse
 import os
