@@ -121,8 +121,8 @@ def build_seconds(ix) -> float:
 
 def timed_search(ix, qv, ranges, k, ef, repeats: int = 2, warmups: int = 1,
                  **search_kw):
-    for _ in range(max(warmups, 1)):             # warm the jit (planner paths
-        ix.search(qv, ranges, k=k, ef=ef, **search_kw)   # may recalibrate)
+    for _ in range(max(warmups, 1)):             # warm the jit
+        ix.search(qv, ranges, k=k, ef=ef, **search_kw)
     best = np.inf
     out = None
     for _ in range(repeats):

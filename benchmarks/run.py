@@ -151,10 +151,8 @@ def bench_planner(n, d, nq, quick):
         ranges = selectivity_ranges(attrs, nq, frac, seed=17)
         qv = dataset(nq, d, seed=91)[0]
         gt = gt_for(vecs, attrs, qv, ranges, k)
-        # planner warms twice: the second warm runs with a calibrated cost
-        # model, so the timed repeats see the steady-state routing
         (pids, _, pst), pqps = timed_search(ix, qv, ranges, k, ef,
-                                            warmups=2, plan="auto")
+                                            plan="auto")
         (gids, _, _), gqps = timed_search(ix, qv, ranges, k, ef, plan="graph")
         (bids, _, _), bqps = timed_search(brute, qv, ranges, k, ef)
         for mname, ids, qps, sf in (
@@ -290,7 +288,7 @@ def bench_mesh_auto(n, d, nq, quick):
         qv = dataset(nq, d, seed=91)[0]
         gt = gt_for(vecs, attrs, qv, ranges, k)
         lo, hi = rank_interval(dist.attrs_sorted, ranges)
-        strat, _ = dist.mesh_substrate.plan_strategies(lo, hi, k=k, ef=ef,
+        strat, _ = dist.mesh_substrate.plan_strategies(lo, hi, k=k,
                                                        mode="auto")
         scan_frac = round(float((strat == 0).mean()), 3)
         for plan in ("graph", "auto"):
@@ -337,9 +335,7 @@ def bench_async_cache(n, d, nq, quick):
                                                  plan=plan)
         ix.install_cache(None)
         # the cache contract: hits are bit-identical to the dispatch that
-        # POPULATED them (fill vs cached).  u_ids is not part of the flag —
-        # under plan="auto" online recalibration between the uncached and
-        # fill passes can legitimately flip a boundary query's routing
+        # POPULATED them (fill vs cached)
         identical = bool(np.array_equal(fill.ids, c_ids)
                          and np.array_equal(fill.dists, c_d))
         rows.append(dict(method="cache_repeat", plan=plan,

@@ -35,9 +35,9 @@ for q in range(nq):
         assert i < 0 or ranges[q, 0] <= attrs[i] <= ranges[q, 1]
 print("all results in range ✓")
 
-# adaptive query planner (docs/planner.md): each query is routed to the
-# cheapest correct strategy — a fused exact scan of the rank slice for narrow
-# ranges, beam search for wide ones — with cost calibration happening online
+# adaptive query planner (docs/planner.md): each query is routed by its
+# selectivity — a fused exact scan of the rank slice for narrow ranges, beam
+# search for wide ones
 mixed = np.concatenate([selectivity_ranges(attrs, nq // 2, 0.005, seed=2),
                         selectivity_ranges(attrs, nq // 2, 0.5, seed=3)])
 pids, _, pstats = index.search(queries, mixed, k=k, ef=64, plan="auto")

@@ -4,23 +4,29 @@ The search never materializes the induced subgraph: the range filter is an
 id-interval mask applied to neighbor expansions (ids are attribute ranks), and
 Theorem 4.7 (heredity) guarantees this equals searching the induced RNSG.
 
-Two hot paths share one ``while_loop``-per-query / ``vmap``-over-batch shape:
+Two hot paths share one ``while_loop``-per-query / ``vmap``-over-batch shape,
+and both carry a visited set whose size does not depend on the corpus size n
+(a vmapped batch carries (Q, H) state, never (Q, n+1)):
 
-* ``beam_width=1`` — the legacy single-node expansion: candidate pool =
-  (ef,) arrays re-argsorted each hop, visited set = (n+1,) bitmask.  Kept
-  verbatim as the A/B oracle (every parity test doubles as a correctness
-  check of the batched path).
+* ``beam_width=1`` — single-node expansion: candidate pool = (ef,) arrays
+  re-argsorted (stable) over ``[pool, fresh]`` each hop.
 * ``beam_width=B>1`` — kernel-fused batched expansion: each iteration pops
   the best ``B`` unexpanded candidates, scores all ``B*m`` neighbors in one
-  fused gather+score call, folds them into the sorted pool with a bounded
-  O(ef+B*m) merge (sort only the fresh distances, then a stable
-  two-pointer merge via ``searchsorted`` — never a full pool argsort), and
-  tracks visited nodes in a **fixed-size lossy hash table** (2-probe,
-  open-addressed, sized by ``ef*m`` — independent of the corpus size n, so
-  a vmapped batch carries (Q, H) state instead of (Q, n+1)).  Hash
-  collisions only ever cause false *negatives*: a forgotten node is
-  re-scored, and the merge provably drops it (the pool's worst distance is
-  monotonically non-increasing once full), so results stay exact.
+  fused gather+score call, and folds them into the sorted pool with a
+  bounded O(ef+B*m) merge (sort only the fresh distances, then a stable
+  two-pointer merge via ``searchsorted`` — never a full pool argsort).
+
+The visited set is a **fixed-size lossy hash table** (2-probe,
+open-addressed, sized from ``ef`` and ``m`` — see
+``visited_table_size``) backed by a pool-membership test.  Collisions only
+ever cause false *negatives*: a forgotten node is re-scored, and the merge
+provably drops it — it was pushed out of (or kept out of) the pool by ef
+entries no worse than it, the pool's worst distance is monotonically
+non-increasing once full, and pool entries win distance ties — so ids,
+distances and hop counts are exactly those of an (n+1,) visited bitmap
+(the test suite keeps that bitmap search as its oracle); only the ``ndist``
+counter grows, by the re-scores, and ``evictions`` counts the ids the table
+forgot.
 
 Each hop's phases run under ``jax.named_scope`` — ``beam.expand`` (pick the
 node(s) to expand, gather and score their neighbors), ``beam.visited``
@@ -44,18 +50,26 @@ _HASH1 = 2654435761
 _HASH2 = 2246822519
 
 
-def visited_table_size(ef: int, m: int) -> int:
+def visited_table_size(ef: int, m: int, beam_width: int = 1) -> int:
     """Slots in the per-query lossy visited table (power of two).
 
-    A search scores ~ef·m̄ distinct nodes (the cost model's ``ndist_per_ef``
-    prior), but most re-discoveries are already caught by the pool-
-    membership dedup, so ~half a slot per potential insertion keeps the
-    collision — i.e. re-score — rate in the low percent while the carried
-    (Q, H) loop state stays small (the table is copied once per iteration
-    on backends that can't scatter in place, so oversizing it costs more
-    than the re-scores it prevents).  Deliberately **independent of n**:
-    this is the whole point of replacing the (n+1,) bitmask."""
-    target = max(int(ef), 1) * max(int(m), 4) // 2
+    Deliberately **independent of n**: the table replaces an (n+1,) bitmask.
+
+    ``beam_width=1`` inserts every in-range neighbor it scores, about
+    ``ef·m`` ids a search; the table takes four times that, so its load
+    stays under a quarter and few inserts evict (a 2-probe table that
+    overwrites its second probe loses about α²/3 of the inserts made while
+    it fills to load α).  Its re-scores cost no device work (every hop
+    scores all m neighbors and masks them); they only show in ``ndist``.
+
+    ``beam_width>1`` scores ~ef·m̄ distinct nodes, but most re-discoveries
+    are already caught by the pool-membership and intra-hop dedup, so ~half
+    a slot per potential insertion keeps the re-score rate in the low
+    percent while the carried (Q, H) loop state stays small (the table is
+    copied once per iteration on backends that can't scatter in place, so
+    oversizing it costs more than the re-scores it prevents)."""
+    ef, m = max(int(ef), 1), max(int(m), 1)
+    target = ef * max(m, 4) // 2 if beam_width > 1 else 4 * ef * m
     size = 1 << (target - 1).bit_length()
     return int(min(max(size, 256), 1 << 13))
 
@@ -69,17 +83,25 @@ def _hash_slots(ids: jax.Array, size: int) -> Tuple[jax.Array, jax.Array]:
     return h1, h2
 
 
-def _table_insert(table: jax.Array, ids: jax.Array, size: int) -> jax.Array:
-    """Insert ids (−1 = skip) into the 2-probe table ((size+1,), slot
-    ``size`` is the write sink).  First probe wins if its slot is empty or
-    already holds the id; otherwise the second probe is overwritten —
-    lossy by design, the evicted id is merely re-scored if met again."""
+def _table_insert(table: jax.Array, ids: jax.Array,
+                  size: int) -> Tuple[jax.Array, jax.Array]:
+    """Insert distinct ids (−1 = skip; none already stored) into the 2-probe
+    table ((size+1,), slot ``size`` is the write sink).  First probe wins if
+    its slot is empty or already holds the id; otherwise the second probe
+    is overwritten — lossy by design, a forgotten id is merely re-scored if
+    met again.  Returns the table and the number of ids it forgot: the ids
+    inserted less the empty slots they filled (an overwritten occupant, or
+    one of two new ids written to one slot).  Each written slot keeps
+    exactly one of the distinct ids sent to it, so a slot counts as filled
+    through its one surviving id — O(len(ids)) gathers, no pairwise test."""
     valid = ids >= 0
     h1, h2 = _hash_slots(ids, size)
     cur = table[h1]
     slot = jnp.where((cur == -1) | (cur == ids), h1, h2)
     slot = jnp.where(valid, slot, size)
-    return table.at[slot].set(jnp.where(valid, ids, -1))
+    new = table.at[slot].set(jnp.where(valid, ids, -1))
+    filled = valid & (new[slot] == ids) & (table[slot] == -1)
+    return new, jnp.sum(valid) - jnp.sum(filled)
 
 
 def _table_lookup(table: jax.Array, ids: jax.Array, size: int) -> jax.Array:
@@ -91,10 +113,11 @@ def _table_lookup(table: jax.Array, ids: jax.Array, size: int) -> jax.Array:
 
 def _merge_sorted(pool_d, pool_i, pool_e, fresh_d, fresh_i, fresh_e, ef: int):
     """Stable bounded merge: two distance-sorted candidate lists -> the best
-    ``ef``.  The batched path's replacement for the legacy full argsort
-    over the (ef+m) pool: one ``searchsorted`` places every pool entry in
-    the merged order (pool entries win distance ties, matching the stable
-    argsort over ``[pool, fresh]`` the legacy path performs), a second
+    ``ef``.  The batched path's replacement for the single-node path's
+    full argsort over the (ef+m) pool: one ``searchsorted`` places every
+    pool entry in the merged order (pool entries win distance ties,
+    matching the stable argsort over ``[pool, fresh]`` the single-node path
+    performs), a second
     inverts that placement so each output lane *gathers* its element —
     scatter-free on purpose, vmapped scatters serialize on CPU/XLA while
     gathers vectorize."""
@@ -154,14 +177,18 @@ def _pool_finish(cand_d, cand_ids, live, k: int, quant):
 
 
 @partial(jax.jit, static_argnames=("k", "ef", "max_steps", "use_kernel",
-                                   "early_stop", "beam_width"))
+                                   "early_stop", "beam_width",
+                                   "_visited_slots"))
 def beam_search_batch(vecs: jax.Array, nbrs: jax.Array, qv: jax.Array,
                       lo: jax.Array, hi: jax.Array, entry: jax.Array,
                       *, k: int = 10, ef: int = 64, max_steps: int = 0,
                       use_kernel: bool = False, early_stop: bool = True,
-                      beam_width: int = 1, quant=None, live=None):
+                      beam_width: int = 1, quant=None, live=None,
+                      _visited_slots: int = 0):
     """vecs:(n,d) f32; nbrs:(n,m) i32; qv:(Q,d); lo/hi/entry:(Q,) rank ids.
-    Returns (ids:(Q,k) i32 rank ids (-1 pad), dists:(Q,k), stats dict).
+    Returns (ids:(Q,k) i32 rank ids (-1 pad), dists:(Q,k), stats dict of
+    per-query ``hops``, ``ndist`` (scored neighbors) and ``evictions``
+    (ids the visited table forgot)).
 
     ``quant=(data, scale)`` switches neighbor scoring to the quantized
     corpus copy (``data``: (n,d) int8/bf16 in the same rank order;
@@ -190,9 +217,13 @@ def beam_search_batch(vecs: jax.Array, nbrs: jax.Array, qv: jax.Array,
     ``live`` ((n,) bool, optional) is the streaming tombstone mask: dead
     nodes are traversed exactly like live ones (they keep the graph
     navigable — removing them would break the heredity argument) but are
-    filtered out of the final pool before the top-k / rerank."""
+    filtered out of the final pool before the top-k / rerank.
+
+    ``_visited_slots`` overrides the visited table's size (a power of two;
+    0 sizes it by ``visited_table_size``) so tests can force evictions."""
     n, m = nbrs.shape
     steps_cap = max_steps or 8 * ef + 64
+    H = _visited_slots or visited_table_size(ef, m, beam_width)
     if live is not None:
         live = live.astype(bool)
 
@@ -200,7 +231,7 @@ def beam_search_batch(vecs: jax.Array, nbrs: jax.Array, qv: jax.Array,
         return _beam_batched(vecs, nbrs, qv, lo, hi, entry, k=k, ef=ef,
                              steps_cap=steps_cap, use_kernel=use_kernel,
                              early_stop=early_stop, beam_width=beam_width,
-                             quant=quant, live=live)
+                             H=H, quant=quant, live=live)
 
     # traversal scores against the quantized copy when one is given (the
     # dtype is trace-static, so the scale branch costs nothing at runtime)
@@ -239,10 +270,11 @@ def beam_search_batch(vecs: jax.Array, nbrs: jax.Array, qv: jax.Array,
         cand_ids = jnp.full((ef,), -1, jnp.int32).at[:ne].set(e0c.astype(jnp.int32))
         cand_d = jnp.full((ef,), INF).at[:ne].set(d0)
         expanded = jnp.zeros((ef,), bool).at[:ne].set(~ev)
-        visited = jnp.zeros((n + 1,), bool).at[jnp.where(ev, e0c, n)].set(True)
+        table, _ = _table_insert(jnp.full((H + 1,), -1, jnp.int32),
+                                 jnp.where(ev, e0c.astype(jnp.int32), -1), H)
 
         def cond(st):
-            cand_d, expanded, _, _, steps, _ = st
+            cand_d, expanded, _, _, steps, _, _ = st
             unexp = jnp.where(~expanded, cand_d, INF)
             best = jnp.min(unexp)
             worst = jnp.max(jnp.where(jnp.isfinite(cand_d), cand_d, -INF))
@@ -253,40 +285,50 @@ def beam_search_batch(vecs: jax.Array, nbrs: jax.Array, qv: jax.Array,
             return go
 
         def body(st):
-            cand_d, expanded, cand_ids, visited, steps, ndist = st
+            cand_d, expanded, cand_ids, table, steps, ndist, lost = st
             with jax.named_scope("beam.expand"):
                 unexp = jnp.where(~expanded, cand_d, INF)
                 bi = jnp.argmin(unexp)
                 expanded = expanded.at[bi].set(True)
                 node = jnp.maximum(cand_ids[bi], 0)
-                nb = nbrs[node]                               # (m,)
+                nb = nbrs[node].astype(jnp.int32)             # (m,)
                 valid = (nb >= 0) & (nb >= L) & (nb <= R)
             with jax.named_scope("beam.visited"):
-                valid = valid & ~visited[jnp.maximum(nb, 0)]
-                visited = visited.at[jnp.where(valid, nb, n)].set(True)
+                # every node ever scored is either held in the pool with a
+                # finite distance or in the table, unless the table forgot
+                # it after the pool dropped it — then the re-score ranks it
+                # behind all ef pool entries and the merge drops it again
+                # (module docstring); unfilled slots (inf) hold no node
+                in_pool = jnp.any((nb[:, None] == cand_ids[None, :])
+                                  & jnp.isfinite(cand_d)[None, :], axis=1)
+                valid &= ~in_pool & ~_table_lookup(table, nb, H)
+                table, lost_h = _table_insert(
+                    table, jnp.where(valid, nb, -1), H)
             with jax.named_scope("beam.expand"):
                 d_nb = neighbor_dists(q, nb, valid)
             with jax.named_scope("beam.merge"):
-                ids_all = jnp.concatenate([cand_ids, nb.astype(jnp.int32)])
+                ids_all = jnp.concatenate([cand_ids, nb])
                 d_all = jnp.concatenate([cand_d, d_nb])
                 # invalid neighbors: never expand
                 exp_all = jnp.concatenate([expanded, ~valid])
-                order = jnp.argsort(d_all)[:ef]
+                order = jnp.argsort(d_all, stable=True)[:ef]
                 return (d_all[order], exp_all[order], ids_all[order],
-                        visited, steps + 1, ndist + jnp.sum(valid))
+                        table, steps + 1, ndist + jnp.sum(valid),
+                        lost + lost_h)
 
-        st = (cand_d, expanded, cand_ids, visited,
-              jnp.zeros((), jnp.int32), jnp.zeros((), jnp.int32))
-        cand_d, _, cand_ids, _, steps, ndist = jax.lax.while_loop(cond, body, st)
+        zero = jnp.zeros((), jnp.int32)
+        st = (cand_d, expanded, cand_ids, table, zero, zero, zero)
+        cand_d, _, cand_ids, _, steps, ndist, lost = jax.lax.while_loop(
+            cond, body, st)
         with jax.named_scope("beam.finish"):
             out_ids, out_d = _pool_finish(cand_d, cand_ids, live, k, quant)
-        return out_ids, out_d, steps, ndist
+        return out_ids, out_d, steps, ndist, lost
 
-    ids, dists, steps, ndist = jax.vmap(one_query)(qv, lo, hi, entry)
+    ids, dists, steps, ndist, lost = jax.vmap(one_query)(qv, lo, hi, entry)
     if quant is not None:
         with jax.named_scope("beam.finish"):
             ids, dists = rerank_pool(vecs, ids, qv, k, use_kernel)
-    return ids, dists, {"hops": steps, "ndist": ndist}
+    return ids, dists, {"hops": steps, "ndist": ndist, "evictions": lost}
 
 
 # ======================================================================
@@ -294,7 +336,7 @@ def beam_search_batch(vecs: jax.Array, nbrs: jax.Array, qv: jax.Array,
 # ======================================================================
 def _beam_batched(vecs, nbrs, qv, lo, hi, entry, *, k: int, ef: int,
                   steps_cap: int, use_kernel: bool, early_stop: bool,
-                  beam_width: int, quant=None, live=None):
+                  beam_width: int, H: int, quant=None, live=None):
     n, m = nbrs.shape
     score_x, score_scale = (vecs, None) if quant is None else quant
     # the pool holds ef candidates, so at most ef can be unexpanded — a
@@ -302,7 +344,6 @@ def _beam_batched(vecs, nbrs, qv, lo, hi, entry, *, k: int, ef: int,
     # rather than rejected
     B = min(int(beam_width), ef)
     F = B * m                           # fresh neighbors per iteration
-    H = visited_table_size(ef, m)
     # only the best min(F, ef) fresh candidates can survive the bounded
     # merge, so the fused kernel keeps a running top-fm in VMEM and the
     # full (F,) distance vector never leaves it
@@ -352,12 +393,11 @@ def _beam_batched(vecs, nbrs, qv, lo, hi, entry, *, k: int, ef: int,
         expanded = jnp.zeros((ef,), bool).at[:ne].set(~ev)
         o = jnp.argsort(cand_d)         # sort once; the merge keeps it sorted
         cand_d, cand_ids, expanded = cand_d[o], cand_ids[o], expanded[o]
-        table = jnp.full((H + 1,), -1, jnp.int32)
-        table = _table_insert(table, jnp.where(ev, e0c.astype(jnp.int32), -1),
-                              H)
+        table, _ = _table_insert(jnp.full((H + 1,), -1, jnp.int32),
+                                 jnp.where(ev, e0c.astype(jnp.int32), -1), H)
 
         def cond(st):
-            cand_d, expanded, _, _, steps, _ = st
+            cand_d, expanded, _, _, steps, _, _ = st
             unexp = jnp.where(~expanded, cand_d, INF)
             best = jnp.min(unexp)
             worst = jnp.max(jnp.where(jnp.isfinite(cand_d), cand_d, -INF))
@@ -368,7 +408,7 @@ def _beam_batched(vecs, nbrs, qv, lo, hi, entry, *, k: int, ef: int,
             return go
 
         def body(st):
-            cand_d, expanded, cand_ids, table, steps, ndist = st
+            cand_d, expanded, cand_ids, table, steps, ndist, lost = st
             with jax.named_scope("beam.expand"):
                 # best B unexpanded: the pool is sorted, so they are the
                 # first B selectable lanes
@@ -387,7 +427,7 @@ def _beam_batched(vecs, nbrs, qv, lo, hi, entry, *, k: int, ef: int,
                          & jnp.repeat(node >= 0, m))
             with jax.named_scope("beam.visited"):
                 # intra-hop dedup: two expanded nodes may share a neighbor
-                # — keep the first occurrence (the legacy path never sees
+                # — keep the first occurrence (the single-node path never sees
                 # this: its single hop has unique neighbors)
                 eq = ids_f[:, None] == ids_f[None, :]
                 before = jnp.arange(F)[None, :] < jnp.arange(F)[:, None]
@@ -401,7 +441,8 @@ def _beam_batched(vecs, nbrs, qv, lo, hi, entry, *, k: int, ef: int,
                 # lossy visited set: false negatives fall through to a
                 # re-score
                 valid &= ~_table_lookup(table, ids_f, H)
-                table = _table_insert(table, jnp.where(valid, ids_f, -1), H)
+                table, lost_h = _table_insert(
+                    table, jnp.where(valid, ids_f, -1), H)
             with jax.named_scope("beam.expand"):
                 fd, fi = fresh_sorted(q, ids_f, valid)
             with jax.named_scope("beam.merge"):
@@ -409,18 +450,18 @@ def _beam_batched(vecs, nbrs, qv, lo, hi, entry, *, k: int, ef: int,
                 cand_d, cand_ids, expanded = _merge_sorted(
                     cand_d, cand_ids, expanded, fd, fi, fe, ef)
                 return (cand_d, expanded, cand_ids, table,
-                        steps + 1, ndist + jnp.sum(valid))
+                        steps + 1, ndist + jnp.sum(valid), lost + lost_h)
 
-        st = (cand_d, expanded, cand_ids, table,
-              jnp.zeros((), jnp.int32), jnp.zeros((), jnp.int32))
-        cand_d, _, cand_ids, _, steps, ndist = jax.lax.while_loop(
+        zero = jnp.zeros((), jnp.int32)
+        st = (cand_d, expanded, cand_ids, table, zero, zero, zero)
+        cand_d, _, cand_ids, _, steps, ndist, lost = jax.lax.while_loop(
             cond, body, st)
         with jax.named_scope("beam.finish"):
             out_ids, out_d = _pool_finish(cand_d, cand_ids, live, k, quant)
-        return out_ids, out_d, steps, ndist
+        return out_ids, out_d, steps, ndist, lost
 
-    ids, dists, steps, ndist = jax.vmap(one_query)(qv, lo, hi, entry)
+    ids, dists, steps, ndist, lost = jax.vmap(one_query)(qv, lo, hi, entry)
     if quant is not None:
         with jax.named_scope("beam.finish"):
             ids, dists = rerank_pool(vecs, ids, qv, k, use_kernel)
-    return ids, dists, {"hops": steps, "ndist": ndist}
+    return ids, dists, {"hops": steps, "ndist": ndist, "evictions": lost}

@@ -57,8 +57,7 @@ class RNSGGraph:
     def save(self, path: str) -> None:
         """Atomic single-file save: the npz is written to a sibling temp
         file, fsynced, and renamed over ``path`` — a crash mid-save never
-        corrupts the only copy of the index (same idiom as
-        ``QueryPlanner.save_calibration``).  The parent directory is
+        corrupts the only copy of the index.  The parent directory is
         fsynced after the rename (``repro.index.io.fsync_dir``) so the
         rename itself survives power failure, not just the file bytes.
         ``meta`` and ``build_seconds`` ride along as a JSON sidecar entry

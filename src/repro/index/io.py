@@ -53,8 +53,7 @@ def fsync_dir(path) -> None:
     file contents durable, but the new *name* lives in the directory
     inode — on most filesystems it is only guaranteed on disk after the
     directory itself is fsynced.  Shared by every atomic-save site
-    (``RNSGGraph.save``, ``QueryPlanner.save_calibration``, the
-    ``save_index`` array/manifest commits, and the WAL's segment
+    (``RNSGGraph.save``, the ``save_index`` array/manifest commits, and the WAL's segment
     create/rotate).  No-op on platforms that refuse O_DIRECTORY opens or
     directory fsync (e.g. Windows) — there is no portable stronger
     guarantee there."""

@@ -36,7 +36,7 @@ from jax.experimental.pallas import tpu as pltpu
 def window_rows(bucket: int, tb: int = 128) -> int:
     """Rows actually scanned for a bucket: ceil(bucket/tb) blocks plus one
     extra block so any start alignment is covered (single source of truth —
-    the kernel, its jnp oracle, and the planner cost model all use this)."""
+    the kernel, its jnp oracle and the planner all use this)."""
     return (-(-bucket // tb) + 1) * tb
 
 

@@ -23,7 +23,7 @@ sharded constructor (bit-identical output).
 mutation is appended to a checksummed write-ahead log before it is
 acknowledged, restart replays the uncompacted tail onto the
 ``--index-path`` checkpoint, SIGTERM drains gracefully (seal WAL,
-checkpoint, persist calibration + metrics), and a WAL write failure
+checkpoint, persist metrics), and a WAL write failure
 degrades the server to read-only instead of crashing it.  See
 ``docs/durability.md``.
 """
@@ -133,7 +133,6 @@ def serve_rfann(args):
                          beam_width=args.beam_width,
                          precision=args.precision,
                          max_batch=args.max_batch, max_wait_ms=2.0,
-                         calibration_path=args.calibration or None,
                          cache_bytes=args.cache_mb << 20,
                          log_interval_s=args.log_interval,
                          trace_sample_every=args.trace_sample_every,
@@ -148,7 +147,7 @@ def serve_rfann(args):
               "but leaves no checkpoint to recover onto")
     # graceful SIGTERM: stop accepting work, drain in-flight futures, then
     # the normal shutdown path seals the WAL and persists index +
-    # calibration + metrics — zero acknowledged mutations lost
+    # metrics — zero acknowledged mutations lost
     preempt = PreemptionHandler().install()
     futs = []
     churn_until = args.requests // 2
@@ -186,13 +185,11 @@ def serve_rfann(args):
         idx.close()     # drain any in-flight compaction, seal the WAL
     if engine.cache is not None:
         print(f"[serve] result cache: {engine.cache.snapshot()}")
-    if args.calibration:
-        print(f"[serve] cost-model calibration persisted to {args.calibration}")
     if args.index_path:
         print(f"[serve] index persisted to {args.index_path} "
               f"({args.index_shards} shards) — restored on next startup")
     if args.metrics_path:
-        # final snapshot on shutdown, alongside the calibration save:
+        # final snapshot on shutdown, alongside the index save:
         # Prometheus text at the given path, JSON snapshot as a sibling
         from repro.obs import write_prometheus
         write_prometheus(engine.registry, args.metrics_path)
@@ -289,9 +286,6 @@ def main(argv=None):
                          "multi-device constructor over this many device "
                          "slabs (0 = single-host build; results are "
                          "bit-identical either way)")
-    ap.add_argument("--calibration", default="",
-                    help="JSON path: load cost-model calibration at startup, "
-                         "persist it on shutdown")
     ap.add_argument("--cache-mb", type=int, default=0,
                     help="result-cache byte budget in MiB (0 = no cache)")
     ap.add_argument("--metrics-path", default="",

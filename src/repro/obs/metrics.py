@@ -19,7 +19,7 @@ is exact up to one bucket's relative width (``growth - 1``, ~25% by
 default) — tight enough for p50/p90/p99 latency reporting at O(1) memory,
 and validated against the ``np.percentile`` oracle in ``tests/test_obs.py``.
 
-Pull-style metrics (cache occupancy, cost-model EMAs, …) register a
+Pull-style metrics (cache occupancy, engine summary, …) register a
 **producer** callback: a zero-argument callable returning a flat dict of
 scalars, invoked only at snapshot/export time — zero hot-path cost.
 """

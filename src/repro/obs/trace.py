@@ -8,7 +8,7 @@ the order the stages ran (see docs/observability.md for the stage table):
 
     resolve          attribute range -> rank interval (interval widths, Q)
     plan             cache split and routing decision (cache outcome,
-                     strategy vector, predicted costs, partitions)
+                     strategy vector, partitions)
     *_prep           host arrays, padding and copies for one partition
     *_dispatch       device-work enqueue
     *_block          wait for the device outputs and copy them back

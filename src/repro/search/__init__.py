@@ -18,8 +18,7 @@ primitives:
 
 * ``SearchSubstrate`` — one attribute-sorted corpus slice on the host
   (single node, or one shard of the distributed local path); the planner
-  partitions each batch into fixed-shape jit dispatches and calibrates the
-  cost model from observed wall times.
+  partitions each batch into fixed-shape jit dispatches.
 * ``MeshSubstrate`` — all shards at once under ``shard_map``; the planner
   runs host-side over shard-clipped global intervals and the traced
   per-device body executes a branchless scan+beam select, restitched in

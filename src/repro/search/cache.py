@@ -17,8 +17,8 @@ collide.
 Eviction is LRU under an explicit **byte budget** (ids/dists row bytes +
 per-entry overhead), so a long-running server holds a bounded working set
 regardless of query-stream cardinality.  ``invalidate()`` empties the cache
-wholesale — required whenever the index contents or the calibration that
-results were computed under change (``RFANNEngine.swap_index`` wires this).
+wholesale — required whenever the index contents change
+(``RFANNEngine.swap_index`` wires this).
 ``invalidate_segment(ns)`` is the surgical variant for multi-segment indexes:
 it drops only rows whose namespace matches and bumps that namespace's
 **segment epoch**, so a streaming compaction that replaces the base segment
@@ -41,16 +41,14 @@ rank→id remap — a repeat-query batch performs no device work at all.
 
 Results returned from a hit are the stored bytes verbatim, so a cached
 batch is bit-identical to the dispatch that populated it (asserted by the
-parity tests).  Under ``strategy="auto"`` a stored row reflects the routing
-decision at store time; online calibration may route a later identical
-query differently, but both executions are valid results for the same
-(query, range, k, ef) contract.
+parity tests).  Under ``strategy="auto"`` routing depends only on the
+interval's length and ``k``, so a stored row is the row a re-execution
+would return.
 """
 from __future__ import annotations
 
 import hashlib
 import threading
-import time
 from collections import OrderedDict
 from dataclasses import dataclass
 from typing import Dict, List, Optional, Tuple
@@ -97,20 +95,10 @@ def query_key(q: np.ndarray, lo: int, hi: int, k: int, ef: int,
 
 @dataclass
 class CacheEntry:
-    """One finished per-query result (original corpus ids, -1 padded).
-
-    ``stamp``/``cal_epoch`` implement staleness fencing for rows whose
-    routing was a *decision*, not part of the request contract:
-    ``strategy="auto"`` rows record the planner's calibration epoch at
-    store time (``cal_epoch``) and their insertion time (``stamp``).  A
-    later lookup re-validates both — see :meth:`SearchCache.lookup`.
-    Forced-strategy rows leave ``cal_epoch`` as ``None`` and are never
-    age- or epoch-expired (their result is calibration-independent)."""
+    """One finished per-query result (original corpus ids, -1 padded)."""
     ids: np.ndarray                 # (k,) int32
     dists: np.ndarray               # (k,) float32
     stats: Dict[str, np.generic]    # scalar per-query stats (hops/ndist/...)
-    stamp: float = 0.0              # clock() at store time
-    cal_epoch: Optional[int] = None  # planner calibration epoch (auto rows)
 
     @property
     def nbytes(self) -> int:
@@ -124,15 +112,8 @@ class SearchCache:
     Thread-safe: the engine's dispatch thread and ``swap_index`` callers may
     touch it concurrently (one short lock around every structural op)."""
 
-    def __init__(self, max_bytes: int = 64 << 20, *,
-                 ttl_s: Optional[float] = None, clock=time.monotonic):
-        """``ttl_s`` bounds the age of ``strategy="auto"`` rows (None = no
-        age limit); ``clock`` is injectable for deterministic expiry tests.
-        Forced-strategy rows are exempt — their result does not depend on
-        planner calibration, so age cannot make them wrong."""
+    def __init__(self, max_bytes: int = 64 << 20):
         self.max_bytes = int(max_bytes)
-        self.ttl_s = ttl_s
-        self.clock = clock
         self._d: "OrderedDict[Tuple, CacheEntry]" = OrderedDict()
         self._lock = threading.Lock()
         self.bytes = 0
@@ -144,35 +125,17 @@ class SearchCache:
         self.evictions = 0
         self.invalidations = 0
         self.seg_invalidations = 0
-        self.expired = 0        # TTL / calibration-epoch expiries
 
     def __len__(self) -> int:
         return len(self._d)
 
     # ------------------------------------------------------------ core ops
-    def lookup(self, key: Tuple,
-               cal_epoch: Optional[int] = None) -> Optional[CacheEntry]:
-        """``cal_epoch``: the planner's current calibration epoch.  Entries
-        stored under ``strategy="auto"`` (``entry.cal_epoch is not None``)
-        are re-validated on every hit: a calibration-epoch mismatch (the
-        planner persisted new calibration since the row was stored) or an
-        age beyond ``ttl_s`` expires the row — it is dropped and the lookup
-        counts as a miss, so the caller re-executes under current routing."""
+    def lookup(self, key: Tuple) -> Optional[CacheEntry]:
         with self._lock:
             e = self._d.get(key)
             if e is None:
                 self.misses += 1
                 return None
-            if e.cal_epoch is not None:
-                stale = (cal_epoch is not None and e.cal_epoch != cal_epoch)
-                if not stale and self.ttl_s is not None:
-                    stale = (self.clock() - e.stamp) > self.ttl_s
-                if stale:
-                    del self._d[key]
-                    self.bytes -= e.nbytes
-                    self.expired += 1
-                    self.misses += 1
-                    return None
             self._d.move_to_end(key)
             self.hits += 1
             return e
@@ -198,7 +161,6 @@ class SearchCache:
                         return
                 elif epoch != self.epoch:
                     return
-            entry.stamp = self.clock()
             old = self._d.pop(key, None)
             if old is not None:
                 self.bytes -= old.nbytes
@@ -250,14 +212,13 @@ class SearchCache:
                     misses=self.misses, dedup_hits=self.dedup_hits,
                     evictions=self.evictions,
                     invalidations=self.invalidations,
-                    seg_invalidations=self.seg_invalidations,
-                    expired=self.expired)
+                    seg_invalidations=self.seg_invalidations)
 
     # ------------------------------------------------- batch split / stitch
     def split(self, qv: np.ndarray, lo: np.ndarray, hi: np.ndarray, k: int,
               ef: int, strategy: str, use_kernel: bool = False, ns=None,
               digests: Optional[List[bytes]] = None, beam_width: int = 1,
-              precision: str = "f32", cal_epoch: Optional[int] = None):
+              precision: str = "f32"):
         """Partition one batch into cache hits, misses, and intra-batch
         duplicates of a miss.
 
@@ -281,7 +242,7 @@ class SearchCache:
         first_at: Dict[Tuple, int] = {}     # miss key -> its slot in `miss`
         dups: Dict[int, int] = {}
         for i, key in enumerate(keys):
-            e = self.lookup(key, cal_epoch=cal_epoch)
+            e = self.lookup(key)
             if e is not None:
                 hit_rows[i] = e
                 continue
@@ -297,20 +258,17 @@ class SearchCache:
         return keys, hit_rows, np.asarray(miss, np.int64), dups
 
     def store_batch(self, keys: List[Tuple], res: SearchResult,
-                    epoch=None,
-                    cal_epoch: Optional[int] = None) -> None:
+                    epoch=None) -> None:
         """Store every row of a finished miss-batch result (rows are copied
         so the cache never pins the batch arrays).  Pass the ``epoch``
-        captured at split time — see :meth:`store`.  ``cal_epoch`` (auto
-        rows only) arms the staleness fence on each stored entry."""
+        captured at split time — see :meth:`store`."""
         q = len(res.ids)
         per_row = [(n, v) for n, v in res.stats.items()
                    if isinstance(v, np.ndarray) and v.ndim >= 1 and len(v) == q]
         for j, key in enumerate(keys):
             self.store(key, CacheEntry(
                 np.array(res.ids[j]), np.array(res.dists[j]),
-                {n: v[j] for n, v in per_row},
-                cal_epoch=cal_epoch), epoch=epoch)
+                {n: v[j] for n, v in per_row}), epoch=epoch)
 
     def assemble(self, q: int, k: int, hit_rows: Dict[int, CacheEntry],
                  miss_res: Optional[SearchResult],

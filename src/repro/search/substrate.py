@@ -22,7 +22,7 @@ under a profiler session, on the device trace as ``rnsg.<name>``):
 * ``assemble`` — partition results land back in request order, rank ids
                  are remapped to original corpus ids, per-query stats
                  (hops / ndist / strategy) are assembled, the cache stores
-                 and assembles, and the dispatch histograms and cost model
+                 and assembles, and the dispatch histograms and counters
                  are fed.
 
 Dispatch is **asynchronous at the substrate boundary**: ``dispatch(req)``
@@ -30,16 +30,11 @@ enqueues all device work (jax async dispatch) and returns a
 ``PendingSearch`` whose ``result()`` blocks and assembles.  ``run`` is the
 synchronous spelling (``dispatch(..., defer=False).result()``); the
 distributed local path dispatches every shard before blocking any of them,
-overlapping the per-shard device queues.  Deferred dispatches skip
-wall-time calibration (their block time includes sibling shards' work),
-while ndist-based beam calibration still applies.
+overlapping the per-shard device queues.
 
 Scan partitions pad with empty windows (masked, ~free); beam partitions pad
 by duplicating the last real query (a duplicate lane adds no extra
-``while_loop`` iterations under vmap).  After every planned synchronous
-dispatch the substrate feeds the cost model: observed ``ndist`` from beam
-stats and warm-call wall times per work unit (the first call of each jit
-signature is excluded so compile time never enters calibration).
+``while_loop`` iterations under vmap).
 
 ``MeshSubstrate`` is the ``shard_map`` twin for multi-device serving: the
 planner runs **host-side** over the globally resolved rank intervals (clipped
@@ -48,17 +43,13 @@ scan/beam sub-batches that enter the traced per-device body as replicated
 operands — a branchless select in which each shard executes the ``range_scan``
 kernel and the beam search at most once per call, scatters both groups back
 into request order, and finishes with the cross-shard ``all_gather`` + top-k
-merge.  Warm-call wall times of the traced dispatches feed the cost model
-(mixed scan+beam calls are attributed proportionally to predicted unit
-costs — ``CostModel.observe_wall_mixed``), so mesh routing converges to
-measured hardware ratios instead of serving from the prior forever.  See
-docs/distributed.md.
+merge.  See docs/distributed.md.
 """
 from __future__ import annotations
 
 import time
 from functools import partial
-from typing import Callable, Dict, Optional, Set, Tuple
+from typing import Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -97,8 +88,8 @@ class PendingSearch:
     """Handle for an in-flight substrate dispatch.
 
     The device work is already enqueued when this object exists (jax async
-    dispatch); ``result()`` blocks on the outputs, assembles, feeds the cost
-    model, and returns the ``SearchResult``.  Idempotent — repeated calls
+    dispatch); ``result()`` blocks on the outputs, assembles, and returns
+    the ``SearchResult``.  Idempotent — repeated calls
     return the same object."""
     __slots__ = ("_finalize", "_result")
 
@@ -134,14 +125,10 @@ class SearchSubstrate:
         self.n, self.d = n, d
         self.tb = ROW_TILE          # must match the range_scan kernel tile
         self.d_pad = -(-d // 128) * 128
-        if planner is None:
-            deg = float((np.asarray(nbrs) >= 0).sum(1).mean()) if n else 1.0
-            planner = QueryPlanner(max(n, 1), deg)
-        self.planner = planner
+        self.planner = planner or QueryPlanner(max(n, 1))
         self._x_pad = None          # padded scan copy, built on first scan
         self._quant: Dict[str, dict] = {}   # precision -> quantized slots
         self._live_memo = None      # (mask, (n,) bool dev, (1,n_pad) i32 dev)
-        self._warm: Set[Tuple] = set()
 
     @classmethod
     def from_graph(cls, g, **kw) -> "SearchSubstrate":
@@ -163,9 +150,8 @@ class SearchSubstrate:
         """Enqueue one request's device work and return a ``PendingSearch``.
 
         ``defer=True`` (the async path) enqueues every partition before any
-        block and skips wall-time calibration; ``defer=False`` blocks each
-        partition before dispatching the next, and its wall times are
-        clean enough to calibrate on.  Cache hits are resolved here — a
+        block; ``defer=False`` blocks each partition before dispatching the
+        next.  Cache hits are resolved here — a
         fully-hit request performs no device work at all.  ``q_digests``
         are optional precomputed ``hash_query`` values (the distributed
         local path hashes each query once, not once per shard).
@@ -175,7 +161,7 @@ class SearchSubstrate:
         (host arrays, padding, copies), ``*_dispatch`` (the enqueue) and
         ``*_block`` (device wait and copy back), then ``assemble``
         (request-order scatter, id remap, cache store and assembly, the
-        dispatch histograms and cost-model feedback).  A ``req.trace``
+        dispatch histograms and counters).  A ``req.trace``
         collects the same spans; the installed ``MetricsRegistry`` (when
         any) receives the stage histograms, routed counts, cache outcomes
         and pad waste."""
@@ -202,12 +188,10 @@ class SearchSubstrate:
                 # streaming layer bumps the segment epoch on every
                 # tombstone change / compaction
                 epoch = cache.epoch_for(self.cache_ns)
-                cal_epoch = (self.planner.calibration_epoch
-                             if mode == "auto" else None)
                 keys, hit_rows, miss, dups = cache.split(
                     qv, lo, hi, k, ef, mode, req.use_kernel,
                     ns=self.cache_ns, digests=q_digests, beam_width=bw,
-                    precision=prec, cal_epoch=cal_epoch)
+                    precision=prec)
                 cache_info.update(cache_hits=len(hit_rows),
                                   cache_misses=len(miss),
                                   batch_dedup=len(dups))
@@ -216,7 +200,7 @@ class SearchSubstrate:
                     met.counter("cache_miss_rows_total").inc(len(miss))
                     if dups:
                         met.counter("cache_dedup_rows_total").inc(len(dups))
-                split = (epoch, cal_epoch, keys, hit_rows, miss, dups)
+                split = (epoch, keys, hit_rows, miss, dups)
                 qv, lo, hi = qv[miss], lo[miss], hi[miss]
             work = split is None or len(qv) > 0
             sp.attrs.update(cache_info, strategy_mode=mode,
@@ -229,7 +213,7 @@ class SearchSubstrate:
                 if met is not None and len(qv):
                     met.counter("graph_queries_total").inc(len(qv))
             elif work:
-                plan = self._plan(lo, hi, k, ef, mode, bw, prec, tr, sp)
+                plan = self._plan(lo, hi, k, ef, mode, tr, sp)
         if not work:                    # fully hit: no device work at all
             blocks = []
         elif plan is None:
@@ -252,10 +236,10 @@ class SearchSubstrate:
                     res = SearchResult(resolve.remap_ids(self.order, ids),
                                        dists, stats)
                 if split is not None:
-                    epoch, cal_epoch, keys, hit_rows, miss, dups = split
+                    epoch, keys, hit_rows, miss, dups = split
                     if res is not None:
                         cache.store_batch([keys[i] for i in miss], res,
-                                          epoch=epoch, cal_epoch=cal_epoch)
+                                          epoch=epoch)
                     if hit_rows or dups or res is None:
                         res = cache.assemble(nq, k, hit_rows, res, miss,
                                              dups)
@@ -265,22 +249,14 @@ class SearchSubstrate:
             return res
         return PendingSearch(finalize)
 
-    def _plan(self, lo, hi, k: int, ef: int, mode: str, beam_width: int,
-              precision: str, trace, sp):
+    def _plan(self, lo, hi, k: int, ef: int, mode: str, trace, sp):
         """Planner call of the ``plan`` stage: partitions the batch and
         counts routed rows and pad waste."""
-        plan = self.planner.plan_batch(lo, hi, k=k, ef=ef, mode=mode,
-                                       beam_width=beam_width,
-                                       precision=precision)
+        plan = self.planner.plan_batch(lo, hi, k=k, ef=ef, mode=mode)
         if trace is not None:
-            lens = np.clip(hi - lo + 1, 0, None)
-            sc, bc = self.planner.predict_costs(lens, k=k, ef=ef,
-                                                beam_width=beam_width,
-                                                precision=precision)
             sp.attrs.update(strategy=plan.strategy.copy(),
                             scan_frac=plan.scan_frac,
-                            partitions=[p.signature for p in plan.partitions],
-                            predicted_scan_units=sc, predicted_beam_units=bc)
+                            partitions=[p.signature for p in plan.partitions])
         q = len(lo)
         pad_rows = sum(p.pad_q - len(p.indices) for p in plan.partitions)
         met = self.metrics
@@ -296,7 +272,7 @@ class SearchSubstrate:
     @staticmethod
     def _scatter(plan, outs, q: int, k: int):
         """Partition outputs back into request order (``assemble``), after
-        each partition's histogram and cost-model feedback."""
+        each partition's histograms and counters."""
         out_ids = np.full((q, k), -1, np.int32)
         out_d = np.full((q, k), INF, np.float32)
         hops = np.zeros(q, np.int32)
@@ -360,6 +336,7 @@ class SearchSubstrate:
             def book():
                 if met is not None:
                     met.histogram("graph_dispatch_ms").observe(dt * 1e3)
+                    _book_visited(met, st_h)
             return ids_h, d_h, st_h, book
         return block
 
@@ -368,22 +345,19 @@ class SearchSubstrate:
                           defer: bool, beam_width: int = 1,
                           precision: str = "f32", trace=None, live=None):
         """Dispatch each fixed-shape partition of a plan.  ``defer=False``
-        blocks each partition before dispatching the next (the calibrated
-        loop); ``defer=True`` enqueues them all.  Returns one block closure
-        per partition, already taken (memoized) when not deferred."""
+        blocks each partition before dispatching the next; ``defer=True``
+        enqueues them all.  Returns one block closure per partition, already
+        taken (memoized) when not deferred."""
         blocks = []
         for part in plan.partitions:
             if part.kind == "scan":
                 blk = self._dispatch_scan(qv, lo, hi, part.indices,
                                           part.param, part.pad_q, k, ef,
-                                          calibrate_wall=not defer,
                                           precision=precision, trace=trace,
                                           live=live)
             else:
                 blk = self._dispatch_beam(qv, lo, hi, part.indices,
                                           part.param, part.pad_q, k,
-                                          calibrate=(mode == "auto"),
-                                          calibrate_wall=not defer,
                                           use_kernel=use_kernel,
                                           beam_width=beam_width,
                                           precision=precision, live=live,
@@ -488,13 +462,13 @@ class SearchSubstrate:
             self.cache.invalidate_segment(self.cache_ns)
 
     def _dispatch_scan(self, qv, lo, hi, idx, bucket: int, pad_q: int,
-                       k: int, ef: int, *, calibrate_wall: bool,
-                       precision: str = "f32", trace=None, live=None):
+                       k: int, ef: int, *, precision: str = "f32",
+                       trace=None, live=None):
         """Enqueue one scan partition; returns its block closure.  The
         closure waits for the outputs (``scan_block``) and returns
         ``(ids, dists, units, book)``, where ``book`` feeds the
-        ``scan_dispatch_ms`` histogram (enqueue to host result) and the
-        cost model — run in ``assemble``."""
+        ``scan_dispatch_ms`` histogram (enqueue to host result) — run in
+        ``assemble``."""
         met = self.metrics
         with stage("scan_prep", met, trace):
             nq = len(idx)
@@ -506,9 +480,6 @@ class SearchSubstrate:
             qp[:nq, :self.d] = qv[idx]
             slot = self._quant_for(precision)
             _, live_row = self._live_ops(live)
-            sig = ("scan", bucket, pad_q, k, precision, live is not None)
-            warm = sig in self._warm
-            self._warm.add(sig)
             t0 = time.perf_counter()
             x = (self._scan_corpus() if slot is None
                  else slot["data_pad"])
@@ -558,17 +529,10 @@ class SearchSubstrate:
                     met.histogram("scan_dispatch_ms").observe(dt * 1e3)
                     if rq:
                         met.counter("rerank_rows_total").inc(pad_q * rq)
-                if calibrate_wall and warm:
-                    # the dispatch did pad_q windows of work, not nq:
-                    # normalize by pad_q so calibration measures the
-                    # kernel, not the padding ratio
-                    self.planner.cost.observe_wall("scan", units, dt, pad_q,
-                                                   precision=precision)
             return ids_h, d_h, units, book
         return block
 
     def _dispatch_beam(self, qv, lo, hi, idx, ef: int, pad_q: int, k: int, *,
-                       calibrate: bool, calibrate_wall: bool = True,
                        use_kernel: bool = False, beam_width: int = 1,
                        precision: str = "f32", live=None, trace=None):
         """Enqueue one beam partition; returns its block closure
@@ -578,7 +542,8 @@ class SearchSubstrate:
             empty = np.zeros(0, np.int32)
             return lambda: (np.zeros((0, k), np.int32),
                             np.zeros((0, k), np.float32),
-                            {"hops": empty, "ndist": empty}, _no_book)
+                            {"hops": empty, "ndist": empty,
+                             "evictions": empty}, _no_book)
         met = self.metrics
         with stage("beam_prep", met, trace):
             pad = np.concatenate([idx, np.repeat(idx[-1:], pad_q - nq)])
@@ -592,10 +557,6 @@ class SearchSubstrate:
             slot = self._quant_for(precision)
             quant = None if slot is None else (slot["data"], slot["scale"])
             live_b, _ = self._live_ops(live)
-            sig = ("beam", ef, pad_q, k, beam_width, precision,
-                   live is not None)
-            warm = sig in self._warm
-            self._warm.add(sig)
             t0 = time.perf_counter()
             lo_p = jnp.asarray(lo[pad].astype(np.int32))
             hi_p = jnp.asarray(hi[pad].astype(np.int32))
@@ -619,34 +580,32 @@ class SearchSubstrate:
             def book():
                 if met is not None:
                     met.histogram("beam_dispatch_ms").observe(dt * 1e3)
-                if calibrate:
-                    self.planner.cost.update_beam(
-                        float(st_h["ndist"].mean()), ef,
-                        beam_width=beam_width)
-                    if calibrate_wall and warm:
-                        # pad lanes duplicate the last real query, so pad_q
-                        # lanes of ~ndist work each were executed —
-                        # normalize by pad_q
-                        self.planner.cost.observe_wall(
-                            "beam", max(float(st_h["ndist"].mean()), 1.0),
-                            dt, pad_q, precision=precision)
+                    _book_visited(met, st_h)      # real lanes only
             return ids_h, d_h, st_h, book
         return block
 
     # ------------------------------------------------- legacy sync wrapper
     def _run_beam(self, qv, lo, hi, idx, ef: int, pad_q: int, k: int, *,
-                  calibrate: bool, use_kernel: bool = False):
+                  use_kernel: bool = False):
         """Synchronous beam partition dispatch (kept for the empty-partition
         regression test and any external caller of the pre-async API)."""
         ids, d, st, book = self._dispatch_beam(
             qv, lo, hi, np.asarray(idx, np.int64), ef, pad_q, k,
-            calibrate=calibrate, use_kernel=use_kernel)()
+            use_kernel=use_kernel)()
         book()
         return ids, d, st
 
 
 def _no_book():
     """Bookkeeping of a partition that dispatched nothing."""
+
+
+def _book_visited(met: MetricsRegistry, st: dict) -> None:
+    """The beam's visited-table counters: ids inserted (every scored
+    neighbor is inserted once) and ids the table forgot."""
+    met.counter("beam_visited_inserts_total").inc(int(st["ndist"].sum()))
+    met.counter("beam_visited_evictions_total").inc(
+        int(st["evictions"].sum()))
 
 
 # ======================================================================
@@ -670,12 +629,7 @@ def _shard_graph(vecs, nbrs, rmq, dist_c, order, rank0, xq, scale, live, qv,
     ``live`` is the sharded (1, per) shard-local liveness mask, same uniform
     -operand idiom: under ``use_live=False`` the caller passes an all-ones
     array and the trace never touches it; under ``use_live=True`` the beam
-    filters tombstoned candidates out of its final pool.
-
-    Besides the merged top-k, the body all-gathers each shard's **summed
-    ndist** (one scalar per shard) so the host can feed the cost model's
-    ``ndist_per_ef`` EMA — without it the mesh path would never move the
-    beam-cost estimate (traced bodies return no per-query stats)."""
+    filters tombstoned candidates out of its final pool."""
     vecs, nbrs = vecs[0], nbrs[0]
     rmq, dist_c, order = rmq[0], dist_c[0], order[0]
     n, d = vecs.shape
@@ -685,17 +639,15 @@ def _shard_graph(vecs, nbrs, rmq, dist_c, order, rank0, xq, scale, live, qv,
         quant = (xq[0], scale[:d] if precision == "int8" else None)
     slo, shi = resolve.clip_interval_jax(lo, hi, rank0[0], n)
     entry = resolve.select_entry(rmq, dist_c, slo, shi, n)
-    ids, dists, st = beam_search_batch(vecs, nbrs, qv, slo, shi, entry,
-                                       k=k, ef=ef, beam_width=beam_width,
-                                       quant=quant,
-                                       live=live[0] if use_live else None)
+    ids, dists, _ = beam_search_batch(vecs, nbrs, qv, slo, shi, entry,
+                                      k=k, ef=ef, beam_width=beam_width,
+                                      quant=quant,
+                                      live=live[0] if use_live else None)
     orig = resolve.remap_ids_jax(order, ids)
     dists = jnp.where(ids >= 0, dists, jnp.inf)
     ids_g = jax.lax.all_gather(orig, axis)               # (S, Q, k)
     ds_g = jax.lax.all_gather(dists, axis)
-    nd_g = jax.lax.all_gather(jnp.sum(st["ndist"]), axis)    # (S,)
-    out_i, out_d = merge_topk(ids_g, ds_g, k)
-    return out_i, out_d, nd_g
+    return merge_topk(ids_g, ds_g, k)
 
 
 def _shard_planned(x_scan, vecs, nbrs, rmq, dist_c, order, rank0, xq, scale,
@@ -760,7 +712,6 @@ def _shard_planned(x_scan, vecs, nbrs, rmq, dist_c, order, rank0, xq, scale,
     d_s = jnp.where(ids_s >= 0, d_s, jnp.inf)
     out_i = out_i.at[scan_dst].set(resolve.remap_ids_jax(order, ids_s))
     out_d = out_d.at[scan_dst].set(d_s)
-    nd = jnp.zeros((), jnp.int32)
     if has_beam:
         if precision == "f32":
             quant = None
@@ -768,25 +719,22 @@ def _shard_planned(x_scan, vecs, nbrs, rmq, dist_c, order, rank0, xq, scale,
             quant = (xq[0], scale[:d] if precision == "int8" else None)
         slo, shi = resolve.clip_interval_jax(beam_lo, beam_hi, rank0[0], n)
         entry = resolve.select_entry(rmq, dist_c, slo, shi, n)
-        ids_b, d_b, st = beam_search_batch(vecs, nbrs, beam_q, slo, shi,
-                                           entry, k=k, ef=ef,
-                                           beam_width=beam_width,
-                                           quant=quant, live=live_beam)
+        ids_b, d_b, _ = beam_search_batch(vecs, nbrs, beam_q, slo, shi,
+                                          entry, k=k, ef=ef,
+                                          beam_width=beam_width,
+                                          quant=quant, live=live_beam)
         d_b = jnp.where(ids_b >= 0, d_b, jnp.inf)
         out_i = out_i.at[beam_dst].set(resolve.remap_ids_jax(order, ids_b))
         out_d = out_d.at[beam_dst].set(d_b)
-        nd = jnp.sum(st["ndist"])       # pad lanes: empty windows, ndist 0
     ids_g = jax.lax.all_gather(out_i[:nq], axis)         # (S, Q, k)
     ds_g = jax.lax.all_gather(out_d[:nq], axis)
-    nd_g = jax.lax.all_gather(nd, axis)                  # (S,) beam-group sum
-    out_ii, out_dd = merge_topk(ids_g, ds_g, k)
-    return out_ii, out_dd, nd_g
+    return merge_topk(ids_g, ds_g, k)
 
 
 class MeshSubstrate:
     """Mesh-path twin of ``SearchSubstrate``: host planning, traced dispatch.
 
-    The cost router is host-side policy and cannot run inside a traced
+    The router is host-side policy and cannot run inside a traced
     ``shard_map`` body, so the strategy split happens **before** tracing:
 
     * plan     — ``QueryPlanner.choose_strategy_batch`` over each query's
@@ -802,23 +750,11 @@ class MeshSubstrate:
 
     Compiled signatures are bounded the same way as the local planner's:
     ``(k, ef, bucket, pad_pow2(|scan|), pad_pow2(|beam|), Q)``.
-
-    Calibration feedback: routed dispatches (``auto``/``scan``/``beam``)
-    whose jit signature is already warm feed their wall time back into the
-    planner's cost model — pure-beam calls observe the beam unit cost
-    (work per lane ≈ ``ndist_per_ef · ef``), and mixed scan+beam calls are
-    attributed proportionally to predicted unit costs
-    (``observe_wall_mixed``).  The traced bodies additionally **all-gather
-    a per-shard ndist scalar**, so warm routed dispatches also move the
-    ``ndist_per_ef`` EMA itself — the mesh path calibrates the same two
-    quantities the local path does.  ``req.strategy == "graph"`` — the
-    paper's pure path — never calibrates.
     """
 
     def __init__(self, mesh, axis: str, vecs, nbrs, rmq, dist_c, order,
                  rank0, *, planner: Optional[QueryPlanner] = None,
                  cache: Optional[SearchCache] = None,
-                 calibrate: bool = True,
                  metrics: Optional[MetricsRegistry] = None):
         self.mesh, self.axis = mesh, axis
         self._vecs = jnp.asarray(vecs, jnp.float32)      # (S, per, d)
@@ -831,12 +767,8 @@ class MeshSubstrate:
         self.n_shards, self.per, self.d = s, per, d
         self.tb = ROW_TILE
         self.d_pad = -(-d // 128) * 128
-        if planner is None:
-            deg = float((np.asarray(nbrs) >= 0).sum(-1).mean()) if per else 1.0
-            planner = QueryPlanner(max(per, 1), deg)
-        self.planner = planner
+        self.planner = planner or QueryPlanner(max(per, 1))
         self.cache = cache
-        self.calibrate = calibrate
         self.metrics = metrics      # optional MetricsRegistry (obs layer)
         self._x_pad = None          # padded scan corpus, built on first scan
         self._quant: Dict[str, dict] = {}   # precision -> quantized slots
@@ -914,15 +846,13 @@ class MeshSubstrate:
 
     # ------------------------------------------------------------- planning
     def plan_strategies(self, lo: np.ndarray, hi: np.ndarray, *, k: int,
-                        ef: int, mode: str, beam_width: int = 1,
-                        precision: str = "f32"
-                        ) -> Tuple[np.ndarray, np.ndarray]:
+                        mode: str) -> Tuple[np.ndarray, np.ndarray]:
         """Host half of mesh dispatch: (strategy (Q,) int8, lens_eff (Q,)).
 
         ``lens_eff`` is each query's **widest shard-local clip** of its
         global rank interval — the decision must be one replicated scalar
-        per query, and the widest shard is the one whose scan cost the
-        traced dispatch actually pays (shards execute in lockstep)."""
+        per query, and the widest shard is the one whose scan the traced
+        dispatch actually pays (shards execute in lockstep)."""
         lo = np.asarray(lo, np.int64)
         hi = np.asarray(hi, np.int64)
         lens_eff = np.zeros(len(lo), np.int64)
@@ -934,10 +864,7 @@ class MeshSubstrate:
             return np.full(len(lo), SCAN, np.int8), lens_eff
         if mode == "beam":
             return np.full(len(lo), BEAM, np.int8), lens_eff
-        return (self.planner.choose_strategy_batch(lens_eff, k=k, ef=ef,
-                                                   beam_width=beam_width,
-                                                   precision=precision),
-                lens_eff)
+        return self.planner.choose_strategy_batch(lens_eff, k=k), lens_eff
 
     # ---------------------------------------------------------------- run
     def run(self, req: SearchRequest) -> SearchResult:
@@ -976,11 +903,9 @@ class MeshSubstrate:
             if cache is not None:
                 # fences stores vs invalidate() / invalidate_segment("mesh")
                 epoch = cache.epoch_for("mesh")
-                cal_epoch = (self.planner.calibration_epoch
-                             if mode == "auto" else None)
                 keys, hit_rows, miss, dups = cache.split(
                     qv, lo, hi, k, ef, mode, ns="mesh", beam_width=bw,
-                    precision=prec, cal_epoch=cal_epoch)
+                    precision=prec)
                 cache_info.update(cache_hits=len(hit_rows),
                                   cache_misses=len(miss),
                                   batch_dedup=len(dups))
@@ -989,7 +914,7 @@ class MeshSubstrate:
                     met.counter("cache_miss_rows_total").inc(len(miss))
                     if dups:
                         met.counter("cache_dedup_rows_total").inc(len(dups))
-                split = (epoch, cal_epoch, keys, hit_rows, miss, dups)
+                split = (epoch, keys, hit_rows, miss, dups)
                 qv, lo, hi = qv[miss], lo[miss], hi[miss]
             sp.attrs.update(cache_info, strategy_mode=mode, ns="mesh",
                             dispatched=len(qv), beam_width=bw,
@@ -998,17 +923,17 @@ class MeshSubstrate:
                 if tr is not None:
                     sp.attrs["shard_clip_widths"] = \
                         self._shard_clip_widths(lo, hi)
-                route = self._route(lo, hi, k, ef, mode, bw, prec, tr, sp)
+                route = self._route(lo, hi, k, mode, tr, sp)
         if route is not None:
             ids, dists, stats = self._execute(qv, lo, hi, k, ef, mode, bw,
                                               prec, route, tr, req.live)
         with stage("assemble", None, tr):
             res = None if route is None else SearchResult(ids, dists, stats)
             if split is not None:
-                epoch, cal_epoch, keys, hit_rows, miss, dups = split
+                epoch, keys, hit_rows, miss, dups = split
                 if res is not None:
                     cache.store_batch([keys[i] for i in miss], res,
-                                      epoch=epoch, cal_epoch=cal_epoch)
+                                      epoch=epoch)
                 if hit_rows or dups or res is None:
                     res = cache.assemble(nq, k, hit_rows, res, miss, dups)
                 else:
@@ -1025,8 +950,7 @@ class MeshSubstrate:
             w.append(np.clip(shi.astype(np.int64) - slo + 1, 0, None))
         return np.stack(w)
 
-    def _route(self, lo, hi, k: int, ef: int, mode: str, beam_width: int,
-               precision: str, trace, sp):
+    def _route(self, lo, hi, k: int, mode: str, trace, sp):
         """Routing of the ``plan`` stage: ``None`` for the graph strategy,
         else the per-query strategy vector and widest shard-local clips."""
         if mode == "graph":
@@ -1034,19 +958,11 @@ class MeshSubstrate:
             if self.metrics is not None:
                 self.metrics.counter("graph_queries_total").inc(len(lo))
             return "graph"
-        strategy, lens_eff = self.plan_strategies(lo, hi, k=k, ef=ef,
-                                                  mode=mode,
-                                                  beam_width=beam_width,
-                                                  precision=precision)
+        strategy, lens_eff = self.plan_strategies(lo, hi, k=k, mode=mode)
         if trace is not None:
-            sc, bc = self.planner.predict_costs(lens_eff, k=k, ef=ef,
-                                                beam_width=beam_width,
-                                                precision=precision)
             sp.attrs.update(strategy=strategy.copy(),
                             lens_eff=lens_eff.copy(),
-                            scan_frac=float((strategy == SCAN).mean()),
-                            predicted_scan_units=sc,
-                            predicted_beam_units=bc)
+                            scan_frac=float((strategy == SCAN).mean()))
         if self.metrics is not None:
             n_scan = int((strategy == SCAN).sum())
             self.metrics.counter("scan_routed_total").inc(n_scan)
@@ -1060,7 +976,6 @@ class MeshSubstrate:
         met = self.metrics
         if route == "graph":
             ids, dists = self._call_graph(qv, lo, hi, k, ef,
-                                          calibrate=False,
                                           beam_width=beam_width,
                                           precision=precision, live=live,
                                           trace=trace)
@@ -1074,7 +989,6 @@ class MeshSubstrate:
             # graph body plus pow2 padding and a scatter — dispatch the graph
             # fn directly (same ef, same merge, bit-identical results)
             ids, dists = self._call_graph(qv, lo, hi, k, ef,
-                                          calibrate=self.calibrate,
                                           beam_width=beam_width,
                                           precision=precision, live=live,
                                           trace=trace)
@@ -1112,7 +1026,7 @@ class MeshSubstrate:
         with stage("mesh_planned_dispatch", None, trace) as sp:
             sp.attrs.update(warm=warm, bucket=bucket, pad_scan=pad_s,
                             pad_beam=pad_b, pad_rows=pad_rows)
-            ids, dists, nd_g = fn(x_scan, self._vecs,
+            ids, dists = fn(x_scan, self._vecs,
                                   self._nbrs, self._rmq, self._dist_c,
                                   self._order, self._rank0, xq, scale,
                                   self._live_shards(live),
@@ -1122,35 +1036,13 @@ class MeshSubstrate:
         if met is not None:
             met.histogram("mesh_dispatch_ms").observe(
                 (time.perf_counter() - t0) * 1e3)
-        if self.calibrate and warm:
-            # one fused traced step: attribute the wall time across the two
-            # groups proportionally to their predicted unit costs.  Scan
-            # lanes count the pow2 padding (empty windows still scan their
-            # fixed-shape blocks — real work); beam lanes count only the
-            # real queries (pad lanes carry empty windows and exit the
-            # while_loop immediately)
-            dt = time.perf_counter() - t0
-            n_beam = len(beam_idx)
-            self.planner.cost.observe_wall_mixed(
-                window_rows(bucket, self.tb) * pad_s,
-                self.planner.cost.ndist_per_ef_at(beam_width) * ef * n_beam,
-                dt, pad_s, n_beam, precision=precision)
-            if len(beam_idx):
-                # all-gathered per-shard ndist sums: pad lanes carry empty
-                # windows (ndist 0), so normalize by the real beam count —
-                # this is the signal that moves the mesh path's ndist EMA
-                nd_mean = float(np.asarray(nd_g).mean()) / len(beam_idx)
-                self.planner.cost.update_beam(nd_mean, ef,
-                                              beam_width=beam_width)
         return ids, dists, {"strategy": strategy,
                             "scan_frac": len(scan_idx) / nq}
 
-    def _call_graph(self, qv, lo, hi, k: int, ef: int, *, calibrate: bool,
+    def _call_graph(self, qv, lo, hi, k: int, ef: int, *,
                     beam_width: int = 1, precision: str = "f32", live=None,
                     trace=None):
-        """One graph-body mesh dispatch (+ optional warm-call beam
-        calibration for routed uniform-beam batches: wall time and the
-        all-gathered per-shard ndist feed the cost model)."""
+        """One graph-body mesh dispatch."""
         use_live = live is not None
         warm = ("graph", k, max(ef, k), beam_width, precision,
                 use_live) in self._fns
@@ -1161,7 +1053,7 @@ class MeshSubstrate:
         t0 = time.perf_counter()
         with stage("mesh_graph_dispatch", None, trace) as sp:
             sp.attrs["warm"] = warm
-            ids, dists, nd_g = fn(self._vecs, self._nbrs, self._rmq,
+            ids, dists = fn(self._vecs, self._nbrs, self._rmq,
                                   self._dist_c, self._order, self._rank0,
                                   xq, scale, self._live_shards(live),
                                   jnp.asarray(qv),
@@ -1172,23 +1064,6 @@ class MeshSubstrate:
         if self.metrics is not None:
             self.metrics.histogram("mesh_dispatch_ms").observe(
                 (time.perf_counter() - t0) * 1e3)
-        if calibrate and warm:
-            # both feeds normalize by the NON-EMPTY row count: forced-beam
-            # batches may carry empty intervals (the local path routes
-            # those to scan), which exit the while_loop immediately and
-            # would bias both the wall-per-unit estimate and the ndist EMA
-            # toward free
-            n_real = int((np.asarray(lo) <= np.asarray(hi)).sum())
-            if n_real:
-                dt = time.perf_counter() - t0
-                self.planner.cost.observe_wall(
-                    "beam",
-                    max(self.planner.cost.ndist_per_ef_at(beam_width) * ef,
-                        1.0),
-                    dt, n_real, precision=precision)
-                nd_mean = float(np.asarray(nd_g).mean()) / n_real
-                self.planner.cost.update_beam(nd_mean, ef,
-                                              beam_width=beam_width)
         return ids, dists
 
     # ------------------------------------------------------------ operands
@@ -1230,7 +1105,7 @@ class MeshSubstrate:
         ``scale`` + sharded ``live`` + replicated ``(qv, lo, hi)`` — under
         f32 pass ``vecs`` again as ``xq`` and any (d_pad,) f32 row as
         ``scale``; under ``use_live=False`` pass any (S, per) array as
-        ``live`` (all ignored).  Returns (ids, dists, ndist_per_shard)."""
+        ``live`` (all ignored).  Returns (ids, dists)."""
         key = ("graph", k, max(ef, k), beam_width, precision, use_live)
         fn = self._fns.get(key)
         if fn is None:
@@ -1241,7 +1116,7 @@ class MeshSubstrate:
             fn = jax.jit(jax.shard_map(
                 body, mesh=self.mesh,
                 in_specs=(shard,) * 7 + (rep,) + (shard,) + (rep,) * 3,
-                out_specs=(rep, rep, rep), check_vma=False))
+                out_specs=(rep, rep), check_vma=False))
             self._fns[key] = fn
         return fn
 
@@ -1260,6 +1135,6 @@ class MeshSubstrate:
             fn = jax.jit(jax.shard_map(
                 body, mesh=self.mesh,
                 in_specs=(shard,) * 8 + (rep,) + (shard,) + (rep,) * 8,
-                out_specs=(rep, rep, rep), check_vma=False))
+                out_specs=(rep, rep), check_vma=False))
             self._fns[key] = fn
         return fn

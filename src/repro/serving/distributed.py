@@ -17,15 +17,13 @@ routes through the unified search substrate, and ``plan="auto"`` works on
 **both** paths:
 
   * local path (``mesh=None``): one ``SearchSubstrate`` per shard, so each
-    shard runs the full strategy router (fused range-scan | beam per query,
-    with online cost calibration), followed by a host top-k merge.  By
+    shard runs the full strategy router (fused range-scan | beam per
+    query), followed by a host top-k merge.  By
     default the per-shard dispatches are **asynchronous**: every shard's
     device work is enqueued (``SearchSubstrate.dispatch``, jax async
     dispatch) before any shard's result is blocked on, so shard N+1's
     planning and upload overlap shard N's kernels; ``async_dispatch=False``
-    restores the sequential dispatch+block loop (whose per-shard wall
-    times feed wall-clock calibration — the async loop skips it, since a
-    shard's block time includes its siblings' queued work);
+    restores the sequential dispatch+block loop;
   * mesh path: one shard per device along the ``data`` axis via
     ``MeshSubstrate`` — the strategy vector is planned host-side from the
     shard-clipped global intervals and the traced per-device body executes a
@@ -184,7 +182,7 @@ class DistributedRFANN:
         the mesh path uses — identical ids by construction.  With
         ``async_dispatch`` every shard's work is enqueued before any block
         (the merge is the single synchronization point); otherwise shards
-        run the sequential dispatch+block loop with wall calibration.
+        run the sequential dispatch+block loop.
 
         Returns ``(ids, dists, stats)`` — stats aggregate the per-shard
         substrate stats: ``cache_hits`` is total shard hits normalized by

@@ -23,22 +23,17 @@ with no device work.  ``swap_index`` hot-swaps the served index and
 invalidates the cache in the same lock — cached rows reference the old
 corpus and must never survive a swap.
 
-If ``calibration_path`` is given, the planner's online-calibrated cost model
-is restored from it at startup and persisted (atomically: temp file +
-rename) at ``close()`` — a restarted server starts from steady-state
-routing instead of the prior, and a crash mid-shutdown can never leave a
-truncated file behind.  ``index_path`` does the same for the index itself:
-``close()`` writes the served index (graph + quantized corpora + streaming
-segment state) to the sharded directory format (``repro.index.io``), which
-``launch/serve --index-path`` restores at the next startup instead of
-rebuilding.
+``index_path`` persists the served index: ``close()`` writes it (graph +
+quantized corpora + streaming segment state) to the sharded directory
+format (``repro.index.io``), which ``launch/serve --index-path`` restores
+at the next startup instead of rebuilding.
 
 Observability: the engine owns a ``MetricsRegistry`` (``repro.obs``) —
 pass one in to share it, or read the default via :meth:`metrics`.  It is
 installed on the index (and re-installed on ``swap_index``) so substrate
 counters/histograms land in the same snapshot, and the engine itself
 records end-to-end latency/batch-size histograms, queue-depth gauges, and
-pull-side producers for the cache, the cost model, and its own summary.
+pull-side producers for the cache and its own summary.
 The dispatcher thread is tiled by leaf stages (``repro.obs.stage``): it
 waits in ``await_batch``, the substrate runs ``plan`` / ``*_prep`` /
 ``*_dispatch`` / ``*_block`` / ``assemble``, and ``complete`` does the
@@ -55,7 +50,6 @@ from the dispatch thread at that cadence.
 """
 from __future__ import annotations
 
-import os
 import queue
 import threading
 import time
@@ -92,7 +86,6 @@ class RFANNEngine:
                  max_batch: int = 64, max_wait_ms: float = 2.0,
                  plan: str = "auto", beam_width: int = 1,
                  precision: str = "f32",
-                 calibration_path: Optional[str] = None,
                  cache_bytes: int = 0,
                  pipeline_depth: int = 2,
                  metrics: Optional[MetricsRegistry] = None,
@@ -129,21 +122,12 @@ class RFANNEngine:
                                           shards=self.index_save_shards)
         self.max_batch = max_batch
         self.max_wait = max_wait_ms / 1000.0
-        self.calibration_path = calibration_path
         self.registry = metrics if metrics is not None else MetricsRegistry()
         self.log_interval = float(log_interval_s)
         self.trace_sample_every = int(trace_sample_every)
         self.last_trace: Optional[QueryTrace] = None
         self._batch_seq = 0
         self._last_log = time.perf_counter()
-        if calibration_path and os.path.exists(calibration_path):
-            planner = getattr(index, "planner", None)
-            if planner is not None:
-                try:
-                    planner.load_calibration(calibration_path)
-                except ValueError as e:     # stale schema / wrong corpus:
-                    import warnings         # serve from the prior instead
-                    warnings.warn(f"ignoring calibration: {e}")
         self.cache = None
         if cache_bytes:
             from repro.search import SearchCache
@@ -185,7 +169,6 @@ class RFANNEngine:
             index.install_metrics(reg)
         if self.cache is not None:
             reg.register_producer("cache", self.cache.snapshot)
-        reg.register_producer("cost_model", self._cost_snapshot)
         reg.register_producer("engine", self.stats.summary)
         self._resolver = threading.Thread(target=self._resolve_loop,
                                           daemon=True)
@@ -195,12 +178,6 @@ class RFANNEngine:
         self._dispatcher.start()
 
     # ------------------------------------------------------------------
-    def _cost_snapshot(self) -> dict:
-        """Pull-side cost-model producer — reads the *live* index so a
-        ``swap_index`` transparently switches whose calibration is exported."""
-        planner = getattr(self.index, "planner", None)
-        return planner.cost.snapshot() if planner is not None else {}
-
     def summary(self) -> dict:
         """Served counts (``EngineStats``) plus end-to-end latency
         percentiles from the ``engine_e2e_ms`` histogram (bucket
@@ -212,8 +189,7 @@ class RFANNEngine:
 
     def metrics(self) -> dict:
         """One JSON-able snapshot: every counter/gauge/histogram (with
-        p50/p90/p99) plus the pull-side sections (``engine``, ``cache``,
-        ``cost_model``).  Prometheus text comes from
+        p50/p90/p99) plus the pull-side sections (``engine``, ``cache``).  Prometheus text comes from
         ``repro.obs.to_prometheus(engine.registry)``."""
         return self.registry.snapshot()
 
@@ -440,10 +416,6 @@ class RFANNEngine:
             except queue.Empty:
                 break
             self._fail_batch([(q_, rg_, t0_, fut)])
-        if self.calibration_path:
-            planner = getattr(self.index, "planner", None)
-            if planner is not None:
-                planner.save_calibration(self.calibration_path)
         if self.index_path:
             # persist the served index (sharded directory format) so the
             # next startup restores in seconds instead of rebuilding —

@@ -201,7 +201,7 @@ class StreamingRFANN:
 
     # ------------------------------------------------------------ builders
     def _build_view(self, vectors, attrs, ext_ids, delta: DeltaView, *,
-                    version: int, old_sub: Optional[SearchSubstrate] = None,
+                    version: int,
                     base_live: Optional[np.ndarray] = None) -> SegmentView:
         """Build an RNSG base over (vectors, attrs) and wrap it in a view.
         ``build_rnsg`` stable-sorts by attribute, so the result — and every
@@ -213,9 +213,6 @@ class StreamingRFANN:
                               order=base_ids, attrs=g.attrs,
                               cache=self._cache, cache_ns=BASE_NS,
                               metrics=self._metrics)
-        if old_sub is not None:     # carry the calibrated cost model across
-            sub.planner.cost = old_sub.planner.cost
-            sub.planner.calibration_epoch = old_sub.planner.calibration_epoch
         for prec in self._precisions:
             sub.install_quantized(prec)
         if base_live is None:
@@ -647,7 +644,7 @@ class StreamingRFANN:
             # published view and are reconciled at the swap below
             new = self._build_view(cat_vecs, cat_attrs, cat_ids,
                                    DeltaView.empty(self.d),
-                                   version=0, old_sub=v0.sub)
+                                   version=0)
             build_ms = (time.perf_counter() - t0) * 1e3
             t1 = time.perf_counter()
             with self._lock:

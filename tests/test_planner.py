@@ -109,7 +109,7 @@ def test_bucketing_no_recompile_within_signature():
 
 # ----------------------------------------------------------------- routing/plan
 def test_planner_routes_by_selectivity():
-    pl = QueryPlanner(n=100_000, mean_degree=24.0)
+    pl = QueryPlanner(n=100_000)
     lo = np.asarray([10, 0, 50, 2000])
     hi = np.asarray([40, 99_999, 49, 2100])        # narrow, full, empty, small
     plan = pl.plan_batch(lo, hi, k=10, ef=64)
@@ -121,7 +121,7 @@ def test_planner_routes_by_selectivity():
 
 
 def test_planner_forced_modes():
-    pl = QueryPlanner(n=10_000, mean_degree=16.0)
+    pl = QueryPlanner(n=10_000)
     lo = np.asarray([0, 100])
     hi = np.asarray([9_999, 200])
     assert (pl.plan_batch(lo, hi, k=10, ef=64, mode="scan").strategy == 0).all()
@@ -131,30 +131,45 @@ def test_planner_forced_modes():
 def test_choose_strategy_batch_matches_scalar():
     """The vectorized routing decision (the host half of mesh dispatch) must
     agree element-wise with the scalar reference across the whole regime
-    spectrum — empty, tiny, boundary, ceiling, full — before and after
-    calibration shifts the cost model."""
-    pl = QueryPlanner(n=100_000, mean_degree=24.0)
+    spectrum — empty, tiny, boundary, ceiling, full — for every k, including
+    one beyond the scan's lane row."""
+    pl = QueryPlanner(n=100_000)
     rng = np.random.default_rng(5)
     lens = np.concatenate([
         np.asarray([0, 1, 5, 10, 11, 64, 65, 12_500, 12_501, 100_000]),
         rng.integers(0, 100_000, 200),
         2 ** rng.integers(0, 17, 50),              # pow2 boundaries
     ])
-    for k, ef in ((10, 64), (1, 16), (50, 256)):
-        batch = pl.choose_strategy_batch(lens, k=k, ef=ef)
-        scalar = np.asarray([pl.choose_strategy(int(ln), k=k, ef=ef)
+    for k in (10, 1, 50, 129):
+        batch = pl.choose_strategy_batch(lens, k=k)
+        scalar = np.asarray([pl.choose_strategy(int(ln), k=k)
                              for ln in lens], np.int8)
-        assert np.array_equal(batch, scalar), (k, ef)
-    # calibration moves the crossover; the two implementations move together
-    pl.cost.update_beam(ndist_mean=2000.0, ef=64)
-    batch = pl.choose_strategy_batch(lens, k=10, ef=64)
-    scalar = np.asarray([pl.choose_strategy(int(ln), k=10, ef=64)
-                         for ln in lens], np.int8)
-    assert np.array_equal(batch, scalar)
+        assert np.array_equal(batch, scalar), k
     # and plan_batch routes with the same decisions (lo/hi -> lens)
+    batch = pl.choose_strategy_batch(lens, k=10)
     lo = np.zeros(len(lens), np.int64)
     plan = pl.plan_batch(lo, lo + lens - 1, k=10, ef=64)
     assert np.array_equal(plan.strategy, batch)
+
+
+@pytest.mark.parametrize("n, max_scan_frac, k, lens, want", [
+    # the served deployment: 2^20 rows, ceiling 2^-3 — 2^-3 scans, wider beams
+    (1 << 20, 0.125, 10, [0, 131_072, 131_073, 262_144, 1 << 20],
+     [0, 0, 1, 1, 1]),
+    # a raised ceiling moves the one line and nothing else
+    (1 << 20, 0.5, 10, [0, 131_072, 131_073, 262_144, 1 << 20],
+     [0, 0, 0, 0, 1]),
+    # a small corpus: the ceiling is the min bucket (64), yet a slice of at
+    # most k rows still scans
+    (256, 0.125, 100, [0, 64, 100, 101, 256], [0, 0, 0, 1, 1]),
+])
+def test_routing_is_one_threshold(n, max_scan_frac, k, lens, want):
+    """Routing is the scan ceiling alone: slices up to ``max_scan_len`` (or
+    up to ``k`` rows) scan, longer ones beam, whatever either strategy
+    costs."""
+    pl = QueryPlanner(n=n, max_scan_frac=max_scan_frac)
+    assert pl.choose_strategy_batch(np.asarray(lens), k=k).tolist() == want
+    assert [pl.choose_strategy(ln, k=k) for ln in lens] == want
 
 
 # ------------------------------------------------------------------ end to end
@@ -215,16 +230,6 @@ def test_auto_plan_recall_not_worse_than_graph():
     assert ra >= rg - 1e-9
 
 
-def test_cost_model_calibration_moves_estimates():
-    vecs, attrs, idx = _small_index(n=1024)
-    qv = make_vectors(16, 16, seed=2)
-    ranges = selectivity_ranges(attrs, 16, 0.8, seed=2)   # all-beam batch
-    idx.search(qv, ranges, k=5, ef=64, plan="auto")
-    cm = idx.executor.planner.cost
-    assert cm.beam_obs >= 1
-    assert cm.ndist_per_ef > 0
-
-
 # ------------------------------------------------------------------ engine
 def test_engine_serves_with_planner():
     vecs, attrs, idx = _small_index(n=512)
@@ -257,15 +262,15 @@ def test_planner_routes_k_beyond_scan_to_beam():
     beam, and the served auto path answers it end to end."""
     from repro.planner import BEAM, SCAN
     from repro.planner.planner import SCAN_MAX_K
-    p = QueryPlanner(4096, 16.0)
+    p = QueryPlanner(4096)
     lo = np.asarray([0, 10, 7])
     hi = np.asarray([3, 400, 5])                   # tiny, narrow, empty
     for mode in ("auto", "beam"):
         plan = p.plan_batch(lo, hi, k=SCAN_MAX_K + 1, ef=256, mode=mode)
         assert (plan.strategy == BEAM).all(), mode
         assert all(part.kind == "beam" for part in plan.partitions)
-    assert p.choose_strategy(3, k=SCAN_MAX_K + 1, ef=256) == BEAM
-    assert p.choose_strategy(3, k=SCAN_MAX_K, ef=256) == SCAN
+    assert p.choose_strategy(3, k=SCAN_MAX_K + 1) == BEAM
+    assert p.choose_strategy(3, k=SCAN_MAX_K) == SCAN
     vecs, attrs, idx = _small_index(n=512)
     qv = make_vectors(4, 16, seed=11)
     ranges = selectivity_ranges(attrs, 4, 0.5, seed=12)

@@ -1,7 +1,6 @@
 """Unified search substrate: single-source resolve, strategy parity across
 every execution path (including the shard_map mesh-auto path), empty-partition
-guards, beam early-out, calibration persistence."""
-import json
+guards, beam early-out, the visited-table counters."""
 import os
 import re
 import subprocess
@@ -161,8 +160,7 @@ def test_mesh_auto_parity_single_device():
     ranges = _degenerate_ranges(attrs, nq, seed=11)
 
     lo, hi = rank_interval(dist.attrs_sorted, ranges)
-    strat, _ = dist.mesh_substrate.plan_strategies(lo, hi, k=k, ef=64,
-                                                   mode="auto")
+    strat, _ = dist.mesh_substrate.plan_strategies(lo, hi, k=k, mode="auto")
     assert (strat == SCAN).any() and (strat == BEAM).any()   # mixed batch
 
     base, _ = dist.search(qv, ranges, k=k, ef=n, plan="graph")
@@ -216,7 +214,7 @@ def test_mesh_auto_parity_multidevice():
         dist = DistributedRFANN(vecs, attrs, n_shards=8, mesh=mesh, m=16,
                                 ef_spatial=16, ef_attribute=24)
         lo, hi = rank_interval(dist.attrs_sorted, rg)
-        strat, _ = dist.mesh_substrate.plan_strategies(lo, hi, k=8, ef=64,
+        strat, _ = dist.mesh_substrate.plan_strategies(lo, hi, k=8,
                                                        mode='auto')
         assert (strat == SCAN).any() and (strat == BEAM).any(), strat
         base, _ = dist.search(qv, rg, k=8, ef=1024, plan='graph')
@@ -234,45 +232,9 @@ def test_mesh_auto_parity_multidevice():
     assert "OK" in r.stdout
 
 
-def test_mesh_ndist_feedback_moves_cost_model():
-    """ROADMAP item: the traced mesh bodies all-gather a per-shard ndist
-    scalar, so warm routed dispatches move the planner's ``ndist_per_ef``
-    EMA — previously the mesh path never calibrated it.  ``plan='graph'``
-    (the paper's pure path) must still never calibrate."""
-    import jax
-
-    n, d, nq, k = 256, 16, 12, 8
-    vecs, attrs = _corpus(n, d)
-    mesh = jax.make_mesh((1,), ("data",))
-    dist = DistributedRFANN(vecs, attrs, n_shards=1, mesh=mesh, m=16,
-                            ef_spatial=16, ef_attribute=24)
-    planner = dist.mesh_substrate.planner
-    qv = make_vectors(nq, d, seed=7)
-    wide = selectivity_ranges(attrs, nq, 0.6, seed=5)       # routes to beam
-    assert planner.cost.beam_obs == 0
-    dist.search(qv, wide, k=k, ef=64, plan="beam")          # cold: warms only
-    assert planner.cost.beam_obs == 0
-    prior = planner.cost.ndist_per_ef
-    dist.search(qv, wide, k=k, ef=64, plan="beam")          # warm: calibrates
-    assert planner.cost.beam_obs == 1
-    assert planner.cost.ndist_per_ef != prior               # EMA moved
-    obs_g = planner.cost.beam_obs
-    dist.search(qv, wide, k=k, ef=64, plan="graph")         # warm fn, but the
-    dist.search(qv, wide, k=k, ef=64, plan="graph")         # pure path never
-    assert planner.cost.beam_obs == obs_g                   # calibrates
-    # the mixed scan+beam planned body feeds the EMA too
-    mixed = np.concatenate([selectivity_ranges(attrs, nq // 2, 0.01, seed=6),
-                            selectivity_ranges(attrs, nq - nq // 2, 0.6,
-                                               seed=7)])
-    dist.search(qv, mixed, k=k, ef=64, plan="auto")         # warms
-    obs = planner.cost.beam_obs
-    dist.search(qv, mixed, k=k, ef=64, plan="auto")
-    assert planner.cost.beam_obs > obs
-
-
 # ------------------------------------------------------ empty-partition guard
 def test_plan_never_emits_empty_partitions():
-    pl = QueryPlanner(n=10_000, mean_degree=16.0)
+    pl = QueryPlanner(n=10_000)
     rng = np.random.default_rng(0)
     for mode in ("auto", "scan", "beam"):
         for q in (0, 1, 7, 33):
@@ -293,8 +255,7 @@ def test_empty_partition_and_empty_batch_do_not_crash():
     sub = idx.substrate
     ids, d, st = sub._run_beam(np.zeros((0, 8), np.float32),
                                np.zeros(0, np.int64), np.zeros(0, np.int64),
-                               np.zeros(0, np.int64), 16, 8, 5,
-                               calibrate=False)
+                               np.zeros(0, np.int64), 16, 8, 5)
     assert ids.shape == (0, 5) and st["hops"].shape == (0,)
     for plan in ("graph", "auto", "scan", "beam"):
         res = sub.run(SearchRequest(queries=np.zeros((0, 8), np.float32),
@@ -330,53 +291,29 @@ def test_beam_early_out_same_results_fewer_hops():
     assert (np.asarray(st_new["hops"]) < 64).all()           # early exit
 
 
-# ------------------------------------------------- calibration persistence
-def test_calibration_save_load_roundtrip(tmp_path):
-    vecs, attrs = _corpus(512, 16, seed=1)
-    idx = RNSGIndex.build(vecs, attrs, m=16, ef_spatial=16, ef_attribute=24)
-    qv = make_vectors(16, 16, seed=2)
-    rg = np.concatenate([selectivity_ranges(attrs, 8, 0.01, seed=1),
-                         selectivity_ranges(attrs, 8, 0.8, seed=2)])
-    for _ in range(3):                       # calibrate (incl. warm calls)
-        idx.search(qv, rg, k=5, ef=64, plan="auto")
-    p = str(tmp_path / "calib.json")
-    idx.planner.save_calibration(p)
-    state = json.load(open(p))
-    assert state["version"] == 1 and state["cost"]["beam_obs"] >= 1
-    # atomic write: the rename left no temp file, and re-saving over an
-    # existing path replaces it wholesale (never truncates in place)
-    assert [f.name for f in tmp_path.iterdir()] == ["calib.json"]
-    idx.planner.save_calibration(p)
-    assert json.load(open(p)) == state
-
-    fresh = QueryPlanner(n=idx.g.n, mean_degree=16.0)
-    assert fresh.cost.state_dict() != idx.planner.cost.state_dict()
-    fresh.load_calibration(p)
-    assert fresh.cost.state_dict() == idx.planner.cost.state_dict()
-
-    # calibration is per-index: a corpus-size mismatch must not load
-    wrong = QueryPlanner(n=idx.g.n + 1, mean_degree=16.0)
-    with pytest.raises(ValueError, match="corpus"):
-        wrong.load_calibration(p)
-
-
-def test_engine_wires_calibration(tmp_path):
-    from repro.serving.engine import RFANNEngine
+# ------------------------------------------------- visited-table counters
+def test_visited_counters_count_real_lanes_only(monkeypatch):
+    """``beam_visited_inserts_total`` / ``beam_visited_evictions_total``
+    sum the real lanes of a beam partition: the pad lanes that fill it to a
+    power of two repeat the last query's work and are not booked.  A
+    16-slot table makes evictions certain."""
+    from functools import partial
+    from repro.obs.metrics import MetricsRegistry
+    from repro.search import substrate as sm
     vecs, attrs = _corpus(512, 16, seed=4)
     idx = RNSGIndex.build(vecs, attrs, m=16, ef_spatial=16, ef_attribute=24)
-    p = str(tmp_path / "engine_calib.json")
-    eng = RFANNEngine(idx, k=5, ef=32, max_batch=8, max_wait_ms=5,
-                      plan="auto", calibration_path=p)
-    qv = make_vectors(16, 16, seed=5)
-    rg = selectivity_ranges(attrs, 16, 0.5, seed=6)
-    futs = [eng.submit(qv[i], rg[i]) for i in range(16)]
-    for f in futs:
-        assert f.result(timeout=120).ids.shape == (5,)
-    eng.close()                                  # persists on shutdown
-    saved = json.load(open(p))["cost"]
-
-    idx2 = RNSGIndex(idx.g)                      # fresh substrate + planner
-    eng2 = RFANNEngine(idx2, k=5, ef=32, plan="auto", calibration_path=p)
-    eng2.close()
-    # startup restored the persisted state exactly (JSON floats round-trip)
-    assert idx2.planner.cost.state_dict() == saved
+    reg = MetricsRegistry()
+    sub = sm.SearchSubstrate.from_graph(idx.g, metrics=reg)
+    monkeypatch.setattr(sm, "beam_search_batch",
+                        partial(beam_search_batch, _visited_slots=16))
+    qv = make_vectors(3, 16, seed=5)
+    lo, hi = np.zeros(3, np.int64), np.full(3, 511, np.int64)
+    ids, d, st, book = sub._dispatch_beam(qv, lo, hi, np.arange(3), 32, 8,
+                                          5)()
+    book()
+    assert ids.shape == (3, 5) and st["evictions"].shape == (3,)
+    assert st["ndist"][-1] > 0 and st["evictions"][-1] > 0   # pads would show
+    inserts = reg.counter("beam_visited_inserts_total").value
+    evictions = reg.counter("beam_visited_evictions_total").value
+    assert inserts == int(st["ndist"].sum())
+    assert evictions == int(st["evictions"].sum())

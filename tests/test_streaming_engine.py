@@ -319,7 +319,7 @@ def test_engine_wal_churn_survives_sigkill(tmp_path):
 
 def test_serve_sigterm_drains_and_restarts(tmp_path):
     """SIGTERM on the serve launcher: graceful drain (PreemptionHandler),
-    WAL sealed, index + calibration checkpointed, exit 0 — then a restart
+    WAL sealed, index checkpointed, exit 0 — then a restart
     restores from the checkpoint and replays the WAL with zero
     acknowledged mutations lost."""
     import subprocess

@@ -163,3 +163,25 @@ def test_kernel_op_name_is_explicit(one_chip, name):
     ops = re.findall(r"%([\w.\-]+) = [^\n]*custom-call\([^\n]*tpu_custom_call",
                      text)
     assert ops and all(op.rsplit(".", 1)[0] == name for op in ops), ops
+
+
+@pytest.mark.parametrize("bw", [1, 4])
+def test_beam_program_has_no_corpus_sized_visited_state(one_chip, bw):
+    """The served beam (2^20 rows, d=96, degree 32, a 32-lane partition,
+    ef=64, k=10) compiles for v5e with no buffer of extent n + 1 or
+    32 · (n + 1): its visited set is a table sized by (ef, m), not a
+    per-lane bitmap over the corpus."""
+    import re
+    from repro.core.beam import beam_search_batch
+    s = one_chip
+    n, d, m, q = 1 << 20, 96, 32, 32
+
+    def fn(vecs, nbrs, qv, lo, hi, entry):
+        return beam_search_batch(vecs, nbrs, qv, lo, hi, entry, k=10, ef=64,
+                                 beam_width=bw)
+    text = _compiled_text(
+        fn, _sds(s, (n, d), jnp.float32), _sds(s, (n, m), jnp.int32),
+        _sds(s, (q, d), jnp.float32), _sds(s, (q,), jnp.int32),
+        _sds(s, (q,), jnp.int32), _sds(s, (q,), jnp.int32))
+    for extent in (n + 1, q * (n + 1)):
+        assert not re.search(rf"\b{extent}\b", text), extent
