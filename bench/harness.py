@@ -3,7 +3,9 @@ against the plain reference, and the metrics of the cell.
 
 Everything cell-specific is found by name: the cell in ``BENCHMARK.json``
 names its configuration (``bench/configs/<config>.json``) and its traffic
-mix (``bench/traffic/<mix>.json``); each metric is read by
+mix (``bench/traffic/<mix>.json``); the configuration's ``layout.kind``
+(``single`` where it has none) names the deployment that builds, serves and
+warms up its index (``bench/deploy/<kind>.py``); each metric is read by
 ``bench/metrics/<metric>.py``.
 """
 from __future__ import annotations
@@ -50,6 +52,12 @@ class Cell:
     entry: dict                 # the workloads entry
     cfg: dict
     mix: dict
+    root: Path = ROOT           # where its configuration and deployment lie
+
+    @property
+    def kind(self) -> str:
+        """The deployment's kind: ``bench/deploy/<kind>.py``."""
+        return self.cfg.get("layout", {}).get("kind", "single")
 
 
 def resolve_cell(spec: dict, name: str, root: Path = ROOT) -> Cell:
@@ -61,7 +69,7 @@ def resolve_cell(spec: dict, name: str, root: Path = ROOT) -> Cell:
     cfg = json.loads((root / configs[entry["config"]]["file"]).read_text())
     mix = traffic.load_mix(root / "bench" / "traffic"
                            / f"{entry['traffic']}.json")
-    return Cell(name, entry, cfg, mix)
+    return Cell(name, entry, cfg, mix, root)
 
 
 def cell_metrics(spec: dict, cell: str, key: str) -> List[dict]:
@@ -71,14 +79,27 @@ def cell_metrics(spec: dict, cell: str, key: str) -> List[dict]:
                                            for w in spec["workloads"]])]
 
 
-def reader(name: str, root: Path = ROOT) -> Callable:
-    """``bench/metrics/<name>.py``'s ``read(ctx)``."""
-    path = root / "bench" / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{name.replace('.', '_')}", path)
+def _load(path: Path, name: str):
+    spec = importlib.util.spec_from_file_location(name.replace(".", "_"),
+                                                  path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def reader(name: str, root: Path = ROOT) -> Callable:
+    """``bench/metrics/<name>.py``'s ``read(ctx)``."""
+    return _load(root / "bench" / "metrics" / f"{name}.py",
+                 f"bench_metric_{name}").read
+
+
+def deployment(kind: str, root: Path = ROOT):
+    """``bench/deploy/<kind>.py``: its ``build(cell, corpus, devices)``
+    returns the index on the cell's chips, ``engine(cell, index)`` the
+    engine that serves it, and ``warm_up(cell, index, traffic)`` compiles
+    every shape the traffic can reach."""
+    return _load(root / "bench" / "deploy" / f"{kind}.py",
+                 f"bench_deploy_{kind}")
 
 
 # ------------------------------------------------------------ context
@@ -152,40 +173,11 @@ class CompileCounter:
 
 
 def build(cell: Cell, corpus: data.Corpus):
-    from repro.core.rfann import RNSGIndex
-    return RNSGIndex.build(corpus.vecs, corpus.attrs, **cell.cfg["build"])
-
-
-def make_engine(cell: Cell, index):
-    from repro.serving.engine import RFANNEngine
-    return RFANNEngine(index, k=cell.cfg["k"], ef=cell.cfg["ef"],
-                       **cell.cfg["engine"])
-
-
-def warm_up(cell: Cell, index, tr: load.Traffic) -> None:
-    """Compile every shape the traffic can reach, outside the window: the
-    scan at every power-of-two bucket up to the planner's scan ceiling and
-    every padded batch size, and the beam at every padded batch size."""
-    cfg, mix = cell.cfg, cell.mix
-    k, ef, mb = cfg["k"], cfg["ef"], int(cfg["engine"]["max_batch"])
-    pads = [1 << i for i in range(mb.bit_length()) if 1 << i <= mb]
-    qv = tr.corpus.queries[:mb]
-    srt = tr.attrs_sorted
-    levels = sorted(set(int(v) for v in mix["levels"]))
-
-    def search(count, rg, plan):
-        index.search(qv[:count], rg[:count], k=k, ef=ef, plan=plan)
-
-    bucket = 64
-    while bucket <= index.planner.max_scan_len:
-        rg = data.rank_window(srt, bucket / len(srt), tr.r, mb)
-        for p in pads:
-            search(p, rg, "scan")
-        bucket *= 2
-    for level in {levels[0], levels[-1]}:
-        rg = data.rank_window(srt, 2.0 ** -level, tr.r, mb)
-        for p in pads:
-            search(p, rg, "beam")
+    """The cell's index alone, built by its deployment on its chips, for
+    tools that read the index without serving it."""
+    import jax
+    devices = jax.devices()[:int(cell.entry["chips"])]
+    return deployment(cell.kind, cell.root).build(cell, corpus, devices)
 
 
 def annotate_calls(index) -> None:
@@ -220,9 +212,10 @@ class Served:
 def set_up(cell: Cell, seed: int, seconds: float, *,
            require_chip: bool = True,
            fault: Optional[Callable] = None) -> Served:
-    """Check the devices, draw the data, build, start the engine, prefill
-    and warm up.  ``fault`` (tests only) is called with the built index and
-    engine, to break the timed path underneath the harness."""
+    """Check the devices, draw the data, then build, start the engine and
+    warm up through the cell's deployment.  ``fault`` (tests only) is called
+    with the built index and engine, to break the timed path underneath the
+    harness."""
     cfg, mix = cell.cfg, cell.mix
     import jax
     devs = jax.devices()
@@ -231,8 +224,9 @@ def set_up(cell: Cell, seed: int, seconds: float, *,
         f"count={len(devs)}")
     if require_chip and dev.platform != "tpu":
         raise NoChip(f"no TPU found (platform {dev.platform!r})")
-    if len(devs) < int(cell.entry["chips"]):
-        raise NoChip(f"{cell.entry['chips']} chips asked, {len(devs)} found")
+    chips = int(cell.entry["chips"])
+    if len(devs) < chips:
+        raise NoChip(f"{chips} chips asked, {len(devs)} found")
     if dev.platform == "tpu":
         # every program is cached, so only a checkout's first run compiles
         from repro.runtime.compile_cache import enable_compile_cache
@@ -244,17 +238,18 @@ def set_up(cell: Cell, seed: int, seconds: float, *,
     corpus = data.make_corpus(cfg, seed, n_ops)
     log(f"data: n={cfg['n']} d={cfg['d']} queries={n_ops} in "
         f"{time.perf_counter() - t:.3f}s")
+    deploy = deployment(cell.kind, cell.root)
     t = time.perf_counter()
-    index = build(cell, corpus)
-    log(f"build: {time.perf_counter() - t:.3f}s")
-    engine = make_engine(cell, index)
+    index = deploy.build(cell, corpus, devs[:chips])
+    log(f"build: {cell.kind}, {time.perf_counter() - t:.3f}s")
+    engine = deploy.engine(cell, index)
     tr = load.Traffic(mix, corpus, seed, cfg["k"])
     sched = traffic.make_schedule(mix, seed, n_ops)
     tr.prepare(sched)
     if fault is not None:
         fault(index, engine)
     t = time.perf_counter()
-    warm_up(cell, index, tr)
+    deploy.warm_up(cell, index, tr)
     log(f"warm-up: {time.perf_counter() - t:.3f}s, "
         f"{compiles.count} compiles so far")
     return Served(cell, corpus, index, engine, tr, sched, compiles, devs)

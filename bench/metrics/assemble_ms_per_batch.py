@@ -1,6 +1,7 @@
 """Substrate stitch layer: mean host wall per batch of assembling its
-result — request-order scatter, id remap, cache, dispatch histograms and
-cost-model feedback (``stage_assemble_ms`` sum over count)."""
+result — request-order scatter of the scan and beam partitions (routed by
+one threshold, ``max_scan_frac``), their histograms and counters, id
+remap and cache (``stage_assemble_ms`` sum over count)."""
 
 
 def read(ctx):

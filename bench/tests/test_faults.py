@@ -6,10 +6,12 @@ import threading
 import numpy as np
 import pytest
 
+from bench import harness
 from bench.tests._tiny import run_tiny, tiny_cell
 
 
-@pytest.mark.parametrize("cell", ["deep96.mixed", "deep96.narrow"])
+@pytest.mark.parametrize(
+    "cell", [w["name"] for w in harness.load_spec()["workloads"]])
 def test_sound_run_is_correct(cell):
     out = run_tiny(tiny_cell(cell))
     assert out["correct"], out["checks"]

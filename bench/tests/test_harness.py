@@ -5,10 +5,12 @@ import os
 import re
 import subprocess
 import sys
+from pathlib import Path
 
 import pytest
 
 from bench import harness, traffic
+from bench.tests._tiny import run_tiny, tiny_cell
 
 SPEC = harness.load_spec()
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
@@ -27,6 +29,9 @@ def test_every_cell_resolves_by_name(cell):
     assert layer
     for m in e2e + layer:
         assert callable(harness.reader(m["name"]))
+    dep = harness.deployment(c.kind)
+    assert all(callable(getattr(dep, f)) for f in ("build", "engine",
+                                                    "warm_up"))
     for m in layer:
         assert m["moves"] in names
 
@@ -97,23 +102,91 @@ def test_run_refuses_without_a_chip_and_prints_no_result():
 
 
 @pytest.mark.parametrize("cell", [w["name"] for w in SPEC["workloads"]])
-def test_traced_run_reads_every_per_layer_metric(cell, monkeypatch):
+def test_traced_run_reads_every_per_layer_metric(cell):
     """A traced tiny run on the CPU, with the device trace of the window
     replaced by the one recorded on a v5e chip (the CPU's has no device
     operations): every per-layer metric of the cell is read, and the
     device times and breakdown reach the result line."""
-    from bench import trace_reduce
-    from bench.tests._tiny import tiny_cell
-    recorded = harness.BENCH / "testdata" / "small.xplane.pb"
-    monkeypatch.setattr(trace_reduce, "find_trace", lambda d: recorded)
-    monkeypatch.setattr(harness.Context, "peaks", lambda self: {
-        "hbm_bytes_per_s": 8.19e11, "bf16_flops_per_s": 1.97e14})
     c = tiny_cell(cell, rate=200.0) if "rate" in tiny_cell(cell).mix \
         else tiny_cell(cell)
-    out = harness.run_cell(c.name, 2**31 + 11, 1.0, True, t_process=0.0,
-                           require_chip=False, cell=c)
+    out = run_tiny(c, 2**31 + 11, 1.0, trace=True)
     assert out["correct"], out["checks"]
     want = {m["name"] for m in harness.cell_metrics(SPEC, cell, "per_layer")}
     assert set(out["metrics"]) == want
     assert 0 < out["device"]["busy_s"] <= out["device"]["window_s"]
     assert out["breakdown"]["device_ops"] and out["breakdown"]["idle_gaps"]
+
+
+def test_a_configuration_without_layout_is_single():
+    cfgs = [harness.resolve_cell(SPEC, w["name"]) for w in SPEC["workloads"]]
+    bare = [c for c in cfgs if "layout" not in c.cfg]
+    assert bare and all(c.kind == "single" for c in bare)
+    dep = harness.deployment("single")
+    assert Path(dep.__file__) == harness.BENCH / "deploy" / "single.py"
+
+
+PROBE = """
+from pathlib import Path
+from bench.deploy import single
+
+CALLS = Path(__file__).with_suffix(".calls")
+
+
+def _called(name):
+    with CALLS.open("a") as f:
+        f.write(name + "\\n")
+
+
+def build(cell, corpus, devices):
+    _called("build")
+    return single.build(cell, corpus, devices)
+
+
+def engine(cell, index):
+    _called("engine")
+    return single.engine(cell, index)
+
+
+def warm_up(cell, index, tr):
+    _called("warm_up")
+    single.warm_up(cell, index, tr)
+"""
+
+
+def test_a_new_kind_needs_only_its_file_and_a_configuration(tmp_path):
+    """A kind written only under another root is found by its name in the
+    configuration's ``layout``, and a tiny cell runs through it correct."""
+    kind = tmp_path / "bench" / "deploy" / "probe.py"
+    kind.parent.mkdir(parents=True)
+    kind.write_text(PROBE)
+    c = tiny_cell("deep96.mixed")
+    c.cfg = dict(c.cfg, layout={"kind": "probe"})
+    c.root = tmp_path
+    out = run_tiny(c)
+    assert out["correct"], out["checks"]
+    assert kind.with_suffix(".calls").read_text().split() == [
+        "build", "engine", "warm_up"]
+
+
+@pytest.mark.parametrize("trace", [False, True])
+def test_a_four_chip_cell_runs_in_a_child_process(trace):
+    """A tiny ``deep96.mixed`` that asks for four chips runs on four CPU
+    devices in a child process (the tests' own has one): correct, with
+    every metric of its kind read."""
+    c = tiny_cell("deep96.mixed")
+    c.cfg = dict(c.cfg, layout={"kind": "single"})
+    c.entry = dict(c.entry, chips=4)
+    out = run_tiny(c, 2**31 + 13, 1.0, trace=trace)
+    assert out["correct"], out["checks"]
+    assert out["device"]["count"] == 4
+    key = "per_layer" if trace else "end_to_end"
+    want = {m["name"] for m in harness.cell_metrics(SPEC, c.name, key)}
+    assert set(out["metrics"]) == want
+
+
+def test_a_cell_with_more_chips_than_devices_is_refused():
+    import jax
+    c = tiny_cell("deep96.mixed")
+    c.entry = dict(c.entry, chips=len(jax.devices()) + 1)
+    with pytest.raises(harness.NoChip):
+        harness.set_up(c, 1, 1.0, require_chip=False)

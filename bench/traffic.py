@@ -9,8 +9,9 @@ A mix file holds only parameters:
 * ``levels`` and ``weights`` — each query covers ``2^-level`` of the
   corpus' attribute ranks, drawn in proportion to ``weights``;
 * ``settle_s`` — seconds of the same traffic served before the measured
-  window, so queues, batching and the planner's cost model reach their
-  steady state;
+  window, so queues and batching reach their steady state (routing needs
+  none: a slice of at most ``max_scan_frac`` of the corpus scans, a longer
+  one beams);
 * ``pool`` (closed loop) — queries in the schedule, sent in turn.
 
 Every seed serves the same multiset of levels and inter-arrival gaps per
