@@ -24,8 +24,9 @@ selectivity workload (2^0..2^-9, k=10) served through
 
 Four chips (``--chips 4``, this phase only).  ``DistributedRFANN`` on a
 ("data",) mesh with 2^20 rows per chip, served through the engine, and
-compared with the local per-shard path over the same shards (identical ids
-for the same per-query strategy).
+compared with the local per-shard path over the same shards under
+``plan="auto"``: both route each shard's clip by the same ceiling, so the
+ids must be identical.
 
 Every result is scored against an exact host reference (float32 candidate
 pass, float64 rescore): recall@10 must be 1.0 for scan-routed queries
@@ -231,7 +232,9 @@ def layer_line(snap: dict) -> str:
              for name in ("engine_queue_wait_ms", "engine_resolve_ms",
                           "engine_handoff_wait_ms", "scan_dispatch_ms",
                           "beam_dispatch_ms", "stage_scan_block_ms",
-                          "stage_beam_block_ms", "stage_assemble_ms",
+                          "stage_beam_block_ms", "stage_mesh_prep_ms",
+                          "stage_mesh_dispatch_ms", "stage_mesh_block_ms",
+                          "stage_assemble_ms",
                           "engine_e2e_ms")
              if name in h]
     parts += [f"{name}={c[name]}" for name in
@@ -412,17 +415,13 @@ def four_chips(args, dev, clock: CompileClock) -> None:
         f"beam={int((strat_m == BEAM).sum())}")
     recall_by_strategy(HostReference(vecs, attrs, np.arange(n)), qv,
                        ranges, ids_m, strat_m, "mesh")
-    # the same shards through the local path, each query forced onto the
-    # strategy the mesh planner gave it
+    # the same shards through the local path under plan="auto": each
+    # shard's planner there routes its clip by the ceiling the mesh uses
+    # (and at 2^20 rows a shard every beamed clip is over 2^17 rows, so its
+    # selectivity-capped ef is the mesh's ef)
     mesh_path, dist.mesh = dist.mesh, None
     try:
-        ids_l = np.full_like(ids_m, -1)
-        for plan, code in (("scan", SCAN), ("beam", BEAM)):
-            sel = np.flatnonzero(strat_m == code)
-            if len(sel):
-                ids, _ = dist.search(qv[sel], ranges[sel], k=K,
-                                     ef=EF, plan=plan)
-                ids_l[sel] = ids
+        ids_l, _ = dist.search(qv, ranges, k=K, ef=EF, plan="auto")
     finally:
         dist.mesh = mesh_path
     same = (ids_l == ids_m).all(axis=1)

@@ -34,11 +34,13 @@ from repro.obs.trace import Span
 PREFIX = "rnsg."
 
 #: the leaf stages that tile the engine's dispatcher thread, from waiting
-#: for a resolved batch to setting its last future (``serving/engine.py``
-#: and the single-chip substrate, ``search/substrate.py``)
+#: for a resolved batch to setting its last future (``serving/engine.py``,
+#: the single-chip substrate and the mesh substrate, ``search/substrate.py``,
+#: and the distributed local path's ``merge``)
 DISPATCHER_STAGES = ("await_batch", "plan", "scan_prep", "scan_dispatch",
                      "rerank", "scan_block", "beam_prep", "beam_dispatch",
-                     "graph_beam_dispatch", "beam_block", "assemble",
+                     "graph_beam_dispatch", "beam_block", "mesh_prep",
+                     "mesh_dispatch", "mesh_block", "merge", "assemble",
                      "complete")
 
 

@@ -19,10 +19,10 @@ primitives:
 * ``SearchSubstrate`` — one attribute-sorted corpus slice on the host
   (single node, or one shard of the distributed local path); the planner
   partitions each batch into fixed-shape jit dispatches.
-* ``MeshSubstrate`` — all shards at once under ``shard_map``; the planner
-  runs host-side over shard-clipped global intervals and the traced
-  per-device body executes a branchless scan+beam select, restitched in
-  request order before the cross-shard ``merge_topk``.
+* ``MeshSubstrate`` — all shards at once under ``shard_map``; each shard
+  routes its own clip of every global interval host-side, and one program
+  per scan bucket and one for the beam write into per-shard request-order
+  buffers before the cross-shard ``merge_topk``.
 
 See docs/architecture.md for the layer diagram and docs/distributed.md for
 the mesh dispatch flow.

@@ -37,13 +37,13 @@ by duplicating the last real query (a duplicate lane adds no extra
 ``while_loop`` iterations under vmap).
 
 ``MeshSubstrate`` is the ``shard_map`` twin for multi-device serving: the
-planner runs **host-side** over the globally resolved rank intervals (clipped
-per shard), and the resulting strategy vector partitions the batch into
-scan/beam sub-batches that enter the traced per-device body as replicated
-operands — a branchless select in which each shard executes the ``range_scan``
-kernel and the beam search at most once per call, scatters both groups back
-into request order, and finishes with the cross-shard ``all_gather`` + top-k
-merge.  See docs/distributed.md.
+router runs **host-side** over the globally resolved rank intervals, clipped
+per shard, and each shard routes its own clip (scan up to the planner's
+ceiling, beam beyond).  The (shard, query) pairs of a batch, padded to
+``mesh_rows`` rows, form one program per scan bucket and one beam program,
+each scattering its shard's results into a per-shard request-order buffer;
+one last program ``all_gather``s the buffers and takes the cross-shard
+top-k.  See docs/distributed.md.
 """
 from __future__ import annotations
 
@@ -54,7 +54,7 @@ from typing import Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core.beam import beam_search_batch, rerank_pool
 from repro.kernels.ops import range_scan
@@ -62,8 +62,8 @@ from repro.kernels.quantize import (QuantizedCorpus, quantize_corpus,
                                     rerank_depth)
 from repro.obs.metrics import MetricsRegistry
 from repro.obs.stages import stage
-from repro.planner.bucketing import (ROW_TILE, bucket_for_len, next_pow2,
-                                     pad_pow2, window_rows)
+from repro.planner.bucketing import (ROW_TILE, bucket_for_len, buckets_np,
+                                     next_pow2, pad_pow2, window_rows)
 from repro.planner.planner import BEAM, QueryPlanner, SCAN
 from repro.search import resolve
 from repro.search.cache import SearchCache
@@ -611,6 +611,137 @@ def _book_visited(met: MetricsRegistry, st: dict) -> None:
 # ======================================================================
 # Mesh path: traced per-device bodies + the host-planned mesh substrate.
 # ======================================================================
+#: floor of a mesh call's padded batch: every batch of up to this many rows
+#: pads to the same row count, so the batch size enters no compile key
+MESH_ROWS = 64
+#: route of a (shard, query) pair whose shard-local clip is empty
+NO_CLIP = -1
+
+
+def mesh_rows(nq: int) -> int:
+    """Rows of a mesh call's padded batch: a power of two, at least
+    ``MESH_ROWS``."""
+    return max(next_pow2(nq), MESH_ROWS)
+
+
+def shard_routes(lo, hi, *, per: int, n_shards: int, planner: QueryPlanner,
+                 k: int, mode: str):
+    """Per-shard routing of a batch of global rank intervals.
+
+    Returns ``(routes, slo, shi)``, each (S, Q): ``routes`` holds ``SCAN``,
+    ``BEAM`` or ``NO_CLIP`` per (shard, query) pair, ``slo``/``shi`` the
+    shard-local clip.  Under ``auto`` each shard routes its own clip by the
+    planner's ceiling (scan at most ``planner.max_scan_len`` rows, beam
+    longer ones), so a window that straddles two shards may be scanned on
+    one and beamed on the other; ``scan``/``beam`` force every non-empty
+    clip.  An empty clip dispatches nothing."""
+    rank0 = np.arange(n_shards, dtype=np.int64)[:, None] * per
+    slo, shi = resolve.clip_interval(np.asarray(lo, np.int64)[None, :],
+                                     np.asarray(hi, np.int64)[None, :],
+                                     rank0, per)
+    lens = np.clip(shi.astype(np.int64) - slo + 1, 0, None)
+    if mode == "scan":
+        scan = np.ones(lens.shape, bool)
+    elif mode == "beam":
+        scan = np.zeros(lens.shape, bool)
+    else:
+        scan = planner.choose_strategy_batch(
+            lens.ravel(), k=k).reshape(lens.shape) == SCAN
+    routes = np.where(lens > 0, np.where(scan, SCAN, BEAM), NO_CLIP)
+    return routes.astype(np.int8), slo, shi
+
+
+def reported_strategy(routes: np.ndarray) -> np.ndarray:
+    """(Q,) strategy of each merged answer: ``SCAN`` (exact) when every
+    shard with a non-empty clip scanned it, else ``BEAM``."""
+    return np.where((routes == BEAM).any(axis=0), BEAM, SCAN).astype(np.int8)
+
+
+def _group_rows(sel: np.ndarray, a: np.ndarray, b: np.ndarray, rows: int,
+                fill: Tuple[int, int]) -> np.ndarray:
+    """(S, 3, pad) int32 operand of one group program: per shard, the
+    request positions of its selected pairs (``dst``) and their (a, b)
+    window, padded to ``pad_pow2`` of the largest shard's count with
+    ``fill`` windows that scatter into the sink row ``rows``."""
+    pad = pad_pow2(int(sel.sum(axis=1).max()))
+    ops = np.empty((len(sel), 3, pad), np.int32)
+    ops[:, 0], ops[:, 1], ops[:, 2] = rows, fill[0], fill[1]
+    for s in range(len(sel)):
+        q = np.flatnonzero(sel[s])
+        ops[s, 0, :len(q)] = q
+        ops[s, 1, :len(q)] = a[s, q]
+        ops[s, 2, :len(q)] = b[s, q]
+    return ops
+
+
+def _scatter(buf_i, buf_d, dst, order, ids, dists):
+    """One group's shard-local results into this shard's request-order
+    buffer (pad rows land in the sink row, the last)."""
+    dists = jnp.where(ids >= 0, dists, jnp.inf)
+    return (buf_i.at[0, dst].set(resolve.remap_ids_jax(order, ids)),
+            buf_d.at[0, dst].set(dists))
+
+
+def _shard_scan(x_scan, vecs, order, scale, live, qall, ops, buf_i, buf_d,
+                *, k: int, ef: int, bucket: int, precision: str,
+                use_live: bool):
+    """Per-device body of one scan group: this shard's clips of one pow2
+    bucket through the ``range_scan`` kernel, scattered into its buffer.
+
+    ``x_scan`` is the row/lane-padded shard corpus, quantized under a
+    non-f32 ``precision`` (then ``scale`` is the replicated (d_pad,)
+    dequant row and the ``rerank_depth`` survivors are rescored against the
+    f32 ``vecs``, so scan rows leave exact).  ``live`` is the sharded
+    (1, per) liveness mask, untouched under ``use_live=False``."""
+    x_scan, vecs, order = x_scan[0], vecs[0], order[0]
+    n, d = vecs.shape
+    dst, starts, lens = ops[0, 0], ops[0, 1], ops[0, 2]
+    q = qall[dst]
+    live_row = None
+    if use_live:
+        live_row = jnp.pad(live[0].astype(jnp.int32),
+                           (0, x_scan.shape[0] - n))[None, :]
+    if precision == "f32":
+        ids, dists = range_scan(x_scan, starts, lens, q, bucket=bucket, k=k,
+                                n_valid=n, live=live_row)
+    else:
+        ids_q, _ = range_scan(x_scan, starts, lens, q, bucket=bucket,
+                              k=rerank_depth(k, ef, cap=ROW_TILE), n_valid=n,
+                              scale=scale if precision == "int8" else None,
+                              live=live_row)
+        ids, dists = rerank_pool(vecs, ids_q, q[:, :d], k, use_kernel=False)
+    return _scatter(buf_i, buf_d, dst, order, ids, dists)
+
+
+def _shard_beam(vecs, nbrs, rmq, dist_c, order, xq, scale, live, qall, ops,
+                buf_i, buf_d, *, k: int, ef: int, beam_width: int,
+                precision: str, use_live: bool):
+    """Per-device body of the beam group: this shard's beam-routed clips
+    through ``beam_search_batch`` (entry by RMQ over the clip), scattered
+    into its buffer.  ``xq``/``scale`` are the quantized scoring operands
+    (ignored under f32); quantized traversals rerank their pool in f32."""
+    vecs, nbrs = vecs[0], nbrs[0]
+    rmq, dist_c, order = rmq[0], dist_c[0], order[0]
+    n, d = vecs.shape
+    dst, lo, hi = ops[0, 0], ops[0, 1], ops[0, 2]
+    quant = None if precision == "f32" else (
+        xq[0], scale[:d] if precision == "int8" else None)
+    entry = resolve.select_entry(rmq, dist_c, lo, hi, n)
+    ids, dists, _ = beam_search_batch(vecs, nbrs, qall[dst, :d], lo, hi,
+                                      entry, k=k, ef=ef,
+                                      beam_width=beam_width, quant=quant,
+                                      live=live[0] if use_live else None)
+    return _scatter(buf_i, buf_d, dst, order, ids, dists)
+
+
+def _shard_merge(buf_i, buf_d, *, k: int, axis: str):
+    """Per-device merge: every shard's request-order buffer (sink row
+    dropped) gathered across the mesh, then the cross-shard top-k."""
+    ids = jax.lax.all_gather(buf_i[0, :-1], axis)          # (S, rows, k)
+    dists = jax.lax.all_gather(buf_d[0, :-1], axis)
+    return merge_topk(ids, dists, k)
+
+
 def _shard_graph(vecs, nbrs, rmq, dist_c, order, rank0, xq, scale, live, qv,
                  lo, hi, *, k: int, ef: int, axis: str, beam_width: int = 1,
                  precision: str = "f32", use_live: bool = False):
@@ -650,106 +781,66 @@ def _shard_graph(vecs, nbrs, rmq, dist_c, order, rank0, xq, scale, live, qv,
     return merge_topk(ids_g, ds_g, k)
 
 
-def _shard_planned(x_scan, vecs, nbrs, rmq, dist_c, order, rank0, xq, scale,
-                   live, scan_q, scan_lo, scan_hi, scan_dst,
-                   beam_q, beam_lo, beam_hi, beam_dst, *,
-                   k: int, ef: int, bucket: int, nq: int,
-                   has_beam: bool, axis: str, beam_width: int = 1,
-                   precision: str = "f32", use_live: bool = False):
-    """Per-device planned body: branchless strategy dispatch.
+#: each mesh program's per-device body and the layout of its operands and
+#: of its outputs: ``s`` sharded along the mesh axis, ``r`` replicated
+_PROGRAMS = {
+    "scan": (_shard_scan, "sssrsrsss", "ss"),
+    "beam": (_shard_beam, "ssssssrsrsss", "ss"),
+    "merge": (_shard_merge, "ss", "rr"),
+    "graph": (_shard_graph, "sssssssrsrrr", "rr"),
+}
 
-    The host already split the batch into scan/beam sub-batches (replicated
-    operands, padded to pow2 with empty windows), so the trace runs the
-    ``range_scan`` kernel and the beam search **at most once each** — no
-    ``lax.cond`` on traced values, no per-query branching.  Each group's
-    results scatter into an ``(nq+1, k)`` buffer at its original request
-    positions (pads land in the sink row ``nq``, dropped before the merge),
-    restoring request order *before* the cross-shard top-k merge so the merge
-    is identical to the graph body's.
 
-    Quantized precisions: ``x_scan`` holds the *quantized* padded scan
-    corpus (the caller swaps it per precision — same rank order, narrower
-    DMA), ``xq`` the unpadded quantized rows for the beam's gathers, and
-    ``scale`` the replicated (d_pad,) dequant row.  The scan keeps
-    ``rerank_depth`` survivors and rescores them against the f32 ``vecs``
-    in-trace, so scan rows leave this body exact; the beam reranks inside
-    ``beam_search_batch``.  Under f32 the extra operands alias ``vecs`` /
-    ones and are ignored.
-
-    The scan group is always non-empty here — uniform-beam batches dispatch
-    the graph body instead (``MeshSubstrate.run`` fast path).
-
-    ``live`` is the sharded (1, per) shard-local liveness mask (all-ones and
-    untouched under ``use_live=False``): the scan masks dead rows in-kernel
-    (a (1, per_pad) i32 row built in-trace), the beam filters its final
-    pool."""
-    x_scan, vecs, nbrs = x_scan[0], vecs[0], nbrs[0]
-    rmq, dist_c, order = rmq[0], dist_c[0], order[0]
-    n, d = vecs.shape
-    if use_live:
-        live_sh = live[0]                                # (per,) shard-local
-        live_row = jnp.pad(live_sh.astype(jnp.int32),
-                           (0, x_scan.shape[0] - n))[None, :]
-        live_beam = live_sh.astype(bool)
-    else:
-        live_row = live_beam = None
-    out_i = jnp.full((nq + 1, k), -1, jnp.int32)
-    out_d = jnp.full((nq + 1, k), jnp.inf, jnp.float32)
-    slo, shi = resolve.clip_interval_jax(scan_lo, scan_hi, rank0[0], n)
-    lens = jnp.clip(shi - slo + 1, 0, bucket)            # shard-local window
-    starts = jnp.clip(slo, 0, n - 1)                     # (len 0 when empty)
-    if precision == "f32":
-        ids_s, d_s = range_scan(x_scan, starts, lens, scan_q,
-                                bucket=bucket, k=k, n_valid=n, live=live_row)
-    else:
-        rq = rerank_depth(k, ef, cap=ROW_TILE)
-        ids_q, _ = range_scan(x_scan, starts, lens, scan_q,
-                              bucket=bucket, k=rq, n_valid=n,
-                              scale=scale if precision == "int8" else None,
-                              live=live_row)
-        ids_s, d_s = rerank_pool(vecs, ids_q, scan_q[:, :d], k,
-                                 use_kernel=False)
-    d_s = jnp.where(ids_s >= 0, d_s, jnp.inf)
-    out_i = out_i.at[scan_dst].set(resolve.remap_ids_jax(order, ids_s))
-    out_d = out_d.at[scan_dst].set(d_s)
-    if has_beam:
-        if precision == "f32":
-            quant = None
-        else:
-            quant = (xq[0], scale[:d] if precision == "int8" else None)
-        slo, shi = resolve.clip_interval_jax(beam_lo, beam_hi, rank0[0], n)
-        entry = resolve.select_entry(rmq, dist_c, slo, shi, n)
-        ids_b, d_b, _ = beam_search_batch(vecs, nbrs, beam_q, slo, shi,
-                                          entry, k=k, ef=ef,
-                                          beam_width=beam_width,
-                                          quant=quant, live=live_beam)
-        d_b = jnp.where(ids_b >= 0, d_b, jnp.inf)
-        out_i = out_i.at[beam_dst].set(resolve.remap_ids_jax(order, ids_b))
-        out_d = out_d.at[beam_dst].set(d_b)
-    ids_g = jax.lax.all_gather(out_i[:nq], axis)         # (S, Q, k)
-    ds_g = jax.lax.all_gather(out_d[:nq], axis)
-    return merge_topk(ids_g, ds_g, k)
+def mesh_program(mesh, axis: str, kind: str, **static):
+    """The jitted ``shard_map`` program of one body of ``_PROGRAMS``, with
+    its static arguments (bodies that gather across the mesh get
+    ``axis``)."""
+    body, ins, outs = _PROGRAMS[kind]
+    spec = {"s": P(axis), "r": P()}
+    if kind in ("merge", "graph"):
+        static["axis"] = axis
+    return jax.jit(jax.shard_map(
+        partial(body, **static), mesh=mesh,
+        in_specs=tuple(spec[c] for c in ins),
+        out_specs=tuple(spec[c] for c in outs), check_vma=False))
 
 
 class MeshSubstrate:
     """Mesh-path twin of ``SearchSubstrate``: host planning, traced dispatch.
 
     The router is host-side policy and cannot run inside a traced
-    ``shard_map`` body, so the strategy split happens **before** tracing:
+    ``shard_map`` body, so each batch is planned before anything is traced.
+    Every step is a leaf stage on the installed registry:
 
-    * plan     — ``QueryPlanner.choose_strategy_batch`` over each query's
-                 widest shard-local clip of the globally resolved rank
-                 interval (one replicated decision per query — every shard
-                 must agree so the traced shapes stay uniform);
-    * dispatch — the strategy vector partitions the batch host-side into a
-                 scan sub-batch (one shared pow2 ``bucket``) and a beam
-                 sub-batch, entering ``shard_map`` as replicated operands;
-                 ``_shard_planned`` runs each kernel at most once per shard;
-    * stitch   — in-trace scatter back to request order, ``all_gather`` +
-                 ``merge_topk`` across shards, replicated result.
+    * ``plan``          — ``shard_routes``: each global rank interval is
+                          clipped to every shard, and each shard routes its
+                          own clip by the planner's ceiling (scan up to
+                          ``max_scan_len`` rows, beam longer ones; an empty
+                          clip dispatches nothing).  An answer reports
+                          ``SCAN`` only when every shard with a non-empty
+                          clip scanned it;
+    * ``mesh_prep``     — the batch pads to ``mesh_rows(Q)`` rows; scan
+                          pairs group by pow2 bucket, beam pairs form one
+                          group, each padded per shard to ``pad_pow2`` of
+                          its largest shard's count (pad rows carry empty
+                          windows and scatter into a sink row); one
+                          ``device_put`` uploads the replicated queries and
+                          every group's sharded (dst, lo, hi) rows;
+    * ``mesh_dispatch`` — one program per group scatters its shard's results
+                          into a per-shard request-order buffer, then one
+                          program ``all_gather``s the buffers and
+                          ``merge_topk``s them: a query scanned on one shard
+                          and beamed on another is merged, never
+                          overwritten;
+    * ``mesh_block``    — the wait for the merged result and its copy back;
+    * ``assemble``      — result and cache assembly.
 
-    Compiled signatures are bounded the same way as the local planner's:
-    ``(k, ef, bucket, pad_pow2(|scan|), pad_pow2(|beam|), Q)``.
+    Programs are keyed by ``(bucket, pad, rows)`` (scan), ``(pad, rows)``
+    (beam) and ``rows`` (merge), with k, ef, width, precision and liveness.
+    Every batch of up to ``MESH_ROWS`` queries pads to the same ``rows``, so
+    the batch size enters no key, and ``warm_up`` compiles every program a
+    batch can reach.  The ``graph`` strategy runs the paper's one-program
+    beam over the padded batch (``graph_fn``).
     """
 
     def __init__(self, mesh, axis: str, vecs, nbrs, rmq, dist_c, order,
@@ -775,6 +866,7 @@ class MeshSubstrate:
         self._ones = None           # dummy replicated scale row (f32/bf16)
         self._live_memo = None      # (mask, (S, per) bool device copy)
         self._live_ones = None      # dummy all-live mask (uniform operands)
+        self._empty: Dict[Tuple[int, int], tuple] = {}   # (rows, k) buffers
         self._fns: Dict[Tuple, object] = {}
 
     @property
@@ -847,35 +939,19 @@ class MeshSubstrate:
     # ------------------------------------------------------------- planning
     def plan_strategies(self, lo: np.ndarray, hi: np.ndarray, *, k: int,
                         mode: str) -> Tuple[np.ndarray, np.ndarray]:
-        """Host half of mesh dispatch: (strategy (Q,) int8, lens_eff (Q,)).
-
-        ``lens_eff`` is each query's **widest shard-local clip** of its
-        global rank interval — the decision must be one replicated scalar
-        per query, and the widest shard is the one whose scan the traced
-        dispatch actually pays (shards execute in lockstep)."""
-        lo = np.asarray(lo, np.int64)
-        hi = np.asarray(hi, np.int64)
-        lens_eff = np.zeros(len(lo), np.int64)
-        for s in range(self.n_shards):
-            slo, shi = resolve.clip_interval(lo, hi, s * self.per, self.per)
-            lens_eff = np.maximum(lens_eff, np.clip(
-                shi.astype(np.int64) - slo + 1, 0, None))
-        if mode == "scan":
-            return np.full(len(lo), SCAN, np.int8), lens_eff
-        if mode == "beam":
-            return np.full(len(lo), BEAM, np.int8), lens_eff
-        return self.planner.choose_strategy_batch(lens_eff, k=k), lens_eff
+        """Host half of mesh dispatch: (reported strategy (Q,) int8,
+        per-shard routes (S, Q) int8) — see ``shard_routes``."""
+        routes, _, _ = shard_routes(lo, hi, per=self.per,
+                                    n_shards=self.n_shards,
+                                    planner=self.planner, k=k, mode=mode)
+        return reported_strategy(routes), routes
 
     # ---------------------------------------------------------------- run
     def run(self, req: SearchRequest) -> SearchResult:
         """Dispatch one request on the mesh; result ids are original corpus
         ids, already merged across shards (replicated).  With a cache
-        installed, hit rows skip the mesh dispatch entirely.  Stages (no
-        stage histograms: the mesh path keeps ``mesh_dispatch_ms``):
-        ``plan`` (cache split, routing), ``mesh_graph_dispatch`` /
-        ``mesh_planned_dispatch`` (enqueue and block — the cross-shard
-        scatter and merge run *inside* the traced body), ``assemble``
-        (result and cache assembly)."""
+        installed, hit rows skip the mesh dispatch entirely.  Stages: see
+        the class docstring."""
         qv = np.asarray(req.queries, np.float32)
         lo = np.asarray(req.lo, np.int64)
         hi = np.asarray(req.hi, np.int64)
@@ -893,7 +969,7 @@ class MeshSubstrate:
         cache = self.cache
         split = None
         route = None
-        with stage("plan", None, tr) as sp:
+        with stage("plan", met, tr) as sp:
             if met is not None:
                 met.counter("queries_total").inc(nq)
                 met.counter("mesh_queries_total").inc(nq)
@@ -920,14 +996,11 @@ class MeshSubstrate:
                             dispatched=len(qv), beam_width=bw,
                             precision=prec)
             if len(qv):
-                if tr is not None:
-                    sp.attrs["shard_clip_widths"] = \
-                        self._shard_clip_widths(lo, hi)
                 route = self._route(lo, hi, k, mode, tr, sp)
         if route is not None:
-            ids, dists, stats = self._execute(qv, lo, hi, k, ef, mode, bw,
-                                              prec, route, tr, req.live)
-        with stage("assemble", None, tr):
+            ids, dists, stats = self._execute(qv, lo, hi, k, ef, bw, prec,
+                                              route, tr, req.live)
+        with stage("assemble", met, tr):
             res = None if route is None else SearchResult(ids, dists, stats)
             if split is not None:
                 epoch, keys, hit_rows, miss, dups = split
@@ -941,151 +1014,155 @@ class MeshSubstrate:
             res.trace = tr
         return res
 
-    def _shard_clip_widths(self, lo, hi) -> np.ndarray:
-        """(S, Q) shard-local clipped interval widths — the plan-span view
-        of how each query's global interval lands on the mesh."""
-        w = []
-        for s in range(self.n_shards):
-            slo, shi = resolve.clip_interval(lo, hi, s * self.per, self.per)
-            w.append(np.clip(shi.astype(np.int64) - slo + 1, 0, None))
-        return np.stack(w)
-
     def _route(self, lo, hi, k: int, mode: str, trace, sp):
-        """Routing of the ``plan`` stage: ``None`` for the graph strategy,
-        else the per-query strategy vector and widest shard-local clips."""
+        """Routing of the ``plan`` stage: ``"graph"`` for the graph
+        strategy, else (reported strategy, routes, slo, shi) from
+        ``shard_routes``; counts queries by reported strategy and
+        (query, shard) pairs by route."""
+        met = self.metrics
         if mode == "graph":
             sp.attrs["chosen"] = "graph"
-            if self.metrics is not None:
-                self.metrics.counter("graph_queries_total").inc(len(lo))
+            if met is not None:
+                met.counter("graph_queries_total").inc(len(lo))
             return "graph"
-        strategy, lens_eff = self.plan_strategies(lo, hi, k=k, mode=mode)
+        routes, slo, shi = shard_routes(lo, hi, per=self.per,
+                                        n_shards=self.n_shards,
+                                        planner=self.planner, k=k, mode=mode)
+        strategy = reported_strategy(routes)
         if trace is not None:
-            sp.attrs.update(strategy=strategy.copy(),
-                            lens_eff=lens_eff.copy(),
+            sp.attrs.update(strategy=strategy.copy(), shard_routes=routes,
+                            shard_clip_widths=np.clip(
+                                shi.astype(np.int64) - slo + 1, 0, None),
                             scan_frac=float((strategy == SCAN).mean()))
-        if self.metrics is not None:
+        if met is not None:
             n_scan = int((strategy == SCAN).sum())
-            self.metrics.counter("scan_routed_total").inc(n_scan)
-            self.metrics.counter("beam_routed_total").inc(len(lo) - n_scan)
-        return strategy, lens_eff
+            met.counter("scan_routed_total").inc(n_scan)
+            met.counter("beam_routed_total").inc(len(lo) - n_scan)
+            for name, code in (("scan", SCAN), ("beam", BEAM)):
+                met.counter(f"mesh_shard_{name}_total").inc(
+                    int((routes == code).sum()))
+        return strategy, routes, slo, shi
 
-    def _execute(self, qv, lo, hi, k: int, ef: int, mode: str,
-                 beam_width: int, precision: str, route, trace, live):
-        """Run one routed batch on the mesh: (ids, dists, stats)."""
+    def _execute(self, qv, lo, hi, k: int, ef: int, beam_width: int,
+                 precision: str, route, trace, live):
+        """Run one routed batch on the mesh (``mesh_prep``,
+        ``mesh_dispatch``, ``mesh_block``): (ids, dists, stats)."""
         nq = len(qv)
+        rows = mesh_rows(nq)
         met = self.metrics
+        use_live = live is not None
+        shard = NamedSharding(self.mesh, P(self.axis))
+        rep = NamedSharding(self.mesh, P())
+        with stage("mesh_prep", met, trace) as sp:
+            if route == "graph":
+                qp = np.zeros((rows, self.d), np.float32)
+                g_lo = np.ones(rows, np.int32)      # pad rows: empty windows
+                g_hi = np.zeros(rows, np.int32)
+                qp[:nq], g_lo[:nq], g_hi[:nq] = qv, lo, hi
+                host, groups = [qp, g_lo, g_hi], []
+                dispatched = self.n_shards * rows
+                real = self.n_shards * nq
+            else:
+                strategy, routes, slo, shi = route
+                qall = np.zeros((rows + 1, self.d_pad), np.float32)
+                qall[:nq, :self.d] = qv
+                groups = self._groups(routes, slo, shi, rows)
+                host = [qall] + [ops for _, ops in groups]
+                # every group program runs its pad rows on every shard, and
+                # the merge gathers ``rows`` from each
+                dispatched = self.n_shards * (
+                    sum(ops.shape[2] for _, ops in groups) + rows)
+                real = int((routes != NO_CLIP).sum()) + self.n_shards * nq
+            if met is not None:
+                met.counter("mesh_rows_total").inc(dispatched)
+                met.counter("mesh_pad_rows_total").inc(dispatched - real)
+            sp.attrs.update(rows=rows, pad_rows=dispatched - real,
+                            groups=[(b, ops.shape[2]) for b, ops in groups])
+            dev = jax.device_put(host, [rep] * (len(host) - len(groups))
+                                 + [shard] * len(groups))
+            live_d = self._live_shards(live)
+            slot = self._quant_for(precision)
+        with stage("mesh_dispatch", met, trace):
+            if route == "graph":
+                xq = self._vecs if slot is None else slot["data"]
+                scale = (self._ones_scale() if slot is None
+                         else slot["scale_pad"])
+                out = self.graph_fn(k, ef, beam_width, precision,
+                                    use_live=use_live)(
+                    self._vecs, self._nbrs, self._rmq, self._dist_c,
+                    self._order, self._rank0, xq, scale, live_d, *dev)
+            else:
+                out = self._dispatch_groups(groups, dev, rows, k, ef,
+                                            beam_width, precision, slot,
+                                            live_d, use_live)
+            del dev
+        with stage("mesh_block", met, trace):
+            ids, dists = jax.device_get(out)
+            ids, dists = ids[:nq], dists[:nq]
+            del out
         if route == "graph":
-            ids, dists = self._call_graph(qv, lo, hi, k, ef,
-                                          beam_width=beam_width,
-                                          precision=precision, live=live,
-                                          trace=trace)
             return ids, dists, {"strategy": np.ones(nq, np.int8),
                                 "scan_frac": 0.0}
-        strategy, lens_eff = route
-        scan_idx = np.flatnonzero(strategy == SCAN)
-        beam_idx = np.flatnonzero(strategy == BEAM)
-        if len(scan_idx) == 0:
-            # uniform-beam batch: the planned body would degenerate to the
-            # graph body plus pow2 padding and a scatter — dispatch the graph
-            # fn directly (same ef, same merge, bit-identical results)
-            ids, dists = self._call_graph(qv, lo, hi, k, ef,
-                                          beam_width=beam_width,
-                                          precision=precision, live=live,
-                                          trace=trace)
-            return ids, dists, {"strategy": strategy, "scan_frac": 0.0}
-        # scan_idx is non-empty past the fast path; one shared bucket covers
-        # every scan query's widest shard-local clip (never truncates)
-        cap = next_pow2(self.per)
-        bucket = max(bucket_for_len(
-            int(ln), min_bucket=self.planner.min_bucket, max_bucket=cap)
-            for ln in lens_eff[scan_idx])
-        pad_s = pad_pow2(len(scan_idx))
-        pad_b = pad_pow2(len(beam_idx)) if len(beam_idx) else 0
-        use_live = live is not None
-        key = ("planned", k, ef, bucket, pad_s, pad_b, nq, beam_width,
-               precision, use_live)
-        warm = key in self._fns
-        fn = self._planned_fn(k=k, ef=ef, bucket=bucket, pad_s=pad_s,
-                              pad_b=pad_b, nq=nq, beam_width=beam_width,
-                              precision=precision, use_live=use_live)
-        slot = self._quant_for(precision)
-        if slot is None:
-            x_scan, xq, scale = (self._scan_corpus(), self._vecs,
-                                 self._ones_scale())
-        else:
-            x_scan, xq, scale = (slot["data_pad"], slot["data"],
-                                 slot["scale_pad"])
-        scan_ops = self._group_operands(qv, lo, hi, scan_idx, pad_s, nq,
-                                        lane_pad=True)
-        beam_ops = self._group_operands(qv, lo, hi, beam_idx, pad_b, nq,
-                                        lane_pad=False)
-        pad_rows = (pad_s - len(scan_idx)) + (pad_b - len(beam_idx))
-        if met is not None and pad_rows:
-            met.counter("pad_rows_total").inc(pad_rows)
-        t0 = time.perf_counter()
-        with stage("mesh_planned_dispatch", None, trace) as sp:
-            sp.attrs.update(warm=warm, bucket=bucket, pad_scan=pad_s,
-                            pad_beam=pad_b, pad_rows=pad_rows)
-            ids, dists = fn(x_scan, self._vecs,
-                                  self._nbrs, self._rmq, self._dist_c,
-                                  self._order, self._rank0, xq, scale,
-                                  self._live_shards(live),
-                                  *scan_ops, *beam_ops)
-            ids = np.asarray(ids)
-            dists = np.asarray(dists)
-        if met is not None:
-            met.histogram("mesh_dispatch_ms").observe(
-                (time.perf_counter() - t0) * 1e3)
         return ids, dists, {"strategy": strategy,
-                            "scan_frac": len(scan_idx) / nq}
+                            "scan_frac": float((strategy == SCAN).mean())}
 
-    def _call_graph(self, qv, lo, hi, k: int, ef: int, *,
-                    beam_width: int = 1, precision: str = "f32", live=None,
-                    trace=None):
-        """One graph-body mesh dispatch."""
-        use_live = live is not None
-        warm = ("graph", k, max(ef, k), beam_width, precision,
-                use_live) in self._fns
-        fn = self.graph_fn(k, ef, beam_width, precision, use_live=use_live)
-        slot = self._quant_for(precision)
-        xq = self._vecs if slot is None else slot["data"]
+    def _groups(self, routes, slo, shi, rows: int):
+        """The group programs of one batch: ``(bucket, ops)`` for each pow2
+        scan bucket any shard reaches, then ``(0, ops)`` for the beam
+        (``_group_rows``: scan rows carry (start, len), beam rows (lo, hi),
+        all shard-local)."""
+        lens = np.clip(shi.astype(np.int64) - slo + 1, 0, None)
+        out = []
+        scan = routes == SCAN
+        if scan.any():
+            buckets = buckets_np(lens, min_bucket=self.planner.min_bucket,
+                                 max_bucket=next_pow2(self.per))
+            for b in np.unique(buckets[scan]):
+                out.append((int(b), _group_rows(scan & (buckets == b), slo,
+                                                lens, rows, (0, 0))))
+        beam = routes == BEAM
+        if beam.any():
+            out.append((0, _group_rows(beam, slo, shi, rows, (1, 0))))
+        return out
+
+    def _dispatch_groups(self, groups, dev, rows: int, k: int, ef: int,
+                         beam_width: int, precision: str, slot, live_d,
+                         use_live: bool):
+        """Enqueue each group program over the per-shard buffers, then the
+        merge; returns the replicated (rows, k) (ids, dists)."""
         scale = self._ones_scale() if slot is None else slot["scale_pad"]
-        t0 = time.perf_counter()
-        with stage("mesh_graph_dispatch", None, trace) as sp:
-            sp.attrs["warm"] = warm
-            ids, dists = fn(self._vecs, self._nbrs, self._rmq,
-                                  self._dist_c, self._order, self._rank0,
-                                  xq, scale, self._live_shards(live),
-                                  jnp.asarray(qv),
-                                  jnp.asarray(np.asarray(lo).astype(np.int32)),
-                                  jnp.asarray(np.asarray(hi).astype(np.int32)))
-            ids = np.asarray(ids)
-            dists = np.asarray(dists)
-        if self.metrics is not None:
-            self.metrics.histogram("mesh_dispatch_ms").observe(
-                (time.perf_counter() - t0) * 1e3)
-        return ids, dists
+        buf = self._empty_buffers(rows, k)
+        qall = dev[0]
+        for (bucket, ops), ops_d in zip(groups, dev[1:]):
+            shape = (ops.shape[2], rows)
+            if bucket:
+                x_scan = (self._scan_corpus() if slot is None
+                          else slot["data_pad"])
+                fn = self._program("scan", shape, k=k, ef=ef, bucket=bucket,
+                                   precision=precision, use_live=use_live)
+                buf = fn(x_scan, self._vecs, self._order, scale, live_d,
+                         qall, ops_d, *buf)
+            else:
+                xq = self._vecs if slot is None else slot["data"]
+                fn = self._program("beam", shape, k=k, ef=ef,
+                                   beam_width=beam_width,
+                                   precision=precision, use_live=use_live)
+                buf = fn(self._vecs, self._nbrs, self._rmq, self._dist_c,
+                         self._order, xq, scale, live_d, qall, ops_d, *buf)
+        return self._program("merge", (rows,), k=k)(*buf)
 
-    # ------------------------------------------------------------ operands
-    def _group_operands(self, qv, lo, hi, idx, pad: int, nq: int, *,
-                        lane_pad: bool):
-        """One strategy group's replicated operands: queries (pow2-padded),
-        global rank interval, and scatter destinations.  Pads carry empty
-        windows (lo=1 > hi=0 — masked in scan, immediate exit in beam) and
-        scatter into the sink row ``nq``."""
-        m = len(idx)
-        qd = self.d_pad if lane_pad else self.d
-        g_q = np.zeros((pad, qd), np.float32)
-        g_lo = np.ones(pad, np.int32)
-        g_hi = np.zeros(pad, np.int32)
-        dst = np.full(pad, nq, np.int32)
-        if m:
-            g_q[:m, :self.d] = qv[idx]
-            g_lo[:m] = lo[idx]
-            g_hi[:m] = hi[idx]
-            dst[:m] = idx
-        return (jnp.asarray(g_q), jnp.asarray(g_lo), jnp.asarray(g_hi),
-                jnp.asarray(dst))
+    def _empty_buffers(self, rows: int, k: int):
+        """The per-shard (S, rows + 1, k) request-order buffers a batch
+        starts from (ids -1, dists inf; kept, never written in place)."""
+        buf = self._empty.get((rows, k))
+        if buf is None:
+            shape = (self.n_shards, rows + 1, k)
+            sh = NamedSharding(self.mesh, P(self.axis))
+            buf = tuple(jax.device_put(
+                [np.full(shape, -1, np.int32),
+                 np.full(shape, np.inf, np.float32)], [sh, sh]))
+            self._empty[(rows, k)] = buf
+        return buf
 
     def _scan_corpus(self):
         """Row/lane-padded per-shard corpus for the scan kernel (lazy: a
@@ -1097,7 +1174,18 @@ class MeshSubstrate:
                              (0, self.d_pad - self.d)))
         return self._x_pad
 
-    # ---------------------------------------------------------- traced fns
+    # ---------------------------------------------------------- programs
+    def _program(self, kind: str, shape=(), **static):
+        """``mesh_program`` of ``kind``, built once per operand ``shape``
+        (group pad and batch rows) and static arguments: ``_fns`` holds
+        every program this substrate compiled."""
+        key = (kind, *shape, *sorted(static.items()))
+        fn = self._fns.get(key)
+        if fn is None:
+            fn = self._fns[key] = mesh_program(self.mesh, self.axis, kind,
+                                               **static)
+        return fn
+
     def graph_fn(self, k: int, ef: int, beam_width: int = 1,
                  precision: str = "f32", use_live: bool = False):
         """Jitted graph-strategy mesh fn (also the dry-run lowering target).
@@ -1106,35 +1194,37 @@ class MeshSubstrate:
         f32 pass ``vecs`` again as ``xq`` and any (d_pad,) f32 row as
         ``scale``; under ``use_live=False`` pass any (S, per) array as
         ``live`` (all ignored).  Returns (ids, dists)."""
-        key = ("graph", k, max(ef, k), beam_width, precision, use_live)
-        fn = self._fns.get(key)
-        if fn is None:
-            body = partial(_shard_graph, k=k, ef=max(ef, k), axis=self.axis,
-                           beam_width=beam_width, precision=precision,
-                           use_live=use_live)
-            shard, rep = P(self.axis), P()
-            fn = jax.jit(jax.shard_map(
-                body, mesh=self.mesh,
-                in_specs=(shard,) * 7 + (rep,) + (shard,) + (rep,) * 3,
-                out_specs=(rep, rep), check_vma=False))
-            self._fns[key] = fn
-        return fn
+        return self._program("graph", k=k, ef=max(ef, k),
+                             beam_width=beam_width, precision=precision,
+                             use_live=use_live)
 
-    def _planned_fn(self, *, k, ef, bucket, pad_s, pad_b, nq,
-                    beam_width: int = 1, precision: str = "f32",
-                    use_live: bool = False):
-        key = ("planned", k, ef, bucket, pad_s, pad_b, nq, beam_width,
-               precision, use_live)
-        fn = self._fns.get(key)
-        if fn is None:
-            body = partial(_shard_planned, k=k, ef=ef, bucket=bucket, nq=nq,
-                           has_beam=pad_b > 0, axis=self.axis,
-                           beam_width=beam_width, precision=precision,
-                           use_live=use_live)
-            shard, rep = P(self.axis), P()
-            fn = jax.jit(jax.shard_map(
-                body, mesh=self.mesh,
-                in_specs=(shard,) * 8 + (rep,) + (shard,) + (rep,) * 8,
-                out_specs=(rep, rep), check_vma=False))
-            self._fns[key] = fn
-        return fn
+    def warm_up(self, *, k: int, ef: int, max_batch: int,
+                beam_width: int = 1, precision: str = "f32") -> None:
+        """Compile every program the planned path reaches on batches of up
+        to ``max_batch`` queries: the scan at each pow2 bucket up to the
+        planner's ceiling and the beam, each at every group pad and batch
+        row count, and the merge at every row count.  Windows in shard 0
+        steer each call to one program; the batch's other rows are
+        empty."""
+        pl = self.planner
+        top = bucket_for_len(pl.max_scan_len, min_bucket=pl.min_bucket,
+                             max_bucket=next_pow2(self.per))
+        buckets = sorted({bucket_for_len(1 << i, min_bucket=pl.min_bucket,
+                                         max_bucket=top)
+                          for i in range(top.bit_length())})
+        sizes = range(1, max_batch + 1)
+        pads = sorted({pad_pow2(c) for c in sizes})
+        qv = np.zeros((max_batch, self.d), np.float32)
+        for rows in sorted({mesh_rows(c) for c in sizes}):
+            nb = min(rows, max_batch)
+            for pad in pads:
+                c = min(pad, nb)
+                lo, hi = np.ones(nb, np.int64), np.zeros(nb, np.int64)
+                lo[:c] = 0
+                for mode, widths in (("scan", buckets), ("beam", [self.per])):
+                    for w in widths:
+                        hi[:c] = min(w, self.per) - 1
+                        self.run(SearchRequest(
+                            queries=qv[:nb], lo=lo, hi=hi, k=k, ef=ef,
+                            strategy=mode, beam_width=beam_width,
+                            precision=precision))

@@ -25,11 +25,14 @@ routes through the unified search substrate, and ``plan="auto"`` works on
     planning and upload overlap shard N's kernels; ``async_dispatch=False``
     restores the sequential dispatch+block loop;
   * mesh path: one shard per device along the ``data`` axis via
-    ``MeshSubstrate`` — the strategy vector is planned host-side from the
-    shard-clipped global intervals and the traced per-device body executes a
-    branchless scan+beam select (each kernel at most once per shard),
-    restitched in request order before the cross-shard ``all_gather`` +
-    top-k merge.  See docs/distributed.md for the full dispatch flow.
+    ``MeshSubstrate`` — each shard routes its own clip of every global
+    interval host-side, exactly as the local path's per-shard planner does,
+    and the batch runs as one ``shard_map`` program per scan bucket and one
+    beam program, each writing into per-shard request-order buffers, then
+    the cross-shard ``all_gather`` + top-k merge.  Batches pad to
+    ``mesh_rows``, so the compiled programs are bounded and
+    ``MeshSubstrate.warm_up`` compiles them all.  See docs/distributed.md
+    for the full dispatch flow.
 """
 from __future__ import annotations
 
@@ -222,7 +225,8 @@ class DistributedRFANN:
             if "scan_frac" in res.stats:
                 scan_fracs.append(float(res.stats["scan_frac"]))
         from repro.obs import stage
-        with stage("merge", None, trace, n_shards=self.n_shards) as sp:
+        with stage("merge", self._metrics, trace,
+                   n_shards=self.n_shards) as sp:
             ids, dists = merge_topk(jnp.asarray(all_i), jnp.asarray(all_d), k)
             ids, dists = np.asarray(ids), np.asarray(dists)
             sp.attrs["q"] = q

@@ -257,3 +257,160 @@ def test_gpipe_pipeline_fwd_and_grad_parity():
         print("OK", err)
     """, devices=4)
     assert "OK" in out
+
+
+# ----------------------------------------------- range-sharded mesh serving
+# Four shards of 512 rows on four virtual devices: the planner's ceiling is
+# then 64 rows a shard, so windows across shard boundaries (ranks 512, 1024,
+# 1536) can be scanned on one side and beamed on the other.
+MESH_SETUP = """
+    import numpy as np, jax
+    from repro.data.ann import make_vectors, make_attrs, ground_truth
+    from repro.obs import MetricsRegistry
+    from repro.planner import BEAM, SCAN
+    from repro.search import rank_interval
+    from repro.serving.distributed import DistributedRFANN
+    n, d, k, ef = 2048, 16, 10, 64
+    vecs = make_vectors(n, d, seed=0); attrs = make_attrs(n, seed=0)
+    srt = np.sort(attrs)
+    mesh = jax.make_mesh((4,), ("data",))
+    kw = dict(n_shards=4, m=16, ef_spatial=16, ef_attribute=24)
+    dist = DistributedRFANN(vecs, attrs, mesh=mesh, **kw)
+    ms = dist.mesh_substrate
+    assert ms.per == 512 and ms.planner.max_scan_len == 64
+
+    def windows(pairs):
+        return np.asarray([[srt[a], srt[b]] for a, b in pairs], np.float32)
+"""
+
+
+def test_mesh_routes_each_shard_clip_and_matches_the_reference():
+    """``plan="auto"`` on the mesh routes every shard's clip by itself:
+    answers reported as scan (every touched shard scanned) equal the exact
+    reference's ids; beam-reported answers reach recall@10 >= 0.9, the
+    deployments' floor, which a beam at ef=64 over clips of at most 512 rows
+    clears by a wide margin here (the pool holds an eighth of the widest
+    clip); and the mesh returns the local path's ids, whose per-shard
+    planners route by the same ceiling."""
+    out = _run(MESH_SETUP + """
+    rng = np.random.default_rng(5)
+    pairs = [(512 - 20, 512 + 100),     # 21 rows scanned | 101 beamed
+             (1024 - 30, 1024 + 30),    # both sides scanned
+             (1536 - 200, 1536 + 200),  # both sides beamed
+             (512 - 64, 512 + 63),      # both at the ceiling: scanned
+             (100, 140), (0, n - 1), (700, 1500)]
+    for width in (16, 48, 80, 160, 400, 900):
+        a = rng.integers(0, n - width, 6)
+        pairs += [(int(x), int(x) + width - 1) for x in a]
+    rg = windows(pairs)
+    qv = make_vectors(len(rg), d, seed=7)
+    lo, hi = rank_interval(dist.attrs_sorted, rg)
+    strat, routes = ms.plan_strategies(lo, hi, k=k, mode="auto")
+    assert routes.shape == (4, len(rg))
+    assert list(routes[:, 0]) == [SCAN, BEAM, -1, -1], routes[:, 0]
+    assert strat[0] == BEAM and strat[1] == SCAN and strat[2] == BEAM
+    assert strat[3] == SCAN
+    ids, dists = dist.search(qv, rg, k=k, ef=ef, plan="auto")
+    gt, _ = ground_truth(vecs, attrs, qv, rg, k)
+    gt = np.asarray(gt)
+    for q in np.flatnonzero(strat == SCAN):
+        assert set(ids[q][ids[q] >= 0]) == set(gt[q][gt[q] >= 0]), q
+    beam = np.flatnonzero(strat == BEAM)
+    hits = sum(len(set(ids[q]) & set(gt[q][gt[q] >= 0])) for q in beam)
+    total = sum(int((gt[q] >= 0).sum()) for q in beam)
+    assert hits / total >= 0.9, hits / total
+    local = DistributedRFANN(vecs, attrs, **kw)
+    ids_l, _ = local.search(qv, rg, k=k, ef=ef, plan="auto")
+    assert np.array_equal(ids, ids_l)
+    print("OK", len(beam), hits / total)
+    """, devices=4)
+    assert "OK" in out
+
+
+def test_mesh_scans_the_small_clip_of_a_straddling_window():
+    """A window across the shard 0 | shard 1 boundary with 21 rows on
+    shard 0 (under the 64-row ceiling) and 101 on shard 1: the counters
+    show one pair scanned and one beamed, and the answer merges both
+    shards' rows."""
+    out = _run(MESH_SETUP + """
+    reg = MetricsRegistry()
+    dist.install_metrics(reg)
+    rg = windows([(512 - 21, 512 + 100)])
+    qv = make_vectors(1, d, seed=3)
+    res = dist.search_ranks(qv, *rank_interval(dist.attrs_sorted, rg),
+                            k=k, ef=ef, plan="auto")
+    c = reg.snapshot()["counters"]
+    assert c["mesh_shard_scan_total"] == 1, c
+    assert c["mesh_shard_beam_total"] == 1, c
+    assert c["beam_routed_total"] == 1 and c.get("scan_routed_total") == 0
+    assert res.stats["strategy"][0] == BEAM
+    order = np.argsort(attrs, kind="stable")
+    rank = {int(o): r for r, o in enumerate(order)}
+    ranks = [rank[int(i)] for i in res.ids[0] if i >= 0]
+    assert min(ranks) < 512 <= max(ranks), ranks
+    print("OK")
+    """, devices=4)
+    assert "OK" in out
+
+
+def test_mesh_warm_up_leaves_nothing_to_compile():
+    """After ``warm_up`` for batches of up to 64 queries, serving every
+    batch size 1..64 of mixed windows adds no program to
+    ``MeshSubstrate._fns`` and compiles nothing: one scan program per
+    (bucket, group pad), one beam program per pad, one merge."""
+    out = _run(MESH_SETUP + """
+    from repro.planner.bucketing import pad_pow2
+    ms.warm_up(k=k, ef=ef, max_batch=64)
+    pads = {pad_pow2(c) for c in range(1, 65)}
+    assert len(ms._fns) == len(pads) + len(pads) + 1, sorted(ms._fns)
+    keys = set(ms._fns)
+    compiles = [0]
+
+    def count(event, duration, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles[0] += 1
+    jax.monitoring.register_event_duration_secs_listener(count)
+    rng = np.random.default_rng(11)
+    for b in range(1, 65):
+        w = (n * 2.0 ** -rng.integers(0, 10, b)).astype(int)
+        a = rng.integers(0, n - w + 1)
+        rg = windows(zip(a, a + w - 1))
+        dist.search(make_vectors(b, d, seed=b), rg, k=k, ef=ef, plan="auto")
+    assert set(ms._fns) == keys and compiles[0] == 0, compiles
+    print("OK", len(keys))
+    """, devices=4)
+    assert "OK" in out
+
+
+def test_mesh_stages_and_counters_reach_the_engine_registry():
+    """Served through ``RFANNEngine``, the mesh path's stages (``plan``,
+    ``mesh_prep``, ``mesh_dispatch``, ``mesh_block``, ``assemble``) land as
+    ``stage_<name>_ms`` histograms in the engine's registry, beside the
+    four mesh counters, and the pad rows stay within the rows run."""
+    out = _run(MESH_SETUP + """
+    from repro.serving.engine import RFANNEngine
+    eng = RFANNEngine(dist, k=k, ef=ef, plan="auto", max_batch=16)
+    try:
+        rng = np.random.default_rng(2)
+        futs = []
+        for i in range(48):
+            w = int(n * 2.0 ** -rng.integers(0, 10))
+            a = int(rng.integers(0, n - w + 1))
+            futs.append(eng.submit(make_vectors(1, d, seed=i)[0],
+                                   (srt[a], srt[a + w - 1])))
+        for f in futs:
+            f.result(timeout=120)
+        m = eng.metrics()
+    finally:
+        eng.close()
+    h, c = m["histograms"], m["counters"]
+    for st in ("plan", "mesh_prep", "mesh_dispatch", "mesh_block",
+               "assemble"):
+        assert h[f"stage_{st}_ms"]["count"] > 0, st
+    for name in ("mesh_shard_scan_total", "mesh_shard_beam_total",
+                 "mesh_rows_total", "mesh_pad_rows_total"):
+        assert name in c, name
+    assert 0 <= c["mesh_pad_rows_total"] < c["mesh_rows_total"]
+    print("OK")
+    """, devices=4)
+    assert "OK" in out

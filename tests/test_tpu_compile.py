@@ -185,3 +185,48 @@ def test_beam_program_has_no_corpus_sized_visited_state(one_chip, bw):
         _sds(s, (q,), jnp.int32), _sds(s, (q,), jnp.int32))
     for extent in (n + 1, q * (n + 1)):
         assert not re.search(rf"\b{extent}\b", text), extent
+
+
+@pytest.mark.parametrize("kind", ["scan", "beam", "merge"])
+def test_mesh_programs_compile_for_v5e_2x2(topo, kind, monkeypatch):
+    """The range-sharded deployment's mesh programs compile for a v5e 2x2
+    host at its real size (4 shards of 2^20 rows, d=96, degree 32, k=10,
+    ef=64; a 64-row batch, groups padded to 64, the widest scan bucket
+    2^17): the scan group runs the Mosaic kernel on each chip, and the merge
+    gathers across the mesh."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    import repro.kernels.ops as kernel_ops
+    from repro.search.substrate import mesh_program
+    # this process's backend is the CPU, where kernels run interpreted:
+    # trace them for Mosaic, as on the described chip
+    monkeypatch.setattr(kernel_ops, "_interpret", lambda: False)
+    mesh = Mesh(np.asarray(topo.devices[:4]), ("data",))
+    sh, rep = NamedSharding(mesh, P("data")), NamedSharding(mesh, P())
+    s, per, d, m, k, rows, pad = 4, 1 << 20, 96, 32, 10, 64, 64
+    f32, i32 = jnp.float32, jnp.int32
+    qall = _sds(rep, (rows + 1, 128), f32)
+    ops = _sds(sh, (s, 3, pad), i32)
+    buf = [_sds(sh, (s, rows + 1, k), i32), _sds(sh, (s, rows + 1, k), f32)]
+    vecs, order = _sds(sh, (s, per, d), f32), _sds(sh, (s, per), i32)
+    scale, live = _sds(rep, (128,), f32), _sds(sh, (s, per), jnp.bool_)
+    if kind == "scan":
+        fn = mesh_program(mesh, "data", "scan", k=k, ef=64, bucket=1 << 17,
+                          precision="f32", use_live=False)
+        args = [_sds(sh, (s, per, 128), f32), vecs, order, scale, live,
+                qall, ops, *buf]
+    elif kind == "beam":
+        fn = mesh_program(mesh, "data", "beam", k=k, ef=64, beam_width=1,
+                          precision="f32", use_live=False)
+        args = [vecs, _sds(sh, (s, per, m), i32), _sds(sh, (s, 21, per), i32),
+                _sds(sh, (s, per), f32), order, vecs, scale, live, qall, ops,
+                *buf]
+    else:
+        fn = mesh_program(mesh, "data", "merge", k=k)
+        args = buf
+    text = fn.lower(*args).compile().as_text()
+    if kind == "scan":
+        assert "tpu_custom_call" in text
+    if kind == "merge":
+        assert "all-gather" in text
